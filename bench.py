@@ -57,6 +57,7 @@ import jax.numpy as jnp
 
 from skypilot_tpu.models import llama
 from skypilot_tpu.train import trainer
+from skypilot_tpu.utils import jax_env
 
 import argparse
 
@@ -75,11 +76,16 @@ PEAK_BF16_TFLOPS = {
 
 
 def _peak_tflops(device) -> float:
-    kind = getattr(device, 'device_kind', '').lower()
+    """Published bf16 peak of this chip. A device that is not in the
+    table is an error: an MFU over a guessed peak is not a number."""
+    kind = device.device_kind.lower()
     for key, val in PEAK_BF16_TFLOPS.items():
         if key in kind:
             return val
-    return 197.0   # assume v5e-class if unknown
+    raise SystemExit(
+        f'bench.py: no published bf16 peak for device_kind '
+        f'{device.device_kind!r} (platform {device.platform!r}); it '
+        f'measures a TPU chip and does not fall back to another device')
 
 
 def main() -> None:
@@ -116,9 +122,9 @@ def main() -> None:
     locks.acquire_chip_lock('bench')
     seq = args.seq
     batch = args.batch or (BATCH if seq <= 2048 else 1)
+    cache_dir = jax_env.attach_compile_cache()
     dev = jax.devices()[0]
-    on_tpu = jax.default_backend() == 'tpu'
-    steps = STEPS if on_tpu else 1
+    peak_tflops = _peak_tflops(dev)     # exits on anything but a known TPU
     kw = {'attention_impl': args.attn or 'auto'}
     if args.remat_policy:
         kw['remat_policy'] = args.remat_policy
@@ -135,8 +141,9 @@ def main() -> None:
     if args.vocab:
         kw['vocab_size'] = args.vocab
     config = llama.LlamaConfig.bench_1b(max_seq_len=seq, **kw)
-    print(f'[bench] device={dev.device_kind} params={config.num_params/1e6:.0f}M '
-          f'batch={batch} seq={seq} backend={jax.default_backend()}',
+    print(f'[bench] platform={dev.platform} device={dev.device_kind} '
+          f'count={len(jax.devices())} params={config.num_params/1e6:.0f}M '
+          f'batch={batch} seq={seq} compile_cache={cache_dir}',
           file=sys.stderr)
 
     opt = trainer.make_optimizer(total_steps=1000,
@@ -149,23 +156,21 @@ def main() -> None:
     t_compile = time.perf_counter()
     for _ in range(WARMUP):
         state, metrics = step(state, batch_data)
-    # float() forces a device->host transfer — a hard sync even on backends
-    # where block_until_ready returns early (e.g. tunneled devices).
-    float(metrics['loss'])
+    float(metrics['loss'])      # device->host transfer: a hard sync
     print(f'[bench] warmup+compile: {time.perf_counter() - t_compile:.1f}s',
           file=sys.stderr)
 
     t0 = time.perf_counter()
-    for _ in range(steps):
+    for _ in range(STEPS):
         state, metrics = step(state, batch_data)
     final_loss = float(metrics['loss'])
     dt = time.perf_counter() - t0
 
-    tokens = batch * seq * steps
+    tokens = batch * seq * STEPS
     tok_per_sec = tokens / dt
     flops_per_tok = llama.flops_per_token(config)
-    mfu = tok_per_sec * flops_per_tok / (_peak_tflops(dev) * 1e12)
-    print(f'[bench] {tok_per_sec:.0f} tok/s  step={dt/steps*1e3:.0f}ms  '
+    mfu = tok_per_sec * flops_per_tok / (peak_tflops * 1e12)
+    print(f'[bench] {tok_per_sec:.0f} tok/s  step={dt/STEPS*1e3:.0f}ms  '
           f'loss={final_loss:.3f}  MFU={mfu:.3f}',
           file=sys.stderr)
 
@@ -179,6 +184,8 @@ def main() -> None:
         'model_params_m': round(config.num_params / 1e6),
         'batch': batch, 'seq': seq,
         'device': dev.device_kind,
+        'platform': dev.platform,
+        'device_count': len(jax.devices()),
     }))
 
 

@@ -54,6 +54,34 @@ def _wait_http(url: str, deadline_s: float) -> None:
     raise RuntimeError(f'{url} never became healthy: {last}')
 
 
+def _replica_device(base_url: str) -> str:
+    """``device_kind`` as the replica's own /metrics reports it: the
+    process that ran the work names the device, never this parent
+    (which must stay off jax — it would take the chip, or land on the
+    CPU and label a chip run 'cpu')."""
+    return _get(f'{base_url}/metrics')['device']['device_kind']
+
+
+def _refuse_unless_devices(n_servers: int, sweep: str) -> None:
+    """A chip belongs to one process: a sweep that boots ``n_servers``
+    engine processes hangs or fails on an accelerator host with fewer
+    devices. Asked of a child, so this parent never holds a chip."""
+    out = subprocess.run(
+        [sys.executable, '-c',
+         'import json; from skypilot_tpu.utils import jax_env; '
+         'print(json.dumps(jax_env.device_summary()))'],
+        capture_output=True, text=True, check=True).stdout
+    device = json.loads(out.strip().splitlines()[-1])
+    platform, count = device['platform'], device['count']
+    if platform != 'cpu' and count < n_servers:
+        raise SystemExit(
+            f'--sweep {sweep} boots {n_servers} engine processes but '
+            f'this host has {count} {platform} device(s), and a chip '
+            f'belongs to one process at a time. Run it with '
+            f'JAX_PLATFORMS=cpu (counts only) or on a host with a chip '
+            f'per server.')
+
+
 def _run_lb(service: str, port: int, policy: str = 'least_load') -> None:
     from skypilot_tpu.serve import load_balancer
     load_balancer.run_load_balancer(service, policy, '127.0.0.1',
@@ -667,11 +695,11 @@ def _coldstart_boot(args, cache_dir: str, boot_idx: int) -> dict:
     cmd = [sys.executable, '-m', 'skypilot_tpu.infer.server',
            '--port', str(port), '--model', args.model,
            '--slots', str(args.slots),
-           '--max-seq-len', str(args.max_seq_len),
-           '--compile-cache-dir', cache_dir]
+           '--max-seq-len', str(args.max_seq_len)]
     t0 = time.time()
-    proc = subprocess.Popen(cmd, stdout=subprocess.DEVNULL,
-                            stderr=subprocess.STDOUT)
+    proc = subprocess.Popen(
+        cmd, stdout=subprocess.DEVNULL, stderr=subprocess.STDOUT,
+        env={**os.environ, 'JAX_COMPILATION_CACHE_DIR': cache_dir})
     try:
         _wait_http(f'http://127.0.0.1:{port}/health', 600)
         ready_s = time.time() - t0
@@ -707,9 +735,15 @@ def _run_coldstart_sweep(args) -> dict:
     curve (spawn → weights → compile → first token) for both, plus the
     ready-time ratio the cache buys. No improvement assertion: backends
     without persistent-cache support degrade to two cold boots."""
-    import tempfile
-    with tempfile.TemporaryDirectory(prefix='sky-tpu-ccache-') as cache:
-        boots = [_coldstart_boot(args, cache, i) for i in range(2)]
+    import shutil
+
+    from skypilot_tpu.utils import jax_env
+    # A fixed directory under wherever this machine's cache is placed
+    # (the directory is part of the cache key), emptied so boot 1 is
+    # the cold one.
+    cache = os.path.join(jax_env.compile_cache_dir(), 'coldstart-sweep')
+    shutil.rmtree(cache, ignore_errors=True)
+    boots = [_coldstart_boot(args, cache, i) for i in range(2)]
     cold, warm = boots[0], boots[1]
     ratio = (round(cold['time_to_ready_s'] / warm['time_to_ready_s'], 3)
              if warm['time_to_ready_s'] else None)
@@ -841,6 +875,7 @@ def _run_disagg_sweep(args) -> dict:
                      lbp.AFFINITY_LEAD_TOKENS - tail)
 
     roles = ('prefill', 'decode')
+    _refuse_unless_devices(len(roles), 'disagg')
     ports = [common.free_port() for _ in roles]
     procs = []
     for port, role in zip(ports, roles):
@@ -860,9 +895,11 @@ def _run_disagg_sweep(args) -> dict:
     owner_port, fleet_port = common.free_port(), common.free_port()
     sweep = []
     cold_s = None
+    device = None
     try:
         for port in ports:
             _wait_http(f'http://127.0.0.1:{port}/health', 600)
+        device = _replica_device(f'http://127.0.0.1:{ports[0]}')
         from skypilot_tpu.serve import state as serve_state
         from skypilot_tpu.serve.state import ReplicaStatus
         serve_state.add_service(service, spec_json='{}', task_yaml='',
@@ -934,7 +971,6 @@ def _run_disagg_sweep(args) -> dict:
         for p in procs:
             p.wait(timeout=10)
 
-    import jax
     base = sweep[0] if sweep else {}
     return {
         'metric': 'disagg_ttft_improvement_x',
@@ -960,7 +996,7 @@ def _run_disagg_sweep(args) -> dict:
         'page_size': args.page_size,
         'kv_dtype': 'int8',
         'roles': list(roles),
-        'device': jax.devices()[0].device_kind,
+        'device': device,
         'path': ('client -> cache_aware LB (owner-only vs fleet '
                  'prefix index) -> prefill donor + decode puller '
                  '(int8 KV page streaming; client-side '
@@ -1075,9 +1111,9 @@ def main() -> None:
                              'index, emitting fleet_prefix_hit_rate, '
                              'transfer_p99_s and ttft_improvement_x '
                              'per level (boots TWO engine processes '
-                             '— on a single-chip host run with '
-                             'JAX_PLATFORMS=cpu or give each its '
-                             'own device).')
+                             '— refused on an accelerator host with '
+                             'fewer than two devices: a chip belongs '
+                             'to one process).')
     parser.add_argument('--spec-k', type=int, default=0,
                         help='speculative draft width for the replica '
                              '(0 = off; --sweep speculative defaults '
@@ -1260,8 +1296,10 @@ def main() -> None:
         cmd, stdout=subprocess.DEVNULL, stderr=subprocess.STDOUT)
     sweep = []
     cold_s = None
+    device = None
     try:
         _wait_http(f'http://127.0.0.1:{infer_port}/health', 600)
+        device = _replica_device(f'http://127.0.0.1:{infer_port}')
 
         # 2. Register it as a ready replica; start the REAL serve LB.
         #    chaos-resume alternates replicas deterministically
@@ -1418,7 +1456,6 @@ def main() -> None:
         infer_proc.terminate()
         infer_proc.wait(timeout=10)
 
-    import jax
     base = sweep[0] if sweep else {}
     if args.sweep == 'shared-prefix':
         head = {
@@ -1511,7 +1548,7 @@ def main() -> None:
         **({'spec_k': args.spec_k, 'spec_ngram': args.spec_ngram}
            if args.spec_k else {}),
         'tokenizer': ('bpe-8k' if tokenizer else 'bytes'),
-        'device': jax.devices()[0].device_kind,
+        'device': device,
         'path': ('client -> serve LB -> continuous-batching engine '
                  '(streamed; client-side send->first-byte clock)'),
     }

@@ -40,6 +40,7 @@ from skypilot_tpu.models import llama
 from skypilot_tpu.observability import prometheus as prom_lib
 from skypilot_tpu.utils import common as common_lib
 from skypilot_tpu.utils import failpoints
+from skypilot_tpu.utils import jax_env
 
 logger = logging.getLogger(__name__)
 
@@ -183,30 +184,24 @@ def parse_tenant_weights(spec: Optional[str]) -> Optional[dict]:
     return out or None
 
 
-def setup_compile_cache(cache_dir: str) -> bool:
-    """Point XLA's persistent compilation cache at ``cache_dir`` so a
-    relaunched replica deserializes its warm-path programs instead of
-    recompiling them — the dominant term of a scale-to-zero cold start
-    after weights (docs/cost.md "Scale to zero"). The threshold tuning
-    makes the very first boot populate the cache even for small
-    programs, so the SECOND boot is the fast one.
+def setup_compile_cache(cache_dir: Optional[str] = None) -> bool:
+    """Attach XLA's persistent compilation cache (utils/jax_env.py
+    resolves where: ``JAX_COMPILATION_CACHE_DIR`` wins, then
+    ``cache_dir``, then the checkout's fixed default) so a relaunched
+    replica deserializes its warm-path programs instead of recompiling
+    them — the dominant term of a scale-to-zero cold start after
+    weights (docs/cost.md "Scale to zero"). The first boot populates
+    the cache, so the SECOND boot is the fast one.
 
     Degradation, not failure: on the ``infer.server.compile_cache_miss``
     failpoint or any real setup error (read-only dir, an XLA build
     without the flag) the server warms with a cold compile — slower
-    first tokens, never a crash."""
+    first tokens, never a crash. ``/metrics`` reports the directory
+    actually in force, so a caller that needs the cache can tell."""
     try:
         failpoints.hit('infer.server.compile_cache_miss')
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update('jax_compilation_cache_dir', cache_dir)
-        # Cache everything: the default min-compile-time gate would
-        # skip exactly the small warm-path programs a cold start
-        # replays.
-        jax.config.update('jax_persistent_cache_min_compile_time_secs',
-                          0.0)
-        jax.config.update('jax_persistent_cache_min_entry_size_bytes',
-                          -1)
-        logger.info('persistent compile cache at %s', cache_dir)
+        logger.info('persistent compile cache at %s',
+                    jax_env.attach_compile_cache(cache_dir))
         return True
     except failpoints.FailpointError as e:
         logger.warning('compile cache miss injected (%s): serving '
@@ -375,6 +370,12 @@ class InferenceServer:
         # broadcast driver (infer/multihost.py) instead of the local
         # engine queue.
         self.driver = driver
+        # What this replica computes on and caches compiles in, read
+        # once by the process that owns the device: /metrics carries
+        # it, so no client ever has to ask jax itself.
+        self.device = jax_env.device_summary()
+        self.compile_cache_dir = (
+            jax.config.jax_compilation_cache_dir or '')
         self.ready = False
         self.dead: str = ''
         # Graceful drain (docs/robustness.md "Zero-downtime serving"):
@@ -487,6 +488,10 @@ class InferenceServer:
         m['server_inflight'] = self._active
         m['requests_shed'] = self._requests_shed
         m['role'] = self.role
+        m['device'] = self.device
+        m['device_memory_bytes'] = jax_env.device_memory()
+        m['compile_cache_dir'] = self.compile_cache_dir
+        m['compiled_programs'] = self.engine.compiled_counts()
         if self.drain_duration_s is not None:
             m['drain_duration_s'] = round(self.drain_duration_s, 4)
         if self.engine.kv_index_armed():
@@ -968,8 +973,19 @@ class InferenceServer:
 
     def run(self, host: str, port: int) -> None:
         self._thread.start()
-        web.run_app(self.make_app(), host=host, port=port,
-                    print=lambda *_: None)
+        try:
+            web.run_app(self.make_app(), host=host, port=port,
+                        print=lambda *_: None)
+        finally:
+            # SIGTERM lands here (run_app returns). Park the engine
+            # loop before the interpreter tears down: a daemon thread
+            # still inside a device call at exit aborts the process on
+            # TPU ("FATAL: exception not rethrown") instead of exiting.
+            self._stop.set()
+            self._woken.set()
+            if self.driver is not None:
+                self.driver.stop()      # rides the lockstep broadcast
+            self._thread.join(timeout=30)
 
 
 def main() -> None:
@@ -1098,7 +1114,11 @@ def main() -> None:
                              'warm-path programs instead of '
                              'recompiling, cutting cold-start '
                              'time-to-ready. Survives restarts; share '
-                             'it across replicas of one service.')
+                             'it across replicas of one service. '
+                             'JAX_COMPILATION_CACHE_DIR, when set, '
+                             'wins over this flag; with neither, a '
+                             'fixed directory in the checkout is '
+                             'used.')
     parser.add_argument('--no-sdc-sentinel', action='store_true',
                         help='Disable the on-device SDC sentinel '
                              '(docs/robustness.md "Data integrity"). '
@@ -1139,8 +1159,7 @@ def main() -> None:
     args = parser.parse_args()
     logging.basicConfig(level=logging.INFO)
     boot_t0 = time.time()
-    if args.compile_cache_dir:
-        setup_compile_cache(args.compile_cache_dir)
+    setup_compile_cache(args.compile_cache_dir)
     if args.paged and args.long_slots > 0:
         # Usage error: fail in milliseconds, not after minutes of
         # checkpoint loading and KV allocation.
@@ -1159,6 +1178,9 @@ def main() -> None:
     # the lockstep tick loop.
     from skypilot_tpu.infer import multihost
     world = multihost.maybe_initialize_distributed()
+    logger.info('device: platform=%(platform)s '
+                'device_kind=%(device_kind)s count=%(count)d',
+                jax_env.device_summary())
 
     config = MODELS[args.model]()
     if world > 1 and args.tp == 1:
@@ -1242,6 +1264,7 @@ def main() -> None:
         params = llama.init_params(config, jax.random.PRNGKey(0))
     tenant_weights = parse_tenant_weights(args.tenant_weights)
     t_weights = time.time()
+    logger.info('weights ready in %.1fs', t_weights - boot_t0)
     engine = engine_lib.InferenceEngine(
         config, params,
         engine_lib.EngineConfig(

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from skypilot_tpu.parallel import sharding as sharding_lib
+
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
 _NEG_INF = -1e30
@@ -354,13 +356,32 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     """Flash attention. q: [b, hq, s, d]; k/v: [b, hkv, s, d] (GQA).
 
     `interpret` defaults to True off-TPU so tests run on CPU.
+
+    Traced under a multi-device mesh (``jax.set_mesh``, as the sharded
+    train step does) the kernels run per shard inside a ``shard_map``:
+    XLA cannot partition a Mosaic kernel itself ("Mosaic kernels cannot
+    be automatically partitioned"), and attention is independent per
+    (batch, head), so the framework's own layout — batch over the data
+    axes, heads over ``tp`` (parallel/sharding.attention_spec) — needs
+    no collective. Inside an enclosing shard_map (pipeline stages, ring
+    attention) the axes are already manual and the kernel is called as
+    is.
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != 'tpu'
-    return _flash_attention(q, k, v, causal, sm_scale, block_q, block_k,
-                            interpret)
+
+    def kernel(q_, k_, v_):
+        return _flash_attention(q_, k_, v_, causal, sm_scale, block_q,
+                                block_k, interpret)
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return kernel(q, k, v)
+    spec = sharding_lib.attention_spec(mesh, q.shape[1], k.shape[1])
+    return jax.shard_map(kernel, in_specs=(spec,) * 3, out_specs=spec,
+                         check_vma=False)(q, k, v)
 
 
 def _fit_block(want: int, seq_len: int) -> int:
@@ -376,32 +397,38 @@ def _fit_block(want: int, seq_len: int) -> int:
     return max(b, 1)
 
 
+def resolve_impl(impl: str, seq_len: int) -> str:
+    """What ``attention(impl=...)`` runs at this sequence length on
+    this process's backend: ``'flash'`` or ``'dense'``. 'auto' is flash
+    on TPU when the sequence tiles (a multiple of 128, at least 256),
+    dense otherwise; entry points log it so a run says which it took."""
+    if impl in ('dense', 'flash'):
+        return impl
+    on_tpu = jax.default_backend() == 'tpu'
+    tiles = seq_len % 128 == 0 and seq_len >= 256
+    return 'flash' if on_tpu and tiles else 'dense'
+
+
 def attention(q, k, v, *, causal: bool = True,
               sm_scale: Optional[float] = None,
               impl: str = 'auto',
               block_q: Optional[int] = None,
               block_k: Optional[int] = None) -> jnp.ndarray:
-    """Dispatch: 'dense', 'flash', or 'auto' (flash on TPU when shapes
-    allow, else dense). block_q/block_k override the flash tile sizes
-    (clamped to seq; None → defaults)."""
-    if impl == 'dense':
-        return dense_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+    """Dispatch: 'dense', 'flash', or 'auto' (see ``resolve_impl``).
+    block_q/block_k override the flash tile sizes (clamped to seq;
+    None → defaults)."""
     s = q.shape[2]
+    if resolve_impl(impl, s) == 'dense':
+        return dense_attention(q, k, v, causal=causal, sm_scale=sm_scale)
     bq = _fit_block(block_q or DEFAULT_BLOCK_Q, s)
     bk = _fit_block(block_k or DEFAULT_BLOCK_K, s)
-    if impl == 'flash':
-        if min(bq, bk) < 128 and s >= 128:
-            # The gcd fallback would hand the kernel sub-lane tiles (a
-            # pathological grid); explicit flash on such a seq is a
-            # user error, not something to quietly degrade.
-            raise ValueError(
-                f'flash attention needs seq_len divisible by a >=128 '
-                f'tile; got seq_len={s} (fitted tiles {bq}x{bk}). Pad '
-                f'the sequence or use impl="dense"/"auto".')
-        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                               block_q=bq, block_k=bk)
-    on_tpu = jax.default_backend() == 'tpu'
-    if on_tpu and s % 128 == 0 and s >= 256:
-        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                               block_q=bq, block_k=bk)
-    return dense_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+    if impl == 'flash' and min(bq, bk) < 128 and s >= 128:
+        # The gcd fallback would hand the kernel sub-lane tiles (a
+        # pathological grid); explicit flash on such a seq is a
+        # user error, not something to quietly degrade.
+        raise ValueError(
+            f'flash attention needs seq_len divisible by a >=128 '
+            f'tile; got seq_len={s} (fitted tiles {bq}x{bk}). Pad '
+            f'the sequence or use impl="dense"/"auto".')
+    return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
+                           block_q=bq, block_k=bk)

@@ -126,6 +126,9 @@ from jax.experimental.pallas import tpu as pltpu
 # fp32 recompute to the chunked scan (fp32 [T, V] logits alone exceed
 # 6 GB at Llama-3's 128k vocab). Module-level so tests can lower it.
 ONE_SHOT_BWD_MAX_VOCAB = 65536
+# Largest x ([block_t, d]) or w ([d, block_v]) tile the fused forward
+# picks by default.
+_TILE_BYTES = 2 << 20
 
 
 def _ce_fwd_kernel(x_ref, w_ref, t_ref, nll_ref, lse_ref,
@@ -229,13 +232,19 @@ def fused_cross_entropy(x: jnp.ndarray, w: jnp.ndarray,
     backward.
 
     x: [T, d]; w: [d, V]; targets: [T] int32 -> [T] fp32. Tile sizes
-    default to the largest divisors of T / V up to 512. `interpret`
-    defaults to True off-TPU.
+    default to the largest divisors of T / V up to 512, capped so one
+    x tile and one w tile stay within ``_TILE_BYTES`` each: the
+    pipeline double-buffers both in VMEM, and 512 x d tiles at d=4096
+    overran the 16 MiB scoped limit on v5e. `interpret` defaults to
+    True off-TPU.
     """
     if interpret is None:
         interpret = jax.default_backend() != 'tpu'
-    bt = block_t or _auto_block(x.shape[0], 512)
-    bv = block_v or _auto_block(w.shape[1], 512, floor=128)
+    d = x.shape[1]
+    rows = _TILE_BYTES // (d * max(x.dtype.itemsize, w.dtype.itemsize))
+    cap = max(128, min(512, 1 << (max(rows, 1).bit_length() - 1)))
+    bt = block_t or _auto_block(x.shape[0], cap)
+    bv = block_v or _auto_block(w.shape[1], cap, floor=128)
     return _fused_cross_entropy(x, w, targets, bt, bv, interpret)
 
 

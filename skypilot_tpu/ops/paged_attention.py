@@ -47,8 +47,9 @@ fp32 absmax scale per cached token row per KV head
 (``k_scales/v_scales: [hkv, P, page]``), pool-aligned with the pages.
 Quantization happens ON WRITE (each row is quantized independently, so
 appending never rescales earlier rows) and dequantization happens IN
-KERNEL (one multiply per page row before the matmul) — the HBM stream
-is int8, roughly doubling the resident pages per chip. Every entry
+KERNEL (the row scales multiply the score and probability columns, see
+``_scale_rows``) — the HBM stream is int8, roughly doubling the
+resident pages per chip. Every entry
 point takes optional ``k_scales``/``v_scales``; None means the bf16
 path, which is bit-for-bit the pre-quantization code.
 """
@@ -96,6 +97,18 @@ def _deq(pages: jnp.ndarray, scales: Optional[jnp.ndarray]
     if scales is not None:
         out = out * scales.astype(jnp.float32)[..., None]
     return out
+
+
+def _scale_rows(scales: jnp.ndarray) -> jnp.ndarray:
+    """[hkv, P, page] -> [hkv, P, 1, page] at the kernel boundary: a
+    TPU block's last two dims must be (8,128)-divisible or the array's
+    own, and one page's scale row is neither inside [.., P, page]. As
+    (1, page) it is the array's own last two dims, addressed by the
+    pages' index map unchanged, and it lands in VMEM lane-major — so
+    the kernels scale the [rows, page] score/probability COLUMNS
+    (q.(k*s) == (q.k)*s; p@(v*s) == (p*s)@v) rather than relayout it
+    against the [page, hd] page rows."""
+    return scales[:, :, None, :]
 
 
 # ---------------------------------------------------------------------------
@@ -194,14 +207,11 @@ def _decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *refs,
             q = q_ref[0, h].astype(jnp.float32) * sm_scale  # [group, hd]
             k = k_ref[h, 0].astype(jnp.float32)             # [page, hd]
             v = v_ref[h, 0].astype(jnp.float32)
-            if quantized:
-                # Dequant in kernel: the HBM stream stays int8; the
-                # per-row fp32 scale multiplies once in VMEM.
-                k = k * ks_ref[h, 0][:, None]
-                v = v * vs_ref[h, 0][:, None]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)         # [group, page]
+            if quantized:
+                s = s * ks_ref[h, 0]        # [1, page] K row scales
             s = jnp.where(valid, s, _NEG_INF)
             m_prev = m_ref[h]
             m_new = jnp.maximum(m_prev,
@@ -210,6 +220,8 @@ def _decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *refs,
             alpha = jnp.exp(m_prev - m_new)
             l_ref[h] = l_ref[h] * alpha + jnp.sum(pr, axis=-1,
                                                   keepdims=True)
+            if quantized:
+                pr = pr * vs_ref[h, 0]      # [1, page] V row scales
             acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
                 pr, v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -239,8 +251,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     token's K/V first pass the already-bumped length, mirroring the
     dense decode path's write-then-attend contract).
     k_scales/v_scales: [hkv, P, page] f32 row scales on the int8
-    flavor (forces the native kernel — the library kernel has no
-    dequant hook); None = bf16 pages, the pre-quantization path.
+    flavor (forces the native kernel — the library path here is wired
+    for bf16 pages only); None = bf16 pages, the pre-quantization path.
 
     impl: 'native' runs this module's grid kernel everywhere; 'jax'
     runs jax's tuned JetStream decode kernel (same page layout —
@@ -261,8 +273,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         impl = ('jax' if jax_ok and not interpret_resolved
                 else 'native')
     if impl == 'jax' and quantized:
-        raise ValueError("impl='jax' has no int8 dequant hook; use "
-                         "the native kernel for kv_dtype=int8")
+        raise ValueError("impl='jax' is wired for bf16 pages only; "
+                         "use the native kernel for kv_dtype=int8")
     if impl == 'jax' and not interpret_resolved:
         from jax.experimental.pallas.ops.tpu.paged_attention import (
             paged_attention as jax_paged_attention)
@@ -294,12 +306,6 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         j = jnp.minimum(p, jnp.maximum(n_pages - 1, 0))
         return (0, tables[b, j], 0, 0)
 
-    def _scale_index(*args):
-        # Scales live beside their pages: same index map minus the
-        # head_dim axis, DERIVED so a clamp-rule fix can never land on
-        # the value DMA and miss the scale DMA.
-        return _page_index(*args)[:-1]
-
     in_specs = [
         pl.BlockSpec((1, hkv, group, hd),
                      lambda b, p, *_: (b, 0, 0, 0)),
@@ -308,9 +314,9 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     ]
     operands = [q, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((hkv, 1, page_size), _scale_index),
-                     pl.BlockSpec((hkv, 1, page_size), _scale_index)]
-        operands += [k_scales, v_scales]
+        # Scales ride the pages' own index map (_scale_rows).
+        in_specs += [pl.BlockSpec((hkv, 1, 1, page_size), _page_index)] * 2
+        operands += [_scale_rows(k_scales), _scale_rows(v_scales)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(slots, max_pages),
@@ -380,12 +386,11 @@ def _prefill_kernel(table_ref, meta_ref, q_ref, *refs,
         def _do():
             k = k_refs[f][0, 0].astype(jnp.float32)   # [page, hd]
             v = v_refs[f][0, 0].astype(jnp.float32)
-            if quantized:
-                k = k * ks_refs[f][0, 0][:, None]
-                v = v * vs_refs[f][0, 0][:, None]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)   # [C*g, page]
+            if quantized:
+                s = s * ks_refs[f][0, 0]
             # Causality in GLOBAL positions: row r is query
             # offset + r//g; column c is cached position p*page + c.
             qpos = offset + jax.lax.broadcasted_iota(
@@ -400,6 +405,8 @@ def _prefill_kernel(table_ref, meta_ref, q_ref, *refs,
             alpha = jnp.exp(m_prev - m_new)
             l_ref[...] = l_ref[...] * alpha + jnp.sum(
                 pr, axis=-1, keepdims=True)
+            if quantized:
+                pr = pr * vs_refs[f][0, 0]
             acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
                 pr, v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -461,15 +468,6 @@ def paged_prefill_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
             return (h, table[j], 0, 0)
         return index
 
-    def _scale_index(f):
-        # Derived from the page map (minus the head_dim axis): value
-        # and scale DMA targets cannot desynchronize.
-        page_f = _page_index(f)
-
-        def index(*args):
-            return page_f(*args)[:-1]
-        return index
-
     page_spec = [pl.BlockSpec((1, 1, page_size, hd), _page_index(f))
                  for f in range(fan)]
     in_specs = [
@@ -480,10 +478,11 @@ def paged_prefill_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     ]
     operands = [qf, *([k_pages] * fan), *([v_pages] * fan)]
     if quantized:
-        scale_spec = [pl.BlockSpec((1, 1, page_size), _scale_index(f))
+        scale_spec = [pl.BlockSpec((1, 1, 1, page_size), _page_index(f))
                       for f in range(fan)]
         in_specs += [*scale_spec, *scale_spec]
-        operands += [*([k_scales] * fan), *([v_scales] * fan)]
+        operands += [*([_scale_rows(k_scales)] * fan),
+                     *([_scale_rows(v_scales)] * fan)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(hkv, n_groups),
@@ -575,12 +574,11 @@ def _verify_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *refs,
             q = q_ref[0, h].astype(jnp.float32) * sm_scale  # [R*g, hd]
             k = k_ref[h, 0].astype(jnp.float32)             # [page, hd]
             v = v_ref[h, 0].astype(jnp.float32)
-            if quantized:
-                k = k * ks_ref[h, 0][:, None]
-                v = v * vs_ref[h, 0][:, None]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)         # [R*g, page]
+            if quantized:
+                s = s * ks_ref[h, 0]
             kpos = p * page_size + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
             qi = jax.lax.broadcasted_iota(
@@ -593,6 +591,8 @@ def _verify_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *refs,
             alpha = jnp.exp(m_prev - m_new)
             l_ref[h] = l_ref[h] * alpha + jnp.sum(pr, axis=-1,
                                                   keepdims=True)
+            if quantized:
+                pr = pr * vs_ref[h, 0]
             acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
                 pr, v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -650,12 +650,6 @@ def paged_verify_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         j = jnp.minimum(j, max_pages - 1)
         return (0, tables[b, j], 0, 0)
 
-    def _scale_index(*args):
-        # Derived from the page map (minus the head_dim axis): the
-        # lengths+R horizon rule can never change on one and not the
-        # other.
-        return _page_index(*args)[:-1]
-
     in_specs = [
         pl.BlockSpec((1, hkv, R * group, hd),
                      lambda b, p, *_: (b, 0, 0, 0)),
@@ -664,9 +658,8 @@ def paged_verify_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     ]
     operands = [qf, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((hkv, 1, page_size), _scale_index),
-                     pl.BlockSpec((hkv, 1, page_size), _scale_index)]
-        operands += [k_scales, v_scales]
+        in_specs += [pl.BlockSpec((hkv, 1, 1, page_size), _page_index)] * 2
+        operands += [_scale_rows(k_scales), _scale_rows(v_scales)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(slots, max_pages),

@@ -81,14 +81,14 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     # Accumulator inits must be tagged as device-varying over the ring axis
     # (the loop writes axis-dependent values into them).
+    def varying(x):
+        return jax.lax.pcast(x, (axis_name,), to='varying')
+
     init = (
         k, v,
-        jax.lax.pvary(jnp.zeros((b, hq, s_local, d), jnp.float32),
-                      (axis_name,)),
-        jax.lax.pvary(jnp.full((b, hq, s_local, 1), _NEG_INF, jnp.float32),
-                      (axis_name,)),
-        jax.lax.pvary(jnp.zeros((b, hq, s_local, 1), jnp.float32),
-                      (axis_name,)),
+        varying(jnp.zeros((b, hq, s_local, d), jnp.float32)),
+        varying(jnp.full((b, hq, s_local, 1), _NEG_INF, jnp.float32)),
+        varying(jnp.zeros((b, hq, s_local, 1), jnp.float32)),
     )
     _, _, acc, _, l = jax.lax.fori_loop(0, n, step, init)
     return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)

@@ -5,21 +5,3 @@ torchrun/NCCL (SURVEY.md §2.8): DP/FSDP/TP via `jax.sharding` +
 NamedSharding over a Mesh; SP via ring attention (`ops/ring_attention.py`);
 XLA emits the collectives over ICI/DCN.
 """
-
-try:                                    # jax >= 0.8
-    import inspect as _inspect
-
-    from jax import shard_map as _shard_map
-    _HAS_CHECK_VMA = 'check_vma' in _inspect.signature(
-        _shard_map).parameters
-
-    def shard_map(f, *args, check_rep=None, **kwargs):
-        """jax.shard_map with the old check_rep spelling accepted."""
-        if check_rep is not None:
-            if _HAS_CHECK_VMA:
-                kwargs.setdefault('check_vma', check_rep)
-            else:
-                kwargs.setdefault('check_rep', check_rep)
-        return _shard_map(f, *args, **kwargs)
-except ImportError:                     # older jax
-    from jax.experimental.shard_map import shard_map  # noqa: F401

@@ -133,9 +133,8 @@ def llama_pp_loss_fn(config: llama.LlamaConfig, mesh: Mesh,
             loss = jax.lax.pmean(loss, dp_axis)
         return loss
 
-    from skypilot_tpu.parallel import shard_map
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(param_specs, batch_spec, batch_spec),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)

@@ -40,6 +40,20 @@ LLAMA_PARAM_SPECS: Dict[str, Any] = {
 BATCH_SPEC = P(('dp', 'fsdp'), None)           # [batch, seq]
 
 
+def attention_spec(mesh, n_heads: int, n_kv_heads: int) -> P:
+    """[batch, heads, seq, head_dim] layout for attention kernels that
+    run per shard (ops/attention.flash_attention): batch over the data
+    axes, heads over ``tp`` — where the column-parallel qkv projections
+    already leave them — when ``tp`` divides both head counts (GQA
+    groups then stay whole per shard); otherwise every tp shard
+    computes all heads. Names only axes ``mesh`` has."""
+    batch = tuple(a for a in ('dp', 'fsdp') if a in mesh.axis_names)
+    tp = mesh.shape.get('tp', 1)
+    heads = 'tp' if (tp > 1 and n_heads % tp == 0
+                     and n_kv_heads % tp == 0) else None
+    return P(batch or None, heads, None, None)
+
+
 def param_shardings(mesh: Mesh, params: Any) -> Any:
     """NamedShardings matching the params pytree (LLAMA_PARAM_SPECS
     broadcast over identical tree structure).

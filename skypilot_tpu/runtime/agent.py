@@ -245,10 +245,8 @@ class Agent:
                 env['PYTHONPATH'] = (f'{pkg_root}{os.pathsep}{prior_pp}'
                                      if prior_pp else pkg_root)
             # Fake slices must not grab a real TPU. Overridden (not
-            # setdefault): the inherited environment may pin a TPU platform,
-            # and both selection variables must agree for every jax version.
+            # setdefault): the inherited environment may pin a TPU platform.
             env['JAX_PLATFORMS'] = 'cpu'
-            env['JAX_PLATFORM_NAME'] = 'cpu'
             if self.tpu_slice is not None:
                 flag = ('--xla_force_host_platform_device_count='
                         f'{self.tpu_slice.chips_per_host}')

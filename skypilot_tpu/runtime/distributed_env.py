@@ -7,7 +7,9 @@ examples/resnet_distributed_torch.yaml:31-34). The TPU equivalent wires the
 XLA/libtpu process group instead of NCCL:
 
 - ``JAX_COORDINATOR_ADDRESS`` / ``JAX_NUM_PROCESSES`` / ``JAX_PROCESS_ID``:
-  consumed by ``jax.distributed.initialize()`` with no arguments.
+  read by ``infer/multihost.maybe_initialize_distributed`` and passed to
+  ``jax.distributed.initialize()`` explicitly (jax itself reads only the
+  coordinator address from the environment).
 - ``TPU_WORKER_ID`` / ``TPU_WORKER_HOSTNAMES``: libtpu's own multi-host
   wiring (what the TPU VM metadata server would provide); exporting them
   makes the framework authoritative, which is required when running
@@ -57,7 +59,7 @@ def make_env(host_ips: List[str],
         NODE_RANK_ENV: str(rank),
         NODE_IPS_ENV: '\n'.join(host_ips),
         NUM_NODES_ENV: str(num_hosts),
-        # jax.distributed.initialize() picks these up directly.
+        # multihost.maybe_initialize_distributed() passes these on.
         'JAX_COORDINATOR_ADDRESS': coordinator,
         'JAX_NUM_PROCESSES': str(num_hosts * num_slices),
         'JAX_PROCESS_ID': str(slice_id * num_hosts + rank),

@@ -63,7 +63,17 @@ class CheckpointManager:
         if step is None:
             raise FileNotFoundError(
                 f'No checkpoint under {self.directory}')
-        cpu = jax.local_devices(backend='cpu')[0]
+        try:
+            cpu = jax.local_devices(backend='cpu')[0]
+        except RuntimeError as e:
+            # jax initialises only the platforms JAX_PLATFORMS lists:
+            # 'tpu' alone leaves no cpu backend to restore into (unset,
+            # or 'tpu,cpu' as on the chip machines, keeps it).
+            raise RuntimeError(
+                f'restore_to_host needs jax\'s cpu backend beside the '
+                f'accelerator; JAX_PLATFORMS='
+                f'{os.environ.get("JAX_PLATFORMS")!r} does not select '
+                f'it (use e.g. "tpu,cpu")') from e
         sharding = jax.sharding.SingleDeviceSharding(cpu)
         target_struct = jax.tree_util.tree_map(
             lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,

@@ -15,6 +15,8 @@ corpus plugs in by replacing ``synthetic_batch`` with a data iterator.
 from __future__ import annotations
 
 import argparse
+import collections
+import json
 import logging
 import os
 import sys
@@ -30,19 +32,6 @@ MODELS = {
     'moe-tiny': ('moe', 'tiny'),
     'moe-8x7b': ('moe', 'mixtral_8x7b'),
 }
-
-
-def _maybe_init_distributed() -> None:
-    """Join the slice process group when the agent injected the env."""
-    import jax
-
-    from skypilot_tpu.runtime import distributed_env
-    num = int(os.environ.get('JAX_NUM_PROCESSES', '1'))
-    if num > 1:
-        jax.distributed.initialize()   # env-driven, distributed_env.py
-        logger.info('jax.distributed up: process %s/%s',
-                    os.environ.get('JAX_PROCESS_ID'), num)
-    del distributed_env
 
 
 def main(argv=None) -> None:
@@ -67,14 +56,24 @@ def main(argv=None) -> None:
         level=logging.INFO,
         format='%(asctime)s %(levelname)s %(name)s: %(message)s')
 
-    _maybe_init_distributed()
     import jax
     import jax.numpy as jnp
 
+    from skypilot_tpu.infer import multihost
     from skypilot_tpu.models import llama
+    from skypilot_tpu.ops import attention as attention_lib
     from skypilot_tpu.parallel import mesh as mesh_lib
     from skypilot_tpu.parallel import sharding as sharding_lib
     from skypilot_tpu.train import trainer
+    from skypilot_tpu.utils import jax_env
+
+    cache_dir = jax_env.attach_compile_cache()
+    # Join the slice process group when the agent injected the env
+    # (runtime/distributed_env.py); the one helper the server uses too.
+    world = multihost.maybe_initialize_distributed()
+    if world > 1:
+        logger.info('jax.distributed up: process %d/%d',
+                    jax.process_index(), world)
 
     family, preset = MODELS[args.model]
     if family != 'llama':
@@ -83,15 +82,23 @@ def main(argv=None) -> None:
                          f'llama-* presets here for now')
     config = getattr(llama.LlamaConfig, preset)(max_seq_len=args.seq)
 
-    n = len(jax.devices())
+    device = jax_env.device_summary()
+    n = device['count']
     fsdp = args.fsdp or n // (args.dp * args.tp)
     mesh = mesh_lib.make_mesh(dp=args.dp, fsdp=fsdp, tp=args.tp)
-    logger.info('devices=%d mesh dp=%d fsdp=%d tp=%d model=%s (%.0fM)',
-                n, args.dp, fsdp, args.tp, args.model,
-                config.num_params / 1e6)
+    logger.info(
+        'platform=%s device_kind=%s devices=%d mesh dp=%d fsdp=%d tp=%d '
+        'model=%s (%.0fM) attention=%s compile_cache=%s',
+        device['platform'], device['device_kind'], n, args.dp, fsdp,
+        args.tp, args.model, config.num_params / 1e6,
+        attention_lib.resolve_impl(config.attention_impl, args.seq),
+        cache_dir)
 
-    opt = trainer.make_optimizer(learning_rate=args.lr,
-                                 total_steps=args.steps)
+    # A run shorter than the warm-up would never leave it (bf16 params
+    # round away updates that small, so the loss would not move).
+    opt = trainer.make_optimizer(
+        learning_rate=args.lr, total_steps=args.steps,
+        warmup_steps=min(100, max(1, args.steps // 10)))
     step_fn = trainer.make_train_step(config, opt, mesh=mesh)
 
     start_step = 0
@@ -121,6 +128,10 @@ def main(argv=None) -> None:
     t_last = time.perf_counter()
     for step in range(start_step, args.steps):
         state, metrics = step_fn(state, batch)
+        if step == start_step:
+            jax.block_until_ready(metrics['loss'])
+            logger.info('first step (compile + run): %.1fs',
+                        time.perf_counter() - t_last)
         if (step + 1) % args.log_every == 0 or step + 1 == args.steps:
             loss = float(metrics['loss'])
             dt = time.perf_counter() - t_last
@@ -136,6 +147,14 @@ def main(argv=None) -> None:
     if mgr is not None:
         mgr.wait()
         mgr.close()
+    # Placement, as the devices hold it: shards of the largest weight
+    # per device and bytes in use (None where the backend keeps none).
+    shards = collections.Counter(
+        s.device.id for s in
+        state.params['layers']['w_gate'].addressable_shards)
+    logger.info('placement: %s', json.dumps({
+        'w_gate_shards_per_device': dict(sorted(shards.items())),
+        'bytes_in_use': jax_env.device_memory()}))
     logger.info('done: %d steps', args.steps)
 
 

@@ -109,13 +109,20 @@ def make_train_step(config: llama.LlamaConfig,
     repl = NamedSharding(mesh, P())
     state_shard = TrainState(step=repl, params=p_shard, opt_state=o_shard)
     batch_shard = sharding_lib.batch_sharding(mesh)
-    return jax.jit(
+    jitted = jax.jit(
         step_fn,
         in_shardings=(state_shard,
                       {'tokens': batch_shard, 'targets': batch_shard}),
         out_shardings=(state_shard,
                        {'loss': repl, 'grad_norm': repl, 'step': repl}),
         donate_argnums=(0,))
+
+    def sharded_step(state, batch):
+        # The mesh is ambient while the step traces, so code that must
+        # run per shard (the Pallas attention kernels) can see it.
+        with jax.set_mesh(mesh):
+            return jitted(state, batch)
+    return sharded_step
 
 
 def synthetic_batch(config: llama.LlamaConfig, batch_size: int,

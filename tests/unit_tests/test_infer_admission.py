@@ -268,6 +268,12 @@ def test_server_drain_endpoint_completes_inflight_then_reports(params):
             m = await (await client.get('/metrics')).json()
             assert m['draining'] is True
             assert m['drain_duration_s'] is not None
+            # The replica names its own device and compiled programs.
+            assert m['device'] == {'platform': 'cpu',
+                                   'device_kind': 'cpu',
+                                   'count': len(jax.devices())}
+            assert m['compiled_programs']['decode'] == 1
+            assert min(m['compiled_programs'].values()) >= 0
         finally:
             await client.close()
             srv._stop.set()

@@ -7,8 +7,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from skypilot_tpu.parallel import shard_map
 
 from skypilot_tpu.ops import attention, ring_attention
 

@@ -647,6 +647,9 @@ def test_replica_metrics_prometheus_format_end_to_end():
         def kv_index_armed(self):
             return False
 
+        def compiled_counts(self):
+            return {'decode': 1}
+
     srv = infer_server.InferenceServer.__new__(
         infer_server.InferenceServer)
     srv.engine = _FakeEngine()
@@ -655,6 +658,8 @@ def test_replica_metrics_prometheus_format_end_to_end():
     srv._requests_shed = 0
     srv.drain_duration_s = None
     srv.role = 'mixed'
+    srv.device = {'platform': 'cpu', 'device_kind': 'cpu', 'count': 1}
+    srv.compile_cache_dir = ''
 
     class _Req:
         def __init__(self, query):

@@ -35,13 +35,28 @@ def test_resnet_forward_and_train_step():
     assert gnorm > 0
 
 
-def test_train_run_entry_with_checkpoint_resume(tmp_path):
+@pytest.fixture
+def _restore_compile_cache_config():
+    """train.run attaches the persistent compile cache process-wide;
+    the rest of the session must not inherit it."""
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update('jax_compilation_cache_dir', before)
+
+
+def test_train_run_entry_with_checkpoint_resume(
+        tmp_path, caplog, _restore_compile_cache_config):
     ckpt = str(tmp_path / 'ckpts')
     args = ['--model', 'llama-tiny', '--steps', '4', '--batch', '4',
             '--seq', '16', '--fsdp', '4', '--tp', '2',
             '--checkpoint-dir', ckpt, '--checkpoint-every', '2',
             '--log-every', '2']
-    train_run.main(args)
+    with caplog.at_level('INFO'):
+        train_run.main(args)
+    # The boot line says what the run computes on and caches in.
+    assert ('platform=cpu device_kind=cpu devices=8 mesh dp=1 fsdp=4 tp=2'
+            in caplog.text)
+    assert 'attention=dense compile_cache=' in caplog.text
     saved = glob.glob(os.path.join(ckpt, '*'))
     assert saved, 'no checkpoints written'
     # Resume: start_step comes from the checkpoint; finishes instantly.
