@@ -52,7 +52,6 @@ from skypilot_tpu.infer import sampling as sampling_lib
 from skypilot_tpu.infer import sched as sched_lib
 from skypilot_tpu.models import llama
 from skypilot_tpu.observability import stepline as stepline_lib
-from skypilot_tpu.observability import trace
 from skypilot_tpu.utils import failpoints
 from skypilot_tpu.utils import prefix_hash
 
@@ -730,13 +729,16 @@ class InferenceEngine:
         self._stepline = (stepline_lib.StepRecorder(
             self.ecfg.stepline_cap) if self._sl_on else None)
         self._pending_dumps: List[tuple] = []
-        # Engine-thread stage accumulators, reset at each step start
-        # (plain floats, never read cross-thread): dispatch = device
-        # program launches, drain = consume bookkeeping, readback =
-        # blocked on the pair's device→host copy.
-        self._sl_dispatch = 0.0
-        self._sl_drain = 0.0
-        self._sl_readback = 0.0
+        # Engine-thread stage timer, reset at each step start (never
+        # read cross-thread): `with self._stage('dispatch'):` adds the
+        # block's wall time to the step's record and annotates it in a
+        # profiler trace. dispatch = device program launches, drain =
+        # consume bookkeeping, readback = blocked on the pair's
+        # device→host copy, sched = the step's admission section.
+        self._sl_clock = (stepline_lib.StageClock() if self._sl_on
+                          else None)
+        self._stage = (self._sl_clock.stage if self._sl_on
+                       else stepline_lib.no_stage)
         self._sl_batch = 0
 
         # ---- compiled programs ------------------------------------------
@@ -1016,7 +1018,9 @@ class InferenceEngine:
                resume_tokens: Optional[Sequence[int]] = None,
                deadline: Optional[float] = None,
                tenant: str = sched_lib.DEFAULT_TENANT,
-               spec: bool = True) -> Request:
+               spec: bool = True,
+               recv_t: Optional[float] = None,
+               lb_recv_t: Optional[float] = None) -> Request:
         """Queue a request. ``resume_tokens`` continues a stream whose
         earlier tokens were already delivered elsewhere (mid-stream
         failover): they are pre-seeded into ``output_tokens``, so
@@ -1027,7 +1031,10 @@ class InferenceEngine:
         step loop. ``tenant`` is the fair-queueing identity
         (X-SkyTpu-Tenant). ``spec=False`` opts this request out of
         speculative drafting (outputs are identical; only step count
-        changes — the bench's spec-off baseline lane). Raises
+        changes — the bench's spec-off baseline lane). ``recv_t`` /
+        ``lb_recv_t`` are the wall-clock moments the server's handler
+        and the serve LB received the request: observed only, they
+        ride the flight recorder's ``submit`` event. Raises
         :class:`AdmissionError` when the scheduler's (global or
         per-tenant) queue bound is hit."""
         if not prompt_tokens:
@@ -1112,7 +1119,11 @@ class InferenceEngine:
                         req.submitted_at,
                         prompt_tokens=len(req.prompt_tokens),
                         **({'resumed_from': req.resumed_from}
-                           if req.resumed_from else {}))
+                           if req.resumed_from else {}),
+                        **({'recv_t': recv_t}
+                           if recv_t is not None else {}),
+                        **({'lb_recv_t': lb_recv_t}
+                           if lb_recv_t is not None else {}))
         finally:
             # Outside the lock: the dump handoff takes the writer's
             # own condition, which must never nest under the engine
@@ -1470,25 +1481,35 @@ class InferenceEngine:
         program (no host sync). Returns True when the prompt is now
         fully cached."""
         self._note_first_dispatch(plan.req)
-        t_d = time.perf_counter() if self._sl_on else 0.0
-        if self.allocator is not None:
-            self.cache, self._last_dev = self._prefill_chunk(
-                self.cache, self.params, jnp.int32(plan.slot),
-                plan.table_row, jnp.asarray(plan.padded),
-                jnp.int32(plan.off), jnp.int32(plan.tl),
-                self._next_key(), jnp.float32(plan.req.temperature),
-                self._last_dev)
-        else:
-            self.cache, self._last_dev = self._prefill_chunk(
-                self.cache, self.params, jnp.int32(plan.slot),
-                jnp.asarray(plan.padded), jnp.int32(plan.off),
-                jnp.int32(plan.tl), self._next_key(),
-                jnp.float32(plan.req.temperature), self._last_dev)
-        if self._sl_on:
-            self._sl_dispatch += time.perf_counter() - t_d
+        with self._stage('dispatch'):
+            if self.allocator is not None:
+                self.cache, self._last_dev = self._prefill_chunk(
+                    self.cache, self.params, jnp.int32(plan.slot),
+                    plan.table_row, jnp.asarray(plan.padded),
+                    jnp.int32(plan.off), jnp.int32(plan.tl),
+                    self._next_key(),
+                    jnp.float32(plan.req.temperature), self._last_dev)
+            else:
+                self.cache, self._last_dev = self._prefill_chunk(
+                    self.cache, self.params, jnp.int32(plan.slot),
+                    jnp.asarray(plan.padded), jnp.int32(plan.off),
+                    jnp.int32(plan.tl), self._next_key(),
+                    jnp.float32(plan.req.temperature), self._last_dev)
         with self._lock:
             self._prefill_tokens += plan.tl
+            self._note_prefill_dispatched(plan)
         return self._note_chunk_dispatched(plan)
+
+    def _note_prefill_dispatched(self,  # holds: _lock
+                                 plan: _ChunkPlan) -> None:
+        """Timeline event: the chunk just dispatched was the LAST of
+        the request's prompt. From here to ``first_token`` lie that
+        step's device time and the dispatch-ahead pipeline's
+        stale-by-one consume."""
+        if self._sl_on and plan.off + plan.tl >= plan.total:
+            self._stepline.note_event(
+                plan.req.request_id, plan.req.tenant,
+                'prefill_dispatched', time.time(), off=plan.off)
 
     def _note_chunk_dispatched(self, plan: _ChunkPlan) -> bool:
         """Post-dispatch bookkeeping shared by the standalone and
@@ -1784,12 +1805,6 @@ class InferenceEngine:
                 and s not in self._prefilling]
 
     # ---- the step --------------------------------------------------------
-    # Traced only when SKY_TPU_TRACE is set at process start (the
-    # decorator returns `step` unchanged otherwise — this loop runs per
-    # token and must stay wrapper-free by default). min_dur_s filters
-    # steady-state decode ticks: only outliers (prefill-bucket compiles,
-    # long chunk batches) are worth a span.
-    @trace.traced(name='engine.step', hop='infer', min_dur_s=0.05)
     def step(self) -> int:
         """Refill free slots, advance at most ``prefill_chunks_per_step``
         prefill chunks (round-robin across prefilling slots), then decode
@@ -1799,21 +1814,23 @@ class InferenceEngine:
         With the flight recorder on (the default), the step body runs
         between a counter pre-snapshot and a ring append: the record
         is derived purely from clocks and counter deltas, so the
-        recorded step is bit-identical to the unrecorded one."""
+        recorded step is bit-identical to the unrecorded one. The
+        step and its stages are also ``jax.profiler`` annotations
+        (``engine.step`` carries the record's index as ``step_num``):
+        a profiler trace shows them beside the device's operations."""
         if not self._sl_on:
             return self._step_inner()
         t0 = time.perf_counter()
         t_wall = time.time()
-        self._sl_dispatch = 0.0
-        self._sl_drain = 0.0
-        self._sl_readback = 0.0
         self._sl_batch = 0
         with self._lock:
             pre = (self._prefill_tokens, self._spec_drafted,
                    self._spec_accepted, self._decode_steps,
                    self._spec_steps, self._fused_steps,
                    self._decode_tokens)
-        worked = self._step_inner()
+            idx = self._stepline.steps.total
+        with self._sl_clock.step(idx):
+            worked = self._step_inner()
         self._sl_record(t_wall, time.perf_counter() - t0, pre)
         self._flush_stepline_dumps()
         return worked
@@ -1825,7 +1842,7 @@ class InferenceEngine:
         on-device and must not block submit() (which HTTP handlers call
         from the event loop)."""
         self._service_kv_jobs()
-        with self._lock:
+        with self._stage('sched'), self._lock:
             self._sweep_dead_requests()
             spec_k = self._spec_k
             for slot in range(self.ecfg.n_slots):
@@ -2054,24 +2071,23 @@ class InferenceEngine:
         ``_consume_one``. Decode N+1 depends only on ``_last_dev`` and
         the cache — both device-resident — so it never waits for the
         host to have READ step N."""
-        t_d = time.perf_counter() if self._sl_on else 0.0
-        self._refresh_dispatch_state(decoding)
-        if self.allocator is not None:
-            pair, self.cache = self._decode(
-                self.cache, self.params, self._table_dev,
-                self._last_dev, self._next_key(), self._temps_dev,
-                self._active_dev)
-        else:
-            pair, self.cache = self._decode(
-                self.cache, self.params, self._last_dev,
-                self._next_key(), self._temps_dev, self._active_dev)
-        self._last_dev = pair[1]
-        # Overlap the readback with everything that follows: by consume
-        # time the bytes are (usually) already on the host.
-        pair.copy_to_host_async()
-        if self._sl_on:
-            self._sl_dispatch += time.perf_counter() - t_d
-            self._sl_batch = len(decoding)
+        with self._stage('dispatch'):
+            self._refresh_dispatch_state(decoding)
+            if self.allocator is not None:
+                pair, self.cache = self._decode(
+                    self.cache, self.params, self._table_dev,
+                    self._last_dev, self._next_key(), self._temps_dev,
+                    self._active_dev)
+            else:
+                pair, self.cache = self._decode(
+                    self.cache, self.params, self._last_dev,
+                    self._next_key(), self._temps_dev,
+                    self._active_dev)
+            self._last_dev = pair[1]
+            # Overlap the readback with everything that follows: by
+            # consume time the bytes are (usually) already on the host.
+            pair.copy_to_host_async()
+        self._sl_batch = len(decoding)
         with self._lock:
             # Under the lock so metrics()' tokens_in_flight sum never
             # reads a half-applied increment batch (consume decrements
@@ -2112,35 +2128,34 @@ class InferenceEngine:
         pair row 0 (the prefilled list) and joins the NEXT step's
         decode — one extra step, zero token-sequence difference
         (greedy outputs are gated bit-identical fused on vs off)."""
-        t_d = time.perf_counter() if self._sl_on else 0.0
-        self._refresh_dispatch_state(decoding)
-        self._note_first_dispatch(plan.req)
-        chunk_key = self._next_key()
-        dec_key = self._next_key()
-        if self.allocator is not None:
-            pair, self.cache = self._mixed(
-                self.cache, self.params, jnp.int32(plan.slot),
-                plan.table_row, jnp.asarray(plan.padded),
-                jnp.int32(plan.off), jnp.int32(plan.tl), chunk_key,
-                jnp.float32(plan.req.temperature), self._table_dev,
-                self._last_dev, dec_key, self._temps_dev,
-                self._active_dev)
-        else:
-            pair, self.cache = self._mixed(
-                self.cache, self.params, jnp.int32(plan.slot),
-                jnp.asarray(plan.padded), jnp.int32(plan.off),
-                jnp.int32(plan.tl), chunk_key,
-                jnp.float32(plan.req.temperature), self._last_dev,
-                dec_key, self._temps_dev, self._active_dev)
-        self._last_dev = pair[1]
-        pair.copy_to_host_async()
-        if self._sl_on:
-            self._sl_dispatch += time.perf_counter() - t_d
-            self._sl_batch = len(decoding)
+        with self._stage('dispatch'):
+            self._refresh_dispatch_state(decoding)
+            self._note_first_dispatch(plan.req)
+            chunk_key = self._next_key()
+            dec_key = self._next_key()
+            if self.allocator is not None:
+                pair, self.cache = self._mixed(
+                    self.cache, self.params, jnp.int32(plan.slot),
+                    plan.table_row, jnp.asarray(plan.padded),
+                    jnp.int32(plan.off), jnp.int32(plan.tl), chunk_key,
+                    jnp.float32(plan.req.temperature),
+                    self._table_dev, self._last_dev, dec_key,
+                    self._temps_dev, self._active_dev)
+            else:
+                pair, self.cache = self._mixed(
+                    self.cache, self.params, jnp.int32(plan.slot),
+                    jnp.asarray(plan.padded), jnp.int32(plan.off),
+                    jnp.int32(plan.tl), chunk_key,
+                    jnp.float32(plan.req.temperature), self._last_dev,
+                    dec_key, self._temps_dev, self._active_dev)
+            self._last_dev = pair[1]
+            pair.copy_to_host_async()
+        self._sl_batch = len(decoding)
         with self._lock:
             self._decode_steps += 1
             self._fused_steps += 1
             self._prefill_tokens += plan.tl
+            self._note_prefill_dispatched(plan)
             for s in decoding:
                 self._inflight_tok[s] += 1
         completes = self._note_chunk_dispatched(plan)
@@ -2243,24 +2258,23 @@ class InferenceEngine:
         The [spec_k+3, slots] pair rides the in-flight queue exactly
         like a decode pair; consume applies host bookkeeping per
         emitted token and rolls rejected pages back."""
-        t_d = time.perf_counter() if self._sl_on else 0.0
-        self._refresh_dispatch_state(decoding)
-        drafts_dev = jnp.asarray(draft_mat)
-        lens_dev = jnp.asarray(draft_lens)
-        if self.allocator is not None:
-            pair, self._last_dev, self.cache = self._verify(
-                self.cache, self.params, self._table_dev,
-                self._last_dev, drafts_dev, lens_dev,
-                self._next_key(), self._temps_dev, self._active_dev)
-        else:
-            pair, self._last_dev, self.cache = self._verify(
-                self.cache, self.params, self._last_dev, drafts_dev,
-                lens_dev, self._next_key(), self._temps_dev,
-                self._active_dev)
-        pair.copy_to_host_async()
-        if self._sl_on:
-            self._sl_dispatch += time.perf_counter() - t_d
-            self._sl_batch = len(decoding)
+        with self._stage('dispatch'):
+            self._refresh_dispatch_state(decoding)
+            drafts_dev = jnp.asarray(draft_mat)
+            lens_dev = jnp.asarray(draft_lens)
+            if self.allocator is not None:
+                pair, self._last_dev, self.cache = self._verify(
+                    self.cache, self.params, self._table_dev,
+                    self._last_dev, drafts_dev, lens_dev,
+                    self._next_key(), self._temps_dev,
+                    self._active_dev)
+            else:
+                pair, self._last_dev, self.cache = self._verify(
+                    self.cache, self.params, self._last_dev,
+                    drafts_dev, lens_dev, self._next_key(),
+                    self._temps_dev, self._active_dev)
+            pair.copy_to_host_async()
+        self._sl_batch = len(decoding)
         with self._lock:
             self._decode_steps += 1
             self._spec_steps += 1
@@ -2282,14 +2296,19 @@ class InferenceEngine:
         drops its token — for greedy decoding the resume path recomputes
         the identical token, so outputs are depth-invariant."""
         pair, decoded, prefilled, spec_r = self._queue.popleft()
-        t_rb = time.perf_counter() if self._sl_on else 0.0
-        pair_host = np.asarray(pair)   # sync point (copy already async)
-        if self._sl_on:
-            # Readback = blocked on the device→host copy; everything
-            # after is drain (host bookkeeping catching up). Both
-            # accumulate into the current step's record.
-            t_bk = time.perf_counter()
-            self._sl_readback += t_bk - t_rb
+        # Readback = blocked on the device→host copy; everything after
+        # is drain (host bookkeeping catching up). Both accumulate
+        # into the current step's record.
+        with self._stage('readback'):
+            pair_host = np.asarray(pair)   # sync point (copy async)
+        with self._stage('drain'):
+            self._apply_pair(pair_host, decoded, prefilled, spec_r)
+
+    def _apply_pair(self, pair_host: 'np.ndarray', decoded: List[tuple],
+                    prefilled: List[tuple],
+                    spec_r: Optional[int]) -> None:
+        """The host bookkeeping of one consumed pair (the drain stage
+        of :meth:`_consume_one`)."""
         now = time.time()
         bad: set = set()
         if self._sentinel:
@@ -2351,8 +2370,6 @@ class InferenceEngine:
         for req in touched:
             if not req.done:       # _finish already notified
                 req._notify()
-        if self._sl_on:
-            self._sl_drain += time.perf_counter() - t_bk
 
     def _consume_verify(self, pair_host, decoded, spec_r,
                         touched, bad=()) -> None:  # holds: _lock
@@ -2563,6 +2580,17 @@ class InferenceEngine:
                                       t if t is not None else time.time(),
                                       **detail)
 
+    def note_request_event(self, req: Request, event: str) -> None:
+        """Stamp a front-end moment of ``req`` (the server's
+        ``first_flush``: its first token line has left the handler)
+        onto the request's timeline, now. One brief lock take a
+        request, as ``submit`` has; never on the per-token path."""
+        if not self._sl_on:
+            return
+        with self._lock:
+            self._stepline.note_event(req.request_id, req.tenant,
+                                      event, time.time())
+
     def _note_anomaly(self, trigger: str,  # holds: _lock
                       detail: Dict[str, Any]) -> None:
         """Record the anomaly in the event ring and queue a ring dump
@@ -2617,6 +2645,7 @@ class InferenceEngine:
         read that the step loop acts on)."""
         (pre_pref, pre_drafted, pre_accepted, pre_steps, pre_spec,
          pre_fused, pre_tok) = pre
+        acc = self._sl_clock.acc
         with self._lock:
             d_disp = self._decode_steps - pre_steps
             d_chunk = self._prefill_tokens - pre_pref
@@ -2627,7 +2656,7 @@ class InferenceEngine:
                         else 'decode')
             elif d_chunk:
                 kind = 'prefill'
-            elif d_tok or self._sl_readback or self._sl_drain:
+            elif d_tok or acc['readback'] or acc['drain']:
                 # Consumes only: the step drained in-flight results /
                 # freed finishing slots without dispatching new work.
                 kind = 'free'
@@ -2647,9 +2676,10 @@ class InferenceEngine:
             self._stepline.note_step(stepline_lib.StepRecord(
                 idx=self._stepline.steps.total,
                 t=t_wall, dur_s=dur, kind=kind,
-                dispatch_s=self._sl_dispatch,
-                drain_s=self._sl_drain,
-                readback_s=self._sl_readback,
+                dispatch_s=acc['dispatch'],
+                drain_s=acc['drain'],
+                readback_s=acc['readback'],
+                sched_s=acc['sched'],
                 batch=self._sl_batch,
                 chunk_tokens=d_chunk,
                 prefilling=len(self._prefilling),
@@ -2975,7 +3005,9 @@ class EnginePool:
                resume_tokens: Optional[Sequence[int]] = None,
                deadline: Optional[float] = None,
                tenant: str = sched_lib.DEFAULT_TENANT,
-               spec: bool = True) -> Request:
+               spec: bool = True,
+               recv_t: Optional[float] = None,
+               lb_recv_t: Optional[float] = None) -> Request:
         n = len(prompt_tokens) + len(resume_tokens or ())
         for eng in self.engines:
             if n <= eng.ecfg.max_seq_len - 1:
@@ -2983,7 +3015,8 @@ class EnginePool:
                                   temperature,
                                   resume_tokens=resume_tokens,
                                   deadline=deadline, tenant=tenant,
-                                  spec=spec)
+                                  spec=spec, recv_t=recv_t,
+                                  lb_recv_t=lb_recv_t)
         raise ValueError(
             f'prompt ({n} tokens) exceeds every pool tier '
             f'(largest: {self.engines[-1].ecfg.max_seq_len - 1})')
@@ -3063,6 +3096,11 @@ class EnginePool:
         """Lifecycle milestones land on tier 0 (the merged snapshot
         interleaves them with every tier's requests anyway)."""
         self.engines[0].note_lifecycle_event(event, t, **detail)
+
+    def note_request_event(self, req: Request, event: str) -> None:
+        # Tier i hands out the ids i+1, i+1+n, ... (see __init__).
+        self.engines[(req.request_id - 1) % len(self.engines)
+                     ].note_request_event(req, event)
 
     def stepline_snapshot(self) -> Dict[str, Any]:
         """Merged flight-recorder snapshot across tiers (records

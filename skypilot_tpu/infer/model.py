@@ -13,6 +13,14 @@ Same weights, two execution shapes:
 
 Both are pure functions jitted by the engine with buffer donation on the
 cache (XLA updates it in place).
+
+The paged step programs name their parts with ``jax.named_scope``:
+``embed``, then per layer ``attn`` (norm, projections, rope, the
+attention kernel, the output projection), ``kv_write`` (the K/V rows
+into the pages) and ``mlp``, then ``head``; the sampler adds
+``sample``. A scope changes no instruction, only the ``op_name`` in
+its metadata, by which a profiler trace's device events can be
+grouped.
 """
 from __future__ import annotations
 
@@ -140,7 +148,8 @@ def paged_prefill_chunk(config: llama.LlamaConfig, params: llama.Params,
     work must not assume offset % C == 0.
     """
     C = tokens.shape[0]
-    x = quant_lib.qembed(params['embed'], tokens)[None]   # [1, C, d]
+    with jax.named_scope('embed'):
+        x = quant_lib.qembed(params['embed'], tokens)[None]  # [1, C, d]
     cos, sin = rope_lib.rope_frequencies(config.head_dim,
                                          config.max_seq_len,
                                          config.rope_theta)
@@ -155,11 +164,12 @@ def paged_prefill_chunk(config: llama.LlamaConfig, params: llama.Params,
 
     x, ys = jax.lax.scan(body, x, _layer_xs(params, pkv))
     k_upd, v_upd, ks_upd, vs_upd = _unpack_layer_upd(pkv, ys)
-    x = norms.rms_norm(x, params['final_norm'], config.norm_eps)
-    last = jax.lax.dynamic_index_in_dim(x[0], true_len - 1, axis=0,
-                                        keepdims=False)
-    logits = quant_lib.qdot(last,
-                            params['lm_head']).astype(jnp.float32)
+    with jax.named_scope('head'):
+        x = norms.rms_norm(x, params['final_norm'], config.norm_eps)
+        last = jax.lax.dynamic_index_in_dim(x[0], true_len - 1, axis=0,
+                                            keepdims=False)
+        logits = quant_lib.qdot(last,
+                                params['lm_head']).astype(jnp.float32)
     lengths = pkv.lengths.at[slot].set(
         (offset + true_len).astype(jnp.int32))
     return paged_cache_lib.PagedKVCache(
@@ -205,31 +215,35 @@ def _paged_chunk_layer(config, x, layer, cos, sin, k_pages, v_pages,
     hq, hkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     group = hq // hkv
 
-    h = norms.rms_norm(x, layer['attn_norm'], config.norm_eps)
-    q = quant_lib.qdot(h, layer['wq']).reshape(1, C, hq, hd)
-    k = quant_lib.qdot(h, layer['wk']).reshape(1, C, hkv, hd)
-    v = quant_lib.qdot(h, layer['wv']).reshape(1, C, hkv, hd)
-    q = rope_lib.apply_rope(q, cos, sin, positions[None])
-    k = rope_lib.apply_rope(k, cos, sin, positions[None])
+    with jax.named_scope('attn'):
+        h = norms.rms_norm(x, layer['attn_norm'], config.norm_eps)
+        q = quant_lib.qdot(h, layer['wq']).reshape(1, C, hq, hd)
+        k = quant_lib.qdot(h, layer['wk']).reshape(1, C, hkv, hd)
+        v = quant_lib.qdot(h, layer['wv']).reshape(1, C, hkv, hd)
+        q = rope_lib.apply_rope(q, cos, sin, positions[None])
+        k = rope_lib.apply_rope(k, cos, sin, positions[None])
 
     # Write-then-attend, page edition (quant-on-write on int8 pages:
     # the chunk's own self-attention reads its rows back dequantized,
     # exactly what every later decode step will see).
-    if k_scales is not None:
-        k_pages, v_pages, k_scales, v_scales = (
-            paged_attn.write_chunk_pages(k_pages, v_pages, k[0], v[0],
-                                         table_row, offset,
-                                         k_scales, v_scales))
-    else:
-        k_pages, v_pages = paged_attn.write_chunk_pages(
-            k_pages, v_pages, k[0], v[0], table_row, offset)
-    qg = q[0].reshape(C, hkv, group, hd)
-    att = paged_attn.paged_prefill_attention(
-        qg, k_pages, v_pages, table_row, offset, true_len,
-        k_scales=k_scales, v_scales=v_scales)
-    att = att.reshape(1, C, hq * hd).astype(x.dtype)
-    x = x + quant_lib.qdot(att, layer['wo'])
-    x = llama.mlp_block(config, x, layer)
+    with jax.named_scope('kv_write'):
+        if k_scales is not None:
+            k_pages, v_pages, k_scales, v_scales = (
+                paged_attn.write_chunk_pages(
+                    k_pages, v_pages, k[0], v[0], table_row, offset,
+                    k_scales, v_scales))
+        else:
+            k_pages, v_pages = paged_attn.write_chunk_pages(
+                k_pages, v_pages, k[0], v[0], table_row, offset)
+    with jax.named_scope('attn'):
+        qg = q[0].reshape(C, hkv, group, hd)
+        att = paged_attn.paged_prefill_attention(
+            qg, k_pages, v_pages, table_row, offset, true_len,
+            k_scales=k_scales, v_scales=v_scales)
+        att = att.reshape(1, C, hq * hd).astype(x.dtype)
+        x = x + quant_lib.qdot(att, layer['wo'])
+    with jax.named_scope('mlp'):
+        x = llama.mlp_block(config, x, layer)
     return x, k_pages, v_pages, k_scales, v_scales
 
 
@@ -247,7 +261,8 @@ def paged_decode_step(config: llama.LlamaConfig, params: llama.Params,
     lengths[slot] (the incoming token's write target).
     """
     positions = pkv.lengths
-    x = quant_lib.qembed(params['embed'], tokens)[:, None]
+    with jax.named_scope('embed'):
+        x = quant_lib.qembed(params['embed'], tokens)[:, None]
     cos, sin = rope_lib.rope_frequencies(config.head_dim,
                                          config.max_seq_len,
                                          config.rope_theta)
@@ -261,9 +276,10 @@ def paged_decode_step(config: llama.LlamaConfig, params: llama.Params,
 
     x, ys = jax.lax.scan(body, x, _layer_xs(params, pkv))
     k_upd, v_upd, ks_upd, vs_upd = _unpack_layer_upd(pkv, ys)
-    x = norms.rms_norm(x, params['final_norm'], config.norm_eps)
-    logits = quant_lib.qdot(x[:, 0],
-                            params['lm_head']).astype(jnp.float32)
+    with jax.named_scope('head'):
+        x = norms.rms_norm(x, params['final_norm'], config.norm_eps)
+        logits = quant_lib.qdot(x[:, 0],
+                                params['lm_head']).astype(jnp.float32)
     bump = (jnp.ones_like(pkv.lengths) if active is None
             else active.astype(pkv.lengths.dtype))
     new_cache = paged_cache_lib.PagedKVCache(
@@ -279,31 +295,35 @@ def _paged_decode_layer(config, x, layer, cos, sin, k_pages, v_pages,
     hq, hkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     group = hq // hkv
 
-    h = norms.rms_norm(x, layer['attn_norm'], config.norm_eps)
-    q = quant_lib.qdot(h, layer['wq']).reshape(slots, 1, hq, hd)
-    k = quant_lib.qdot(h, layer['wk']).reshape(slots, 1, hkv, hd)
-    v = quant_lib.qdot(h, layer['wv']).reshape(slots, 1, hkv, hd)
-    q = rope_lib.apply_rope(q, cos, sin, positions[:, None])
-    k = rope_lib.apply_rope(k, cos, sin, positions[:, None])
+    with jax.named_scope('attn'):
+        h = norms.rms_norm(x, layer['attn_norm'], config.norm_eps)
+        q = quant_lib.qdot(h, layer['wq']).reshape(slots, 1, hq, hd)
+        k = quant_lib.qdot(h, layer['wk']).reshape(slots, 1, hkv, hd)
+        v = quant_lib.qdot(h, layer['wv']).reshape(slots, 1, hkv, hd)
+        q = rope_lib.apply_rope(q, cos, sin, positions[:, None])
+        k = rope_lib.apply_rope(k, cos, sin, positions[:, None])
 
     # Write the new K/V into the slot's current page, then attend over
     # positions <= length (the new token sees itself).
-    if k_scales is not None:
-        k_pages, v_pages, k_scales, v_scales = (
-            paged_attn.append_token_pages(
+    with jax.named_scope('kv_write'):
+        if k_scales is not None:
+            k_pages, v_pages, k_scales, v_scales = (
+                paged_attn.append_token_pages(
+                    k_pages, v_pages, k[:, 0], v[:, 0], block_tables,
+                    positions, k_scales, v_scales))
+        else:
+            k_pages, v_pages = paged_attn.append_token_pages(
                 k_pages, v_pages, k[:, 0], v[:, 0], block_tables,
-                positions, k_scales, v_scales))
-    else:
-        k_pages, v_pages = paged_attn.append_token_pages(
-            k_pages, v_pages, k[:, 0], v[:, 0], block_tables,
-            positions)
-    qg = q[:, 0].reshape(slots, hkv, group, hd)
-    att = paged_attn.paged_decode_attention(
-        qg, k_pages, v_pages, block_tables, positions + 1,
-        k_scales=k_scales, v_scales=v_scales)
-    att = att.reshape(slots, 1, hq * hd).astype(x.dtype)
-    x = x + quant_lib.qdot(att, layer['wo'])
-    x = llama.mlp_block(config, x, layer)
+                positions)
+    with jax.named_scope('attn'):
+        qg = q[:, 0].reshape(slots, hkv, group, hd)
+        att = paged_attn.paged_decode_attention(
+            qg, k_pages, v_pages, block_tables, positions + 1,
+            k_scales=k_scales, v_scales=v_scales)
+        att = att.reshape(slots, 1, hq * hd).astype(x.dtype)
+        x = x + quant_lib.qdot(att, layer['wo'])
+    with jax.named_scope('mlp'):
+        x = llama.mlp_block(config, x, layer)
     return x, k_pages, v_pages, k_scales, v_scales
 
 
@@ -399,7 +419,8 @@ def paged_verify_step(config: llama.LlamaConfig, params: llama.Params,
     slots, R = tokens.shape
     positions = pkv.lengths[:, None] + jnp.arange(
         R, dtype=jnp.int32)[None, :]                  # [slots, R]
-    x = quant_lib.qembed(params['embed'], tokens)     # [slots, R, d]
+    with jax.named_scope('embed'):
+        x = quant_lib.qembed(params['embed'], tokens)  # [slots, R, d]
     cos, sin = rope_lib.rope_frequencies(config.head_dim,
                                          config.max_seq_len,
                                          config.rope_theta)
@@ -413,8 +434,10 @@ def paged_verify_step(config: llama.LlamaConfig, params: llama.Params,
 
     x, ys = jax.lax.scan(body, x, _layer_xs(params, pkv))
     k_upd, v_upd, ks_upd, vs_upd = _unpack_layer_upd(pkv, ys)
-    x = norms.rms_norm(x, params['final_norm'], config.norm_eps)
-    logits = quant_lib.qdot(x, params['lm_head']).astype(jnp.float32)
+    with jax.named_scope('head'):
+        x = norms.rms_norm(x, params['final_norm'], config.norm_eps)
+        logits = quant_lib.qdot(x,
+                                params['lm_head']).astype(jnp.float32)
     return logits, paged_cache_lib.PagedKVCache(
         k_pages=k_upd, v_pages=v_upd, lengths=pkv.lengths,
         k_scales=ks_upd, v_scales=vs_upd)
@@ -427,29 +450,33 @@ def _paged_verify_layer(config, x, layer, cos, sin, k_pages, v_pages,
     hq, hkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     group = hq // hkv
 
-    h = norms.rms_norm(x, layer['attn_norm'], config.norm_eps)
-    q = quant_lib.qdot(h, layer['wq']).reshape(slots, R, hq, hd)
-    k = quant_lib.qdot(h, layer['wk']).reshape(slots, R, hkv, hd)
-    v = quant_lib.qdot(h, layer['wv']).reshape(slots, R, hkv, hd)
-    q = rope_lib.apply_rope(q, cos, sin, positions)
-    k = rope_lib.apply_rope(k, cos, sin, positions)
+    with jax.named_scope('attn'):
+        h = norms.rms_norm(x, layer['attn_norm'], config.norm_eps)
+        q = quant_lib.qdot(h, layer['wq']).reshape(slots, R, hq, hd)
+        k = quant_lib.qdot(h, layer['wk']).reshape(slots, R, hkv, hd)
+        v = quant_lib.qdot(h, layer['wv']).reshape(slots, R, hkv, hd)
+        q = rope_lib.apply_rope(q, cos, sin, positions)
+        k = rope_lib.apply_rope(k, cos, sin, positions)
 
     # Write-then-attend, run edition (sink-redirected past coverage).
-    if k_scales is not None:
-        k_pages, v_pages, k_scales, v_scales = (
-            paged_attn.append_run_pages(k_pages, v_pages, k, v,
-                                        block_tables, lengths,
-                                        k_scales, v_scales))
-    else:
-        k_pages, v_pages = paged_attn.append_run_pages(
-            k_pages, v_pages, k, v, block_tables, lengths)
-    qg = q.reshape(slots, R, hkv, group, hd)
-    att = paged_attn.paged_verify_attention(
-        qg, k_pages, v_pages, block_tables, lengths,
-        k_scales=k_scales, v_scales=v_scales)
-    att = att.reshape(slots, R, hq * hd).astype(x.dtype)
-    x = x + quant_lib.qdot(att, layer['wo'])
-    x = llama.mlp_block(config, x, layer)
+    with jax.named_scope('kv_write'):
+        if k_scales is not None:
+            k_pages, v_pages, k_scales, v_scales = (
+                paged_attn.append_run_pages(k_pages, v_pages, k, v,
+                                            block_tables, lengths,
+                                            k_scales, v_scales))
+        else:
+            k_pages, v_pages = paged_attn.append_run_pages(
+                k_pages, v_pages, k, v, block_tables, lengths)
+    with jax.named_scope('attn'):
+        qg = q.reshape(slots, R, hkv, group, hd)
+        att = paged_attn.paged_verify_attention(
+            qg, k_pages, v_pages, block_tables, lengths,
+            k_scales=k_scales, v_scales=v_scales)
+        att = att.reshape(slots, R, hq * hd).astype(x.dtype)
+        x = x + quant_lib.qdot(att, layer['wo'])
+    with jax.named_scope('mlp'):
+        x = llama.mlp_block(config, x, layer)
     return x, k_pages, v_pages, k_scales, v_scales
 
 
@@ -616,8 +643,9 @@ def paged_mixed_step(config: llama.LlamaConfig, params: llama.Params,
     ride ``block_tables``, same per-layer chunk-then-decode order as
     the dense version — the unfused two-dispatch state, one launch."""
     C = chunk_tokens.shape[0]
-    xc = quant_lib.qembed(params['embed'], chunk_tokens)[None]
-    xd = quant_lib.qembed(params['embed'], decode_tokens)[:, None]
+    with jax.named_scope('embed'):
+        xc = quant_lib.qembed(params['embed'], chunk_tokens)[None]
+        xd = quant_lib.qembed(params['embed'], decode_tokens)[:, None]
     cos, sin = rope_lib.rope_frequencies(config.head_dim,
                                          config.max_seq_len,
                                          config.rope_theta)
@@ -640,14 +668,15 @@ def paged_mixed_step(config: llama.LlamaConfig, params: llama.Params,
     (xc, xd), ys = jax.lax.scan(body, (xc, xd),
                                 _layer_xs(params, pkv))
     k_upd, v_upd, ks_upd, vs_upd = _unpack_layer_upd(pkv, ys)
-    xc = norms.rms_norm(xc, params['final_norm'], config.norm_eps)
-    last = jax.lax.dynamic_index_in_dim(xc[0], true_len - 1, axis=0,
-                                        keepdims=False)
-    chunk_logits = quant_lib.qdot(
-        last, params['lm_head']).astype(jnp.float32)
-    xd = norms.rms_norm(xd, params['final_norm'], config.norm_eps)
-    dec_logits = quant_lib.qdot(
-        xd[:, 0], params['lm_head']).astype(jnp.float32)
+    with jax.named_scope('head'):
+        xc = norms.rms_norm(xc, params['final_norm'], config.norm_eps)
+        last = jax.lax.dynamic_index_in_dim(xc[0], true_len - 1,
+                                            axis=0, keepdims=False)
+        chunk_logits = quant_lib.qdot(
+            last, params['lm_head']).astype(jnp.float32)
+        xd = norms.rms_norm(xd, params['final_norm'], config.norm_eps)
+        dec_logits = quant_lib.qdot(
+            xd[:, 0], params['lm_head']).astype(jnp.float32)
     bump = active.astype(lengths_mid.dtype)
     return chunk_logits, dec_logits, paged_cache_lib.PagedKVCache(
         k_pages=k_upd, v_pages=v_upd, lengths=lengths_mid + bump,

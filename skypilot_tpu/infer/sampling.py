@@ -19,6 +19,7 @@ class SamplingParams:
             raise ValueError('temperature must be >= 0')
 
 
+@jax.named_scope('sample')
 def speculative_accept(logits: jnp.ndarray, drafts: jnp.ndarray,
                        draft_len: jnp.ndarray, key: jax.Array,
                        temperature: jnp.ndarray, top_k: int = 0
@@ -55,6 +56,7 @@ def speculative_accept(logits: jnp.ndarray, drafts: jnp.ndarray,
     return emitted, accepted
 
 
+@jax.named_scope('sample')
 def sample(logits: jnp.ndarray, key: jax.Array,
            temperature: jnp.ndarray, top_k: int = 0) -> jnp.ndarray:
     """logits [slots, vocab], temperature [slots] → tokens [slots].

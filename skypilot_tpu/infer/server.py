@@ -184,6 +184,16 @@ def parse_tenant_weights(spec: Optional[str]) -> Optional[dict]:
     return out or None
 
 
+def _header_time(value: Optional[str]) -> Optional[float]:
+    """A wall-clock stamp another hop forwarded as a header
+    (common.LB_RECV_HEADER). It is telemetry: a missing or malformed
+    value is no stamp, never a client error."""
+    try:
+        return float(value) if value else None
+    except ValueError:
+        return None
+
+
 def setup_compile_cache(cache_dir: Optional[str] = None) -> bool:
     """Attach XLA's persistent compilation cache (utils/jax_env.py
     resolves where: ``JAX_COMPILATION_CACHE_DIR`` wins, then
@@ -692,6 +702,7 @@ class InferenceServer:
                 self._mark_drained()
 
     async def _admit_generate(self, request: web.Request) -> web.Response:
+        recv_t = time.time()    # the request's timeline on this replica
         if self.engine.integrity_suspect():
             # The SDC sentinel tripped: this device emits garbage —
             # shed EVERYTHING with the quarantined marker. The LB
@@ -817,7 +828,10 @@ class InferenceServer:
                         # false) — the spec-off baseline lane of
                         # bench_ttft --sweep speculative; outputs are
                         # bit-identical either way.
-                        spec=bool(body.get('spec', True)))
+                        spec=bool(body.get('spec', True)),
+                        recv_t=recv_t,
+                        lb_recv_t=_header_time(request.headers.get(
+                            common_lib.LB_RECV_HEADER)))
         except engine_lib.AdmissionError as e:
             # Bounded admission: shed with 429 + Retry-After instead of
             # queueing unboundedly (the LB tries other replicas first).
@@ -861,6 +875,7 @@ class InferenceServer:
             if sent:
                 decoder.feed(req.output_tokens, sent)
             waiter = _TokenWaiter(req)
+            flushed = False
             try:
                 while True:
                     if self.dead:
@@ -879,6 +894,13 @@ class InferenceServer:
                              'text': delta}).encode()
                             + b'\n')
                         sent = n
+                        if not flushed:
+                            # The first token line has left the
+                            # handler: the last stamp of the time to
+                            # first token that this replica can take.
+                            flushed = True
+                            self.engine.note_request_event(
+                                req, 'first_flush')
                     if done and sent == len(req.output_tokens):
                         tail = decoder.flush(req.output_tokens, sent)
                         if tail:

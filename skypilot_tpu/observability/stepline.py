@@ -6,9 +6,17 @@ always-on, low-overhead ring of per-engine-step records (one compact
 :class:`StepRecord` per worked step — kind, dispatch/drain/readback
 wall shares, batch/chunk sizes, speculation accept counts, page
 pressure, queue depth per tenant) plus a per-request timeline ring
-(submit → first_dispatch → first_token → done, with resume / cancel /
-shed events), both appended by the engine step loop under the
+(submit → first_dispatch → prefill_dispatched → first_token →
+first_flush → done, with resume / cancel / shed events; the
+``submit`` event carries the server's ``recv_t`` and the LB's
+``lb_recv_t`` where there were any), both appended under the
 engine's ``_lock``.
+
+The step loop times its stages through :class:`StageClock`, which
+feeds the step record AND opens ``jax.profiler`` annotations
+(``engine.step`` with its ``step_num``, ``engine.dispatch`` /
+``engine.drain`` / ``engine.readback`` / ``engine.sched``): a profiler
+trace of the replica shows the stages on the device's clock.
 
 Three export paths:
 
@@ -38,6 +46,7 @@ fused/pipeline/spec golden tests).
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import os
 import threading
@@ -78,18 +87,15 @@ def dump_interval_s() -> float:
         return DEFAULT_DUMP_INTERVAL_S
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(slots=True)
 class StepRecord:
     """One engine step, compactly. All times are wall seconds; the
     stage shares are DISJOINT: ``dispatch_s`` (device program
     launches), ``drain_s`` (consume bookkeeping while catching host
     state up), ``readback_s`` (blocked on the device→host pair copy),
-    and host = ``dur_s`` minus the three."""
-    __slots__ = ('idx', 't', 'dur_s', 'kind', 'dispatch_s', 'drain_s',
-                 'readback_s', 'batch', 'chunk_tokens', 'prefilling',
-                 'spec_drafted', 'spec_accepted', 'pages_free',
-                 'prefix_evictions', 'preemptions', 'queue_depth',
-                 'tenant_depths')
+    and host = ``dur_s`` minus the three. ``sched_s`` (the step's
+    admission / sweep section under the engine lock) is a part OF
+    host, not taken out of it."""
     idx: int                 # monotonic step index (survives wrap)
     t: float                 # wall-clock step start
     dur_s: float
@@ -107,6 +113,7 @@ class StepRecord:
     preemptions: int         # cumulative
     queue_depth: int
     tenant_depths: Optional[Dict[str, int]]   # None when single-tenant
+    sched_s: float = 0.0     # part of host_s()
 
     def host_s(self) -> float:
         return max(0.0, self.dur_s - self.dispatch_s - self.drain_s
@@ -116,6 +123,64 @@ class StepRecord:
         d = {k: getattr(self, k) for k in self.__slots__}
         d['host_s'] = self.host_s()
         return d
+
+
+class _Stage:
+    """One open stage of a step: its wall time goes to the clock's
+    accumulator of that name, and the interval is an ``engine.<name>``
+    annotation in a profiler trace, should one be running."""
+    __slots__ = ('_clock', '_name', '_ann', '_t0')
+
+    def __init__(self, clock: 'StageClock', name: str) -> None:
+        self._clock, self._name = clock, name
+
+    def __enter__(self) -> None:
+        # The annotation starts when it is constructed.
+        self._ann = self._clock.annotation('engine.' + self._name)
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc: Any) -> None:
+        self._clock.acc[self._name] += time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+
+
+class StageClock:
+    """The step loop's one way of timing a stage, on both clocks: the
+    seconds land in ``acc`` (what the step's :class:`StepRecord` is
+    built from) and the interval in the ``jax.profiler`` trace (plane
+    ``/host:CPU``, the engine thread's line), where it lies beside the
+    device's operations. With no profiler session an annotation costs
+    under a microsecond. Engine thread only (plain floats, never read
+    cross-thread); ``dispatch`` / ``drain`` / ``readback`` are disjoint,
+    ``sched`` is a part of the host remainder."""
+    NAMES = ('dispatch', 'drain', 'readback', 'sched')
+
+    def __init__(self) -> None:
+        # jax stays out of this module's import: the serve LB and the
+        # CLI import it too, and must not pay for (or reach for) jax.
+        from jax import profiler
+        self.annotation = profiler.TraceAnnotation
+        self._step_annotation = profiler.StepTraceAnnotation
+        self.acc: Dict[str, float] = dict.fromkeys(self.NAMES, 0.0)
+
+    def step(self, idx: int) -> Any:
+        """Open one step: the accumulators start from zero, and the
+        returned context is the ``engine.step`` annotation carrying
+        the index of the record this step will write."""
+        for name in self.NAMES:
+            self.acc[name] = 0.0
+        return self._step_annotation('engine.step', step_num=idx)
+
+    def stage(self, name: str) -> _Stage:
+        return _Stage(self, name)
+
+
+_NO_STAGE = contextlib.nullcontext()
+
+
+def no_stage(name: str) -> Any:
+    """:meth:`StageClock.stage` of an engine whose recorder is off."""
+    return _NO_STAGE
 
 
 class Ring:
@@ -259,6 +324,33 @@ _PID_STEPS = 1000
 _PID_REQUESTS = 1001
 _STAGE_TIDS = {s: i + 1 for i, s in enumerate(STAGES)}
 
+# A request's phases, each between two of its stamps. A stamp is an
+# event's own time, or (``submit.recv_t``) a detail of its ``submit``
+# event: the server's receipt (``recv_t``) and the LB's
+# (``lb_recv_t``) precede the engine's submit, which is the first
+# moment the ring can hold anything of the request.
+REQUEST_PHASES = (
+    ('lb_inbound', 'submit.lb_recv_t', 'submit.recv_t'),
+    ('admit', 'submit.recv_t', 'submit'),
+    ('queue_wait', 'submit', 'first_dispatch'),
+    ('prefill', 'first_dispatch', 'first_token'),
+    ('first_token_lag', 'prefill_dispatched', 'first_token'),
+    ('first_flush', 'first_token', 'first_flush'),
+    ('decode', 'first_token', 'done'),
+)
+_PHASE_EVENTS = frozenset(
+    key.partition('.')[0] for _, a, b in REQUEST_PHASES for key in (a, b))
+
+
+def stamp(evs: Dict[str, Dict[str, Any]], key: str) -> Optional[float]:
+    """The time of stamp ``key`` among one request's events by name,
+    or None where the request has no such stamp."""
+    name, _, detail = key.partition('.')
+    ev = evs.get(name)
+    if ev is None:
+        return None
+    return ev.get(detail) if detail else ev['t']
+
 
 def stepline_events(snapshot: Dict[str, Any]
                     ) -> List[Dict[str, Any]]:
@@ -299,34 +391,33 @@ def stepline_events(snapshot: Dict[str, Any]
             })
             t += dur
     # Request tracks: one tid per request_id; lifecycle phases become
-    # 'X' slices bounded by the recorded events, everything else an
+    # 'X' slices bounded by the recorded stamps, everything else an
     # instant.
-    # Lifecycle phase boundaries keyed by FIRST occurrence (each
-    # fires once per request); repeatable events (preemption, resume,
-    # shed, ...) are NOT folded into this map — every occurrence in
-    # the ring gets its own instant below, so a request preempted
-    # twice shows two instants, same as the span-store dump path.
+    # Phase boundaries keyed by FIRST occurrence (a request preempted
+    # before its first token re-dispatches its last chunk; the first
+    # stamp is the one its TTFT phases start from); repeatable events
+    # (preemption, resume, shed, ...) are NOT folded into this map —
+    # every occurrence in the ring gets its own instant below, so a
+    # request preempted twice shows two instants, same as the
+    # span-store dump path.
     by_req: Dict[int, Dict[str, Any]] = {}
     for ev in snapshot.get('events', ()):
         by_req.setdefault(ev['request_id'], {}).setdefault(
             ev['event'], ev)
     for rid, evs in by_req.items():
         tid = (rid % 100000) + 1
-        phases = (('queue_wait', 'submit', 'first_dispatch'),
-                  ('prefill', 'first_dispatch', 'first_token'),
-                  ('decode', 'first_token', 'done'))
-        for name, a, b in phases:
-            if a in evs and b in evs and evs[b]['t'] >= evs[a]['t']:
+        for name, a, b in REQUEST_PHASES:
+            t_a, t_b = stamp(evs, a), stamp(evs, b)
+            if t_a is not None and t_b is not None and t_b >= t_a:
                 events.append({
                     'name': f'req.{name}', 'ph': 'X',
-                    'ts': evs[a]['t'] * 1e6,
-                    'dur': (evs[b]['t'] - evs[a]['t']) * 1e6,
+                    'ts': t_a * 1e6, 'dur': (t_b - t_a) * 1e6,
                     'pid': _PID_REQUESTS, 'tid': tid,
                     'args': {'request_id': rid,
-                             'tenant': evs[a].get('tenant')}})
+                             'tenant': evs[a.partition('.')[0]]
+                             .get('tenant')}})
     for ev in snapshot.get('events', ()):
-        if ev['event'] in ('submit', 'first_dispatch',
-                           'first_token', 'done'):
+        if ev['event'] in _PHASE_EVENTS:
             continue
         events.append({
             'name': f"req.{ev['event']}", 'ph': 'i',

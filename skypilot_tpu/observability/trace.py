@@ -49,7 +49,7 @@ AGENT_COLLECTOR_ENV_VAR = 'SKY_TPU_TRACE_AGENT_COLLECTOR'
 PAYLOAD_KEY = '_traceparent'
 HEADER = 'traceparent'
 
-# Buffer cap: a hot instrumented loop (engine.step) must not grow RAM
+# Buffer cap: a hot instrumented loop must not grow RAM
 # without bound if shipping stalls; drops are counted, not silent.
 _MAX_BUFFER = 10_000
 
@@ -224,11 +224,9 @@ class _SpanHandle:
 
 
 @contextlib.contextmanager
-def span(name: str, *, hop: Optional[str] = None,
-         min_dur_s: float = 0.0, **attrs: Any):
+def span(name: str, *, hop: Optional[str] = None, **attrs: Any):
     """Record one span around a block. No-op (yields None) when tracing
-    is disabled. ``min_dur_s`` drops sub-threshold spans — for hot loops
-    (engine.step) where only outliers are interesting."""
+    is disabled."""
     if not enabled():
         yield None
         return
@@ -246,18 +244,15 @@ def span(name: str, *, hop: Optional[str] = None,
         raise
     finally:
         _current.reset(token)
-        dur = time.time() - t0
-        if dur >= min_dur_s:
-            record_span(
-                name=name, trace_id=ctx.trace_id, span_id=ctx.span_id,
-                parent_id=parent.span_id if parent else None,
-                start=t0, dur_s=dur, status=status,
-                hop=hop or get_hop(), attrs=handle.attrs)
+        record_span(
+            name=name, trace_id=ctx.trace_id, span_id=ctx.span_id,
+            parent_id=parent.span_id if parent else None,
+            start=t0, dur_s=time.time() - t0, status=status,
+            hop=hop or get_hop(), attrs=handle.attrs)
 
 
 def traced(fn: Callable = None, *, name: Optional[str] = None,
-           hop: Optional[str] = None,
-           min_dur_s: float = 0.0) -> Callable:
+           hop: Optional[str] = None) -> Callable:
     """Decorator form. Gated at decoration time (same zero-cost default
     as ``timeline.event``): with ``SKY_TPU_TRACE`` unset the original
     function is returned unchanged — no wrapper, no per-call check."""
@@ -269,7 +264,7 @@ def traced(fn: Callable = None, *, name: Optional[str] = None,
 
         @functools.wraps(f)
         def inner(*a, **kw):
-            with span(label, hop=hop, min_dur_s=min_dur_s):
+            with span(label, hop=hop):
                 return f(*a, **kw)
 
         return inner

@@ -52,6 +52,12 @@ KERNEL (the row scales multiply the score and probability columns, see
 resident pages per chip. Every entry
 point takes optional ``k_scales``/``v_scales``; None means the bf16
 path, which is bit-for-bit the pre-quantization code.
+
+Each ``pallas_call`` carries its entry point's name
+(``paged_decode_attention``, ``paged_prefill_attention``,
+``paged_verify_attention``): that is the operation's name in a
+profiler trace. Without one the compiled custom call takes the name
+of whatever scope encloses it (``closed_call`` inside a layer scan).
 """
 from __future__ import annotations
 
@@ -338,6 +344,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((slots, hkv, group, hd),
                                        jnp.float32),
         interpret=interpret,
+        name='paged_decode_attention',
     )(block_tables, lengths, *operands)
 
 
@@ -504,6 +511,7 @@ def paged_prefill_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((hkv, C * group, hd),
                                        jnp.float32),
         interpret=interpret,
+        name='paged_prefill_attention',
     )(table_row, meta, *operands)
     return out.reshape(hkv, C, group, hd).transpose(1, 0, 2, 3)
 
@@ -682,6 +690,7 @@ def paged_verify_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((slots, hkv, R * group, hd),
                                        jnp.float32),
         interpret=interpret,
+        name='paged_verify_attention',
     )(block_tables, lengths, *operands)
     return out.reshape(slots, hkv, R, group, hd).transpose(0, 2, 1, 3, 4)
 

@@ -2002,6 +2002,7 @@ class LoadBalancer:
                 self.slo.snapshot(self._clock.time()))
         self._requests_total += 1
         t_arrival = self._clock.monotonic()
+        lb_recv_t = self._clock.time()
         # Body read comes FIRST: nothing is selected or counted yet, so
         # a client disconnecting mid-upload cannot leak the inflight
         # gauge or burn a half-open breaker probe slot.
@@ -2024,6 +2025,10 @@ class LoadBalancer:
         # client-supplied value (a hostile client could point replicas
         # at arbitrary pull targets).
         headers.pop(common.KV_DONOR_HEADER, None)
+        # The request's timeline starts here: every leg (retry and
+        # resume legs too) carries the moment this handler was
+        # entered, and the replica's flight recorder keeps it.
+        headers[common.LB_RECV_HEADER] = f'{lb_recv_t:.6f}'
         # Fleet prefix chain (docs/serving.md "Disaggregated prefill/
         # decode"): token prompts chain into page-block hashes — the
         # key space shared with every replica's radix index. Text

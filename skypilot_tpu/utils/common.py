@@ -29,6 +29,12 @@ TENANT_HEADER = 'X-SkyTpu-Tenant'
 # Best-effort end to end — any pull failure degrades to plain
 # recompute, never a client-visible error.
 KV_DONOR_HEADER = 'X-SkyTpu-KV-Donor'
+# Wall-clock moment (epoch seconds) the serve LB's handler received
+# the request, forwarded on every leg: the infer server hands it to
+# the engine's flight recorder, where it starts the request's
+# timeline (docs/observability.md "Flight recorder"). Observed only;
+# LB-internal like the donor header, so a client's value is dropped.
+LB_RECV_HEADER = 'X-SkyTpu-LB-Recv-T'
 
 
 # Directories base_dir() has already created this process: the call
