@@ -565,7 +565,7 @@ def _chunked_kv_axis(config, params, *, slots: int, max_seq_len: int,
     overflow the bf16 pool)."""
     # Bytes one (k+v) page costs across all layers: values at their
     # dtype plus, for int8, one fp32 scale per row per head — the
-    # closed form InferenceEngine._kv_page_bytes reports.
+    # closed form PagedKVCache.page_bytes reports.
     engines = {
         dt: (2 * config.n_layers * config.n_kv_heads * page_size
              * (config.head_dim * (1 if dt == 'int8' else 2)

@@ -833,11 +833,8 @@ class InferenceEngine:
                 pair, new_last, lengths = _accept(
                     tokens, logits, drafts, draft_len, key, temps,
                     active, new_cache.lengths)
-                return pair, new_last, paged_cache_lib.PagedKVCache(
-                    k_pages=new_cache.k_pages,
-                    v_pages=new_cache.v_pages, lengths=lengths,
-                    k_scales=new_cache.k_scales,
-                    v_scales=new_cache.v_scales)
+                return pair, new_last, dataclasses.replace(
+                    new_cache, lengths=lengths)
             self._verify = _jit(_verify_paged, donate=(0,))
 
             def _mixed_paged(kv_cache, params, slot, table_row,
@@ -1256,13 +1253,9 @@ class InferenceEngine:
         pages, matched = self.prefix.peek(tokens)
         if not pages:
             return None
-        pids = jnp.asarray(np.asarray(pages, np.int32))
-        k = self.cache.k_pages[:, :, pids]
-        v = self.cache.v_pages[:, :, pids]
-        if self.cache.k_scales is not None:
-            kq, vq = np.asarray(k), np.asarray(v)
-            ks = np.asarray(self.cache.k_scales[:, :, pids])
-            vs = np.asarray(self.cache.v_scales[:, :, pids])
+        k, v, ks, vs = paged_cache_lib.gather_pages(self.cache, pages)
+        if ks is not None:
+            kq, vq, ks, vs = (np.asarray(a) for a in (k, v, ks, vs))
         else:
             kq, ks = kv_wire.quantize_rows_np(np.asarray(k))
             vq, vs = kv_wire.quantize_rows_np(np.asarray(v))
@@ -1307,32 +1300,19 @@ class InferenceEngine:
         if new is None:
             raise kv_wire.WireError(
                 f'page pool dry ({need} pages needed)')
-        pids = jnp.asarray(np.asarray(new, np.int32))
         if self.cache.k_scales is not None:
             # int8 pool: the transferred bytes land verbatim —
             # byte-exact with what the donor holds.
-            self.cache = paged_cache_lib.PagedKVCache(
-                k_pages=self.cache.k_pages.at[:, :, pids].set(
-                    jnp.asarray(blk.k[:, :, start:])),
-                v_pages=self.cache.v_pages.at[:, :, pids].set(
-                    jnp.asarray(blk.v[:, :, start:])),
-                lengths=self.cache.lengths,
-                k_scales=self.cache.k_scales.at[:, :, pids].set(
-                    jnp.asarray(blk.k_scales[:, :, start:])),
-                v_scales=self.cache.v_scales.at[:, :, pids].set(
-                    jnp.asarray(blk.v_scales[:, :, start:])))
+            self.cache = paged_cache_lib.scatter_pages(
+                self.cache, new, blk.k[:, :, start:], blk.v[:, :, start:],
+                blk.k_scales[:, :, start:], blk.v_scales[:, :, start:])
         else:
-            dt = self.cache.k_pages.dtype
-            kd = jnp.asarray(kv_wire.dequantize_rows_np(
-                blk.k[:, :, start:],
-                blk.k_scales[:, :, start:])).astype(dt)
-            vd = jnp.asarray(kv_wire.dequantize_rows_np(
-                blk.v[:, :, start:],
-                blk.v_scales[:, :, start:])).astype(dt)
-            self.cache = paged_cache_lib.PagedKVCache(
-                k_pages=self.cache.k_pages.at[:, :, pids].set(kd),
-                v_pages=self.cache.v_pages.at[:, :, pids].set(vd),
-                lengths=self.cache.lengths)
+            self.cache = paged_cache_lib.scatter_pages(
+                self.cache, new,
+                kv_wire.dequantize_rows_np(blk.k[:, :, start:],
+                                           blk.k_scales[:, :, start:]),
+                kv_wire.dequantize_rows_np(blk.v[:, :, start:],
+                                           blk.v_scales[:, :, start:]))
         added = self.prefix.insert_remote(
             blk.tokens, [None] * start + list(new))
         assert added == need, (
@@ -2919,23 +2899,10 @@ class InferenceEngine:
                 # scales) — the denominator behind the "~2x
                 # resident pages per HBM byte" claim.
                 'kv_dtype': self.ecfg.kv_dtype,
-                'kv_page_bytes': self._kv_page_bytes()}
+                'kv_page_bytes': self.cache.page_bytes}
                if self.allocator is not None else {}),
             **prefix_stats,
         }
-
-    def _kv_page_bytes(self) -> int:
-        """HBM bytes one physical page costs across every layer — K
-        plus V values at their dtype, plus the fp32 row scales on the
-        int8 flavor."""
-        per = self.cache.k_pages.dtype.itemsize
-        page = self.allocator.page_size
-        vals = (2 * self.config.n_layers * self.config.n_kv_heads
-                * page * self.config.head_dim * per)
-        if self.cache.k_scales is not None:
-            vals += (2 * self.config.n_layers * self.config.n_kv_heads
-                     * page * self.cache.k_scales.dtype.itemsize)
-        return vals
 
     def compiled_counts(self) -> Dict[str, int]:
         """Distinct compiled programs per jitted entry point — the

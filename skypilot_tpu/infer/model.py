@@ -12,7 +12,15 @@ Same weights, two execution shapes:
   the einsum form is the fast form.
 
 Both are pure functions jitted by the engine with buffer donation on the
-cache (XLA updates it in place).
+cache. The paged programs keep that promise all the way down: the page
+pool (every layer's pages folded into one page axis,
+infer/paged_cache.py) is a loop CARRY of the layer scan
+(``_scan_layers``), never per-layer ``xs``/``ys``. A layer writes only
+its new rows into the carried pool (``dynamic_update_slice``s, in
+place) and its attention kernel reads the layer's pages out of the same
+buffer through a block table that carries the layer's offset. So the
+donated input pool IS the output pool: no second pool is allocated and
+no layer's slab is sliced out, stacked back or relaid.
 
 The paged step programs name their parts with ``jax.named_scope``:
 ``embed``, then per layer ``attn`` (norm, projections, rope, the
@@ -24,6 +32,8 @@ grouped.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -155,15 +165,12 @@ def paged_prefill_chunk(config: llama.LlamaConfig, params: llama.Params,
                                          config.rope_theta)
     positions = offset + jnp.arange(C, dtype=jnp.int32)
 
-    def body(carry, xs):
-        layer, k_layer, v_layer, ks, vs = _unpack_layer_xs(xs)
-        h, k_new, v_new, ks, vs = _paged_chunk_layer(
-            config, carry, layer, cos, sin, k_layer, v_layer,
-            table_row, positions, offset, true_len, ks, vs)
-        return h, _pack_layer_ys(k_new, v_new, ks, vs)
+    def layer_fn(h, layer, kv, physical):
+        return _paged_chunk_layer(
+            config, h, layer, cos, sin, kv, physical(table_row),
+            positions, offset, true_len)
 
-    x, ys = jax.lax.scan(body, x, _layer_xs(params, pkv))
-    k_upd, v_upd, ks_upd, vs_upd = _unpack_layer_upd(pkv, ys)
+    x, pkv = _scan_layers(params, pkv, x, layer_fn)
     with jax.named_scope('head'):
         x = norms.rms_norm(x, params['final_norm'], config.norm_eps)
         last = jax.lax.dynamic_index_in_dim(x[0], true_len - 1, axis=0,
@@ -172,45 +179,46 @@ def paged_prefill_chunk(config: llama.LlamaConfig, params: llama.Params,
                                 params['lm_head']).astype(jnp.float32)
     lengths = pkv.lengths.at[slot].set(
         (offset + true_len).astype(jnp.int32))
-    return paged_cache_lib.PagedKVCache(
-        k_pages=k_upd, v_pages=v_upd, lengths=lengths,
-        k_scales=ks_upd, v_scales=vs_upd), logits
+    return dataclasses.replace(pkv, lengths=lengths), logits
 
 
-def _layer_xs(params, pkv):
-    """Per-layer scan operands: pages, plus the scale pages on the
-    int8 flavor (lax.scan cannot carry None leaves in xs)."""
-    if pkv.k_scales is not None:
-        return (params['layers'], pkv.k_pages, pkv.v_pages,
-                pkv.k_scales, pkv.v_scales)
-    return (params['layers'], pkv.k_pages, pkv.v_pages)
+def _scan_layers(params, pkv, x, layer_fn):
+    """The layer scan of every paged step program.
+
+    ``layer_fn(x, layer, pkv, physical) -> (x, pkv)`` is one layer;
+    ``physical`` maps this layer's page ids (a block table, a table
+    row, the sink page 0) to physical pages of the folded pool. The
+    cache rides as a CARRY beside the activations, so XLA keeps the one
+    donated pool buffer through the whole loop: a layer's writes are
+    in-place row updates and nothing of the pool is an ``xs`` to slice
+    or a ``ys`` to stack. ``x`` may be any pytree (the mixed step
+    carries its chunk and decode activations as a pair)."""
+    def body(carry, xs):
+        h, kv = carry
+        layer, idx = xs
+        physical = functools.partial(paged_cache_lib.physical_pages,
+                                     kv.n_pages, idx)
+        return layer_fn(h, layer, kv, physical), None
+
+    (x, pkv), _ = jax.lax.scan(
+        body, (x, pkv),
+        (params['layers'], jnp.arange(pkv.n_layers, dtype=jnp.int32)))
+    return x, pkv
 
 
-def _unpack_layer_xs(xs):
-    if len(xs) == 5:
-        return xs
-    layer, kp, vp = xs
-    return layer, kp, vp, None, None
+def _with_pages(pkv, written):
+    """The cache with what a page writer returned: ``(k_pages,
+    v_pages)``, plus the two scale pools on the int8 flavor."""
+    k_pages, v_pages, *scales = written
+    k_scales, v_scales = scales or (None, None)
+    return dataclasses.replace(pkv, k_pages=k_pages, v_pages=v_pages,
+                               k_scales=k_scales, v_scales=v_scales)
 
 
-def _pack_layer_ys(k_new, v_new, ks, vs):
-    if ks is not None:
-        return (k_new, v_new, ks, vs)
-    return (k_new, v_new)
-
-
-def _unpack_layer_upd(pkv, ys):
-    if pkv.k_scales is not None:
-        return ys
-    k_upd, v_upd = ys
-    return k_upd, v_upd, None, None
-
-
-def _paged_chunk_layer(config, x, layer, cos, sin, k_pages, v_pages,
-                       table_row, positions, offset, true_len,
-                       k_scales=None, v_scales=None):
-    """One layer of paged chunked prefill. k_pages/v_pages:
-    [hkv, P, page, hd] (this layer); x: [1, C, d]."""
+def _paged_chunk_layer(config, x, layer, cos, sin, pkv, table_row,
+                       positions, offset, true_len):
+    """One layer of paged chunked prefill. pkv: the whole folded pool;
+    table_row: this layer's PHYSICAL page ids; x: [1, C, d]."""
     _, C, d = x.shape
     hq, hkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     group = hq // hkv
@@ -227,24 +235,19 @@ def _paged_chunk_layer(config, x, layer, cos, sin, k_pages, v_pages,
     # the chunk's own self-attention reads its rows back dequantized,
     # exactly what every later decode step will see).
     with jax.named_scope('kv_write'):
-        if k_scales is not None:
-            k_pages, v_pages, k_scales, v_scales = (
-                paged_attn.write_chunk_pages(
-                    k_pages, v_pages, k[0], v[0], table_row, offset,
-                    k_scales, v_scales))
-        else:
-            k_pages, v_pages = paged_attn.write_chunk_pages(
-                k_pages, v_pages, k[0], v[0], table_row, offset)
+        pkv = _with_pages(pkv, paged_attn.write_chunk_pages(
+            pkv.k_pages, pkv.v_pages, k[0], v[0], table_row, offset,
+            pkv.k_scales, pkv.v_scales))
     with jax.named_scope('attn'):
         qg = q[0].reshape(C, hkv, group, hd)
         att = paged_attn.paged_prefill_attention(
-            qg, k_pages, v_pages, table_row, offset, true_len,
-            k_scales=k_scales, v_scales=v_scales)
+            qg, pkv.k_pages, pkv.v_pages, table_row, offset, true_len,
+            k_scales=pkv.k_scales, v_scales=pkv.v_scales)
         att = att.reshape(1, C, hq * hd).astype(x.dtype)
         x = x + quant_lib.qdot(att, layer['wo'])
     with jax.named_scope('mlp'):
         x = llama.mlp_block(config, x, layer)
-    return x, k_pages, v_pages, k_scales, v_scales
+    return x, pkv
 
 
 def paged_decode_step(config: llama.LlamaConfig, params: llama.Params,
@@ -267,30 +270,26 @@ def paged_decode_step(config: llama.LlamaConfig, params: llama.Params,
                                          config.max_seq_len,
                                          config.rope_theta)
 
-    def body(carry, xs):
-        layer, k_layer, v_layer, ks, vs = _unpack_layer_xs(xs)
-        h, k_new, v_new, ks, vs = _paged_decode_layer(
-            config, carry, layer, cos, sin, k_layer, v_layer,
-            block_tables, positions, ks, vs)
-        return h, _pack_layer_ys(k_new, v_new, ks, vs)
+    def layer_fn(h, layer, kv, physical):
+        return _paged_decode_layer(
+            config, h, layer, cos, sin, kv, physical(block_tables),
+            positions, physical(0))
 
-    x, ys = jax.lax.scan(body, x, _layer_xs(params, pkv))
-    k_upd, v_upd, ks_upd, vs_upd = _unpack_layer_upd(pkv, ys)
+    x, pkv = _scan_layers(params, pkv, x, layer_fn)
     with jax.named_scope('head'):
         x = norms.rms_norm(x, params['final_norm'], config.norm_eps)
         logits = quant_lib.qdot(x[:, 0],
                                 params['lm_head']).astype(jnp.float32)
     bump = (jnp.ones_like(pkv.lengths) if active is None
             else active.astype(pkv.lengths.dtype))
-    new_cache = paged_cache_lib.PagedKVCache(
-        k_pages=k_upd, v_pages=v_upd, lengths=pkv.lengths + bump,
-        k_scales=ks_upd, v_scales=vs_upd)
-    return logits, new_cache
+    return logits, dataclasses.replace(pkv, lengths=pkv.lengths + bump)
 
 
-def _paged_decode_layer(config, x, layer, cos, sin, k_pages, v_pages,
-                        block_tables, positions,
-                        k_scales=None, v_scales=None):
+def _paged_decode_layer(config, x, layer, cos, sin, pkv, block_tables,
+                        positions, sink_page):
+    """One layer of the paged decode step. pkv: the whole folded pool;
+    block_tables / sink_page: this layer's PHYSICAL page ids (the sink
+    is the layer's own page 0); x: [slots, 1, d]."""
     slots, _, d = x.shape
     hq, hkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     group = hq // hkv
@@ -306,25 +305,19 @@ def _paged_decode_layer(config, x, layer, cos, sin, k_pages, v_pages,
     # Write the new K/V into the slot's current page, then attend over
     # positions <= length (the new token sees itself).
     with jax.named_scope('kv_write'):
-        if k_scales is not None:
-            k_pages, v_pages, k_scales, v_scales = (
-                paged_attn.append_token_pages(
-                    k_pages, v_pages, k[:, 0], v[:, 0], block_tables,
-                    positions, k_scales, v_scales))
-        else:
-            k_pages, v_pages = paged_attn.append_token_pages(
-                k_pages, v_pages, k[:, 0], v[:, 0], block_tables,
-                positions)
+        pkv = _with_pages(pkv, paged_attn.append_token_pages(
+            pkv.k_pages, pkv.v_pages, k[:, 0], v[:, 0], block_tables,
+            positions, pkv.k_scales, pkv.v_scales, sink_page=sink_page))
     with jax.named_scope('attn'):
         qg = q[:, 0].reshape(slots, hkv, group, hd)
         att = paged_attn.paged_decode_attention(
-            qg, k_pages, v_pages, block_tables, positions + 1,
-            k_scales=k_scales, v_scales=v_scales)
+            qg, pkv.k_pages, pkv.v_pages, block_tables, positions + 1,
+            k_scales=pkv.k_scales, v_scales=pkv.v_scales)
         att = att.reshape(slots, 1, hq * hd).astype(x.dtype)
         x = x + quant_lib.qdot(att, layer['wo'])
     with jax.named_scope('mlp'):
         x = llama.mlp_block(config, x, layer)
-    return x, k_pages, v_pages, k_scales, v_scales
+    return x, pkv
 
 
 def verify_step(config: llama.LlamaConfig, params: llama.Params,
@@ -425,27 +418,26 @@ def paged_verify_step(config: llama.LlamaConfig, params: llama.Params,
                                          config.max_seq_len,
                                          config.rope_theta)
 
-    def body(carry, xs):
-        layer, k_layer, v_layer, ks, vs = _unpack_layer_xs(xs)
-        h, k_new, v_new, ks, vs = _paged_verify_layer(
-            config, carry, layer, cos, sin, k_layer, v_layer,
-            block_tables, positions, pkv.lengths, ks, vs)
-        return h, _pack_layer_ys(k_new, v_new, ks, vs)
+    lengths = pkv.lengths
 
-    x, ys = jax.lax.scan(body, x, _layer_xs(params, pkv))
-    k_upd, v_upd, ks_upd, vs_upd = _unpack_layer_upd(pkv, ys)
+    def layer_fn(h, layer, kv, physical):
+        return _paged_verify_layer(
+            config, h, layer, cos, sin, kv, physical(block_tables),
+            positions, lengths, physical(0))
+
+    x, pkv = _scan_layers(params, pkv, x, layer_fn)
     with jax.named_scope('head'):
         x = norms.rms_norm(x, params['final_norm'], config.norm_eps)
         logits = quant_lib.qdot(x,
                                 params['lm_head']).astype(jnp.float32)
-    return logits, paged_cache_lib.PagedKVCache(
-        k_pages=k_upd, v_pages=v_upd, lengths=pkv.lengths,
-        k_scales=ks_upd, v_scales=vs_upd)
+    return logits, pkv
 
 
-def _paged_verify_layer(config, x, layer, cos, sin, k_pages, v_pages,
-                        block_tables, positions, lengths,
-                        k_scales=None, v_scales=None):
+def _paged_verify_layer(config, x, layer, cos, sin, pkv, block_tables,
+                        positions, lengths, sink_page):
+    """One layer of the paged verify step. pkv: the whole folded pool;
+    block_tables / sink_page: this layer's PHYSICAL page ids (the sink
+    is the layer's own page 0); x: [slots, R, d]."""
     slots, R, d = x.shape
     hq, hkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     group = hq // hkv
@@ -460,24 +452,19 @@ def _paged_verify_layer(config, x, layer, cos, sin, k_pages, v_pages,
 
     # Write-then-attend, run edition (sink-redirected past coverage).
     with jax.named_scope('kv_write'):
-        if k_scales is not None:
-            k_pages, v_pages, k_scales, v_scales = (
-                paged_attn.append_run_pages(k_pages, v_pages, k, v,
-                                            block_tables, lengths,
-                                            k_scales, v_scales))
-        else:
-            k_pages, v_pages = paged_attn.append_run_pages(
-                k_pages, v_pages, k, v, block_tables, lengths)
+        pkv = _with_pages(pkv, paged_attn.append_run_pages(
+            pkv.k_pages, pkv.v_pages, k, v, block_tables, lengths,
+            pkv.k_scales, pkv.v_scales, sink_page=sink_page))
     with jax.named_scope('attn'):
         qg = q.reshape(slots, R, hkv, group, hd)
         att = paged_attn.paged_verify_attention(
-            qg, k_pages, v_pages, block_tables, lengths,
-            k_scales=k_scales, v_scales=v_scales)
+            qg, pkv.k_pages, pkv.v_pages, block_tables, lengths,
+            k_scales=pkv.k_scales, v_scales=pkv.v_scales)
         att = att.reshape(slots, R, hq * hd).astype(x.dtype)
         x = x + quant_lib.qdot(att, layer['wo'])
     with jax.named_scope('mlp'):
         x = llama.mlp_block(config, x, layer)
-    return x, k_pages, v_pages, k_scales, v_scales
+    return x, pkv
 
 
 def decode_step(config: llama.LlamaConfig, params: llama.Params,
@@ -654,20 +641,17 @@ def paged_mixed_step(config: llama.LlamaConfig, params: llama.Params,
         (offset + true_len).astype(jnp.int32))
     dpos = lengths_mid
 
-    def body(carry, xs):
-        hc, hd_ = carry
-        layer, k_layer, v_layer, ks, vs = _unpack_layer_xs(xs)
-        hc, k_layer, v_layer, ks, vs = _paged_chunk_layer(
-            config, hc, layer, cos, sin, k_layer, v_layer,
-            table_row, cpos, offset, true_len, ks, vs)
-        hd_, k_layer, v_layer, ks, vs = _paged_decode_layer(
-            config, hd_, layer, cos, sin, k_layer, v_layer,
-            block_tables, dpos, ks, vs)
-        return (hc, hd_), _pack_layer_ys(k_layer, v_layer, ks, vs)
+    def layer_fn(h, layer, kv, physical):
+        hc, hd_ = h
+        hc, kv = _paged_chunk_layer(
+            config, hc, layer, cos, sin, kv, physical(table_row), cpos,
+            offset, true_len)
+        hd_, kv = _paged_decode_layer(
+            config, hd_, layer, cos, sin, kv, physical(block_tables),
+            dpos, physical(0))
+        return (hc, hd_), kv
 
-    (xc, xd), ys = jax.lax.scan(body, (xc, xd),
-                                _layer_xs(params, pkv))
-    k_upd, v_upd, ks_upd, vs_upd = _unpack_layer_upd(pkv, ys)
+    (xc, xd), pkv = _scan_layers(params, pkv, (xc, xd), layer_fn)
     with jax.named_scope('head'):
         xc = norms.rms_norm(xc, params['final_norm'], config.norm_eps)
         last = jax.lax.dynamic_index_in_dim(xc[0], true_len - 1,
@@ -678,6 +662,5 @@ def paged_mixed_step(config: llama.LlamaConfig, params: llama.Params,
         dec_logits = quant_lib.qdot(
             xd[:, 0], params['lm_head']).astype(jnp.float32)
     bump = active.astype(lengths_mid.dtype)
-    return chunk_logits, dec_logits, paged_cache_lib.PagedKVCache(
-        k_pages=k_upd, v_pages=v_upd, lengths=lengths_mid + bump,
-        k_scales=ks_upd, v_scales=vs_upd)
+    return chunk_logits, dec_logits, dataclasses.replace(
+        pkv, lengths=lengths_mid + bump)

@@ -7,10 +7,23 @@ pool of fixed-size pages shared by all slots; a slot owns
 ceil(len/page) pages, HBM scales with tokens-in-flight, and one engine
 serves mixed 2k/16k prompts (subsuming the round-4 two-tier EnginePool).
 
-Device state (static shapes, XLA-friendly):
+Device state (static shapes, XLA-friendly). The layer is folded into the
+page axis, so the pool is ONE array that a step program can carry through
+its layer scan and update in place:
 
-    k_pages, v_pages: [n_layers, n_kv_heads, n_pages, page, head_dim]
+    k_pages, v_pages: [n_kv_heads, n_layers * n_pages, page, head_dim]
     lengths:          [n_slots] int32
+
+Layer ``l``'s page ``p`` is physical page ``l * n_pages + p``
+(``physical_pages``, the only statement of that rule). The scan bodies
+of ``infer/model.py`` add a layer's offset to the block table and hand
+the whole pool to the attention kernels, which gather pages through
+their page index anyway; the writers update only the rows they write.
+Nothing ever slices a layer out of the pool. Everything else that
+indexes the pool by (layer, page) goes through this module:
+``gather_pages`` / ``scatter_pages`` speak ``[L, hkv, n, page, hd]`` (the
+logical view, and the wire format's), ``copy_page`` duplicates one page
+in every layer.
 
 Host state: the **allocator** (free-page stack + per-slot block table).
 Page assignment is control flow, not compute — it changes a few ints
@@ -31,31 +44,45 @@ import numpy as np
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class PagedKVCache:
-    k_pages: jnp.ndarray   # [L, hkv, P, page, hd] (bf16, or int8 quantized)
-    v_pages: jnp.ndarray   # [L, hkv, P, page, hd]
+    k_pages: jnp.ndarray   # [hkv, L*P, page, hd] (bf16, or int8 quantized)
+    v_pages: jnp.ndarray   # [hkv, L*P, page, hd]
     lengths: jnp.ndarray   # [slots] int32
     # int8 KV ("kv_dtype=int8"): per-page, per-head absmax scales — one
     # fp32 scale per cached token row of each page, pool-aligned with
     # the pages themselves so a page id addresses its values AND its
     # scales. None on the bf16 flavor (pytree-wise None is an empty
     # subtree, so bf16 caches flatten exactly as before).
-    k_scales: Optional[jnp.ndarray] = None   # [L, hkv, P, page] f32
-    v_scales: Optional[jnp.ndarray] = None   # [L, hkv, P, page] f32
+    k_scales: Optional[jnp.ndarray] = None   # [hkv, L*P, page] f32
+    v_scales: Optional[jnp.ndarray] = None   # [hkv, L*P, page] f32
+    # Static (pytree aux data): how many layers share the page axis.
+    n_layers: int = dataclasses.field(
+        kw_only=True, metadata=dict(static=True))
 
     @property
     def n_pages(self) -> int:
-        return self.k_pages.shape[2]
+        """Pages a layer owns (the allocator's count), not L*P."""
+        return self.k_pages.shape[1] // self.n_layers
 
     @property
     def page_size(self) -> int:
-        return self.k_pages.shape[3]
+        return self.k_pages.shape[2]
+
+    @property
+    def page_bytes(self) -> int:
+        """HBM bytes one page costs across every layer: K plus V values
+        at their dtype, plus the fp32 row scales on the int8 flavor."""
+        arrays = [self.k_pages, self.v_pages]
+        if self.k_scales is not None:
+            arrays += [self.k_scales, self.v_scales]
+        return sum(a.nbytes for a in arrays) // self.n_pages
 
 
 def init_paged_cache(n_layers: int, n_slots: int, n_pages: int,
                      page_size: int, n_kv_heads: int, head_dim: int,
                      dtype=jnp.bfloat16) -> PagedKVCache:
-    shape = (n_layers, n_kv_heads, n_pages, page_size, head_dim)
+    shape = (n_kv_heads, n_layers * n_pages, page_size, head_dim)
     dtype = jnp.dtype(dtype)
+    lengths = jnp.zeros((n_slots,), jnp.int32)
     if dtype == jnp.int8:
         # Quantized pages halve the KV bytes per token (int8 values +
         # a 4-byte row scale vs 2-byte bf16 x head_dim), so the same
@@ -63,14 +90,59 @@ def init_paged_cache(n_layers: int, n_slots: int, n_pages: int,
         # the prefix cache (PR 4) and shrinks preemption pressure.
         return PagedKVCache(
             k_pages=jnp.zeros(shape, jnp.int8),
-            v_pages=jnp.zeros(shape, jnp.int8),
-            lengths=jnp.zeros((n_slots,), jnp.int32),
+            v_pages=jnp.zeros(shape, jnp.int8), lengths=lengths,
             k_scales=jnp.zeros(shape[:-1], jnp.float32),
-            v_scales=jnp.zeros(shape[:-1], jnp.float32))
-    return PagedKVCache(
-        k_pages=jnp.zeros(shape, dtype),
-        v_pages=jnp.zeros(shape, dtype),
-        lengths=jnp.zeros((n_slots,), jnp.int32))
+            v_scales=jnp.zeros(shape[:-1], jnp.float32),
+            n_layers=n_layers)
+    return PagedKVCache(k_pages=jnp.zeros(shape, dtype),
+                        v_pages=jnp.zeros(shape, dtype), lengths=lengths,
+                        n_layers=n_layers)
+
+
+def physical_pages(n_pages: int, layer, pages):
+    """(layer, page) -> physical page of the folded pool: THE layout
+    rule. ``layer`` and ``pages`` broadcast (a scan body passes its
+    traced layer index and a whole block table). Callers clamp and
+    redirect to the sink BEFORE this, so page 0 stays the layer's own
+    page 0 and never becomes another layer's page."""
+    return layer * n_pages + pages
+
+
+def _layer_pages(cache: PagedKVCache, pids) -> jnp.ndarray:
+    """[L, n] physical ids of pages ``pids`` in every layer."""
+    layers = jnp.arange(cache.n_layers, dtype=jnp.int32)[:, None]
+    return physical_pages(cache.n_pages, layers,
+                          jnp.asarray(pids, jnp.int32)[None, :])
+
+
+def gather_pages(cache: PagedKVCache, pids):
+    """Pages ``pids`` of every layer in the logical view: ``(k, v, ks,
+    vs)`` with k/v ``[L, hkv, n, page, hd]`` and the scales ``[L, hkv, n,
+    page]`` (None on the bf16 flavor)."""
+    phys = _layer_pages(cache, pids)
+
+    def take(arr):
+        return None if arr is None else jnp.moveaxis(arr[:, phys], 0, 1)
+    return (take(cache.k_pages), take(cache.v_pages),
+            take(cache.k_scales), take(cache.v_scales))
+
+
+def scatter_pages(cache: PagedKVCache, pids, k, v, k_scales=None,
+                  v_scales=None) -> PagedKVCache:
+    """Inverse of ``gather_pages``: land ``[L, hkv, n, page, hd]`` values
+    (and ``[L, hkv, n, page]`` scales on the int8 flavor) in pages
+    ``pids`` of every layer."""
+    phys = _layer_pages(cache, pids)
+
+    def put(arr, new):
+        if arr is None:
+            return None
+        return arr.at[:, phys].set(
+            jnp.moveaxis(jnp.asarray(new), 0, 1).astype(arr.dtype))
+    return dataclasses.replace(
+        cache, k_pages=put(cache.k_pages, k), v_pages=put(cache.v_pages, v),
+        k_scales=put(cache.k_scales, k_scales),
+        v_scales=put(cache.v_scales, v_scales))
 
 
 class PageAllocator:
@@ -282,29 +354,28 @@ class PageAllocator:
 def free_slot(cache: PagedKVCache, slot: int) -> PagedKVCache:
     """Device half of freeing: zero the slot's length (the allocator's
     ``free`` is the host half)."""
-    return PagedKVCache(k_pages=cache.k_pages, v_pages=cache.v_pages,
-                        lengths=cache.lengths.at[slot].set(0),
-                        k_scales=cache.k_scales,
-                        v_scales=cache.v_scales)
+    return dataclasses.replace(cache,
+                               lengths=cache.lengths.at[slot].set(0))
 
 
 def copy_page(cache: PagedKVCache, src: jnp.ndarray,
               dst: jnp.ndarray) -> PagedKVCache:
-    """Device half of copy-on-write: duplicate physical page ``src``
-    into ``dst`` across all layers/heads (the allocator's ``cow`` is
-    the host half). src/dst are traced scalars, so one compiled program
-    covers every CoW. On the int8 flavor the page's row scales copy
-    with it — a page id is only meaningful as a (values, scales) pair."""
+    """Device half of copy-on-write: duplicate page ``src`` into ``dst``
+    in every layer, all heads (the allocator's ``cow`` is the host
+    half). src/dst are traced scalars, so one compiled program covers
+    every CoW. On the int8 flavor the page's row scales copy with it — a
+    page id is only meaningful as a (values, scales) pair."""
     def dup(arr):
-        row = jax.lax.dynamic_index_in_dim(arr, src, axis=2,
-                                           keepdims=True)
-        return jax.lax.dynamic_update_index_in_dim(arr, row, dst,
-                                                   axis=2)
-    return PagedKVCache(
-        k_pages=dup(cache.k_pages),
-        v_pages=dup(cache.v_pages),
-        lengths=cache.lengths,
-        k_scales=(dup(cache.k_scales)
-                  if cache.k_scales is not None else None),
-        v_scales=(dup(cache.v_scales)
-                  if cache.v_scales is not None else None))
+        if arr is None:
+            return None
+        for layer in range(cache.n_layers):
+            page = jax.lax.dynamic_index_in_dim(
+                arr, physical_pages(cache.n_pages, layer, src), axis=1,
+                keepdims=True)
+            arr = jax.lax.dynamic_update_index_in_dim(
+                arr, page, physical_pages(cache.n_pages, layer, dst),
+                axis=1)
+        return arr
+    return dataclasses.replace(
+        cache, k_pages=dup(cache.k_pages), v_pages=dup(cache.v_pages),
+        k_scales=dup(cache.k_scales), v_scales=dup(cache.v_scales))

@@ -7,11 +7,17 @@ page ids (its *block table*). HBM then scales with tokens-in-flight,
 not slots x max_seq_len, and one engine serves mixed 2k/16k prompts
 without pricing every slot at 16k.
 
-Layout (per layer):
+Layout:
 
     k_pages, v_pages: [n_kv_heads, n_pages, page_size, head_dim]
     block_tables:     [n_slots, max_pages] int32  (page ids)
     lengths:          [n_slots] int32             (tokens per slot)
+
+``n_pages`` is whatever the block table addresses: one layer's pages, or
+the serving pool with every layer folded into the page axis
+(infer/paged_cache.py), the table then carrying the layer's offset.
+Kernels and writers reach pages only through the table, so neither
+knows the difference.
 
 Kernel design (per /opt/skills/guides/pallas_guide.md):
 
@@ -696,8 +702,47 @@ def paged_verify_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# Paged cache writes (pure JAX; XLA lowers to scatters)
+# Paged cache writes (pure JAX: dynamic_update_slices of the written rows)
 # ---------------------------------------------------------------------------
+# Every writer is a chain of ``dynamic_update_slice``s whose update is
+# the rows written and nothing more. ``k_pages`` may be a whole folded
+# pool (every layer's pages on the page axis, infer/paged_cache.py)
+# carried through a layer scan: XLA applies such an update in place, so
+# a write costs its rows. A scatter (``.at[:, pids, rows].set``) would
+# not do: the TPU compiler relays the whole operand around it.
+def _put(pages: jnp.ndarray, update: jnp.ndarray, pid, row
+         ) -> jnp.ndarray:
+    """pages[:, pid, row:row+n] = update ([hkv, n, ...]); n rows of one
+    page, for the values ([.., hd]) and the row scales alike."""
+    start = (0, pid, row) + (0,) * (pages.ndim - 3)
+    return jax.lax.dynamic_update_slice(pages, update[:, None], start)
+
+
+def _pools(k_pages, v_pages, k_scales, v_scales):
+    """The arrays a writer updates, in the order it returns them: ``(k,
+    v)``, plus the two scale pools on the int8 flavor."""
+    if k_scales is None:
+        return k_pages, v_pages
+    return k_pages, v_pages, k_scales, v_scales
+
+
+def _kv_rows(k_new: jnp.ndarray, v_new: jnp.ndarray, dtype,
+             quantized: bool):
+    """[..., hkv, hd] new rows -> what the pools hold, in ``_pools``'
+    order: cast to the pages' dtype, or quantized, with their row
+    scales after them."""
+    if not quantized:
+        return k_new.astype(dtype), v_new.astype(dtype)
+    (k, ks), (v, vs) = quantize_rows(k_new), quantize_rows(v_new)
+    return k, v, ks, vs
+
+
+def _put_all(pools, rows, pid, row):
+    """One position's (or one page's) rows into each pool."""
+    return tuple(_put(pool, new, pid, row)
+                 for pool, new in zip(pools, rows))
+
+
 def write_chunk_pages(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                       k_new: jnp.ndarray, v_new: jnp.ndarray,
                       table_row: jnp.ndarray, offset: jnp.ndarray,
@@ -718,108 +763,71 @@ def write_chunk_pages(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     C, hkv, hd = k_new.shape
     page = k_pages.shape[2]
     assert C % page == 0, (C, page)
-    quantized = k_scales is not None
-    if quantized:
-        kc, ksc = quantize_rows(k_new.transpose(1, 0, 2))  # [hkv, C, *]
-        vc, vsc = quantize_rows(v_new.transpose(1, 0, 2))
-    else:
-        kc = k_new.transpose(1, 0, 2).astype(k_pages.dtype)
-        vc = v_new.transpose(1, 0, 2).astype(v_pages.dtype)
+    rows = _kv_rows(k_new.transpose(1, 0, 2), v_new.transpose(1, 0, 2),
+                    k_pages.dtype, k_scales is not None)  # [hkv, C, *]
+    pools = _pools(k_pages, v_pages, k_scales, v_scales)
     first = jax.lax.div(offset, page)
     for i in range(C // page):
-        pid = table_row[first + i]
-        k_pages = jax.lax.dynamic_update_slice(
-            k_pages, kc[:, i * page:(i + 1) * page][:, None],
-            (0, pid, 0, 0))
-        v_pages = jax.lax.dynamic_update_slice(
-            v_pages, vc[:, i * page:(i + 1) * page][:, None],
-            (0, pid, 0, 0))
-        if quantized:
-            k_scales = jax.lax.dynamic_update_slice(
-                k_scales, ksc[:, i * page:(i + 1) * page][:, None],
-                (0, pid, 0))
-            v_scales = jax.lax.dynamic_update_slice(
-                v_scales, vsc[:, i * page:(i + 1) * page][:, None],
-                (0, pid, 0))
-    if quantized:
-        return k_pages, v_pages, k_scales, v_scales
-    return k_pages, v_pages
+        pools = _put_all(pools,
+                         [r[:, i * page:(i + 1) * page] for r in rows],
+                         table_row[first + i], 0)
+    return pools
 
 
 def append_run_pages(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                      k_new: jnp.ndarray, v_new: jnp.ndarray,
                      block_tables: jnp.ndarray, lengths: jnp.ndarray,
                      k_scales: Optional[jnp.ndarray] = None,
-                     v_scales: Optional[jnp.ndarray] = None):
+                     v_scales: Optional[jnp.ndarray] = None, *,
+                     sink_page=0):
     """Append a RUN of R tokens' K/V per slot at positions
     ``lengths[slot] + i`` — the speculative-verify write (input token
     plus padded draft candidates in one step).
 
-    k_new/v_new: [slots, R, hkv, hd]. One scatter per run position,
-    chained sequentially. Positions past the slot's block-table
-    coverage (padded drafts of a slot the engine capped, inactive
-    slots' garbage lanes) redirect to the SINK page 0 — the table
-    lookup is clamped and overridden, never allowed to alias a live
-    page the way a clamped index would. With scales (int8 flavor) each
-    run row is quantized on write and returns a 4-tuple.
+    k_new/v_new: [slots, R, hkv, hd]. One row write per slot and run
+    position, chained in run order. Positions past the slot's
+    block-table coverage (padded drafts of a slot the engine capped,
+    inactive slots' garbage lanes) redirect to ``sink_page`` (page 0; a
+    layer's own page 0 when the tables address a folded pool) — the
+    table lookup is clamped and overridden, never allowed to alias a
+    live page the way a clamped index would. With scales (int8 flavor)
+    each run row is quantized on write and returns a 4-tuple.
     """
     page = k_pages.shape[2]
-    maxp = block_tables.shape[1]
+    slots, maxp = block_tables.shape
     R = k_new.shape[1]
-    quantized = k_scales is not None
+    rows = _kv_rows(k_new, v_new, k_pages.dtype, k_scales is not None)
+    pools = _pools(k_pages, v_pages, k_scales, v_scales)
     for i in range(R):
         pos = lengths + i
         col = pos // page
-        valid = col < maxp
         pids = jnp.take_along_axis(
             block_tables, jnp.minimum(col, maxp - 1)[:, None],
             axis=1)[:, 0]
-        pids = jnp.where(valid, pids, 0)
-        rows = pos % page
-        if quantized:
-            kq, ks = quantize_rows(k_new[:, i].transpose(1, 0, 2))
-            vq, vs = quantize_rows(v_new[:, i].transpose(1, 0, 2))
-            k_pages = k_pages.at[:, pids, rows].set(kq)
-            v_pages = v_pages.at[:, pids, rows].set(vq)
-            k_scales = k_scales.at[:, pids, rows].set(ks)
-            v_scales = v_scales.at[:, pids, rows].set(vs)
-        else:
-            k_pages = k_pages.at[:, pids, rows].set(
-                k_new[:, i].transpose(1, 0, 2).astype(k_pages.dtype))
-            v_pages = v_pages.at[:, pids, rows].set(
-                v_new[:, i].transpose(1, 0, 2).astype(v_pages.dtype))
-    if quantized:
-        return k_pages, v_pages, k_scales, v_scales
-    return k_pages, v_pages
+        pids = jnp.where(col < maxp, pids, sink_page)
+        at = pos % page
+        for s in range(slots):
+            pools = _put_all(pools, [r[s, i][:, None] for r in rows],
+                             pids[s], at[s])
+    return pools
 
 
 def append_token_pages(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                        k_new: jnp.ndarray, v_new: jnp.ndarray,
                        block_tables: jnp.ndarray, lengths: jnp.ndarray,
                        k_scales: Optional[jnp.ndarray] = None,
-                       v_scales: Optional[jnp.ndarray] = None):
-    """Append one token's K/V per slot at position lengths[slot].
+                       v_scales: Optional[jnp.ndarray] = None, *,
+                       sink_page=0):
+    """Append one token's K/V per slot at position lengths[slot]: a run
+    of one (``append_run_pages``).
 
-    k_new/v_new: [slots, hkv, hd]. One vectorized scatter per array:
-    slot i's row lands in page table[i, len//page] at row len%page.
-    Distinct slots own distinct pages, so the scatter indices never
-    collide (XLA may apply them in any order). With scales (int8
-    flavor) the row quantizes on write and returns a 4-tuple.
+    k_new/v_new: [slots, hkv, hd]. Slot i's row lands in page
+    table[i, len//page] at row len%page: one ``[hkv, 1, 1, hd]`` update
+    per slot. Active slots own distinct pages; inactive slots' garbage
+    rows may share the sink page, where the last one written stays.
+    With scales (int8 flavor) the row quantizes on write and returns a
+    4-tuple.
     """
-    page = k_pages.shape[2]
-    pids = jnp.take_along_axis(
-        block_tables, (lengths // page)[:, None], axis=1)[:, 0]
-    rows = lengths % page
-    if k_scales is not None:
-        kq, ks = quantize_rows(k_new.transpose(1, 0, 2))
-        vq, vs = quantize_rows(v_new.transpose(1, 0, 2))
-        k_pages = k_pages.at[:, pids, rows].set(kq)
-        v_pages = v_pages.at[:, pids, rows].set(vq)
-        k_scales = k_scales.at[:, pids, rows].set(ks)
-        v_scales = v_scales.at[:, pids, rows].set(vs)
-        return k_pages, v_pages, k_scales, v_scales
-    k_pages = k_pages.at[:, pids, rows].set(
-        k_new.transpose(1, 0, 2).astype(k_pages.dtype))
-    v_pages = v_pages.at[:, pids, rows].set(
-        v_new.transpose(1, 0, 2).astype(v_pages.dtype))
-    return k_pages, v_pages
+    return append_run_pages(k_pages, v_pages, k_new[:, None],
+                            v_new[:, None], block_tables, lengths,
+                            k_scales, v_scales, sink_page=sink_page)

@@ -91,6 +91,8 @@ def test_transfer_bit_identical_to_local_recompute(params, blob,
     pages, n = puller.prefix.peek(_PREFIX, whole=True)
     assert n == 32
     from skypilot_tpu.infer import kv_wire
+    from skypilot_tpu.infer import paged_cache
     blk = kv_wire.unpack(blob)
     np.testing.assert_array_equal(
-        np.asarray(puller.cache.k_pages[:, :, pages]), blk.k)
+        np.asarray(paged_cache.gather_pages(puller.cache, pages)[0]),
+        blk.k)

@@ -149,6 +149,33 @@ def test_append_run_pages_writes_and_sink_redirects():
     assert not k2[:, 2].any()
 
 
+@pytest.mark.parametrize('base', [0, 6])
+def test_append_run_pages_sink_follows_the_tables_offset(base):
+    """Tables that address a folded pool carry their layer's offset
+    (here ``base``, one "layer" of 6 pages into a 12-page pool): the
+    writer is handed that layer's own page 0 as ``sink_page`` and the
+    pad tail lands there, not in physical page 0."""
+    hkv, hd, page, P, maxp = 2, 8, 4, 12, 2
+    k_pages = jnp.zeros((hkv, P, page, hd), jnp.float32)
+    tables = jnp.asarray([[3, 4], [5, 0]], jnp.int32) + base
+    lengths = jnp.asarray([3, 7], jnp.int32)
+    k_new = jnp.arange(2 * 3 * hkv * hd, dtype=jnp.float32).reshape(
+        2, 3, hkv, hd) + 1.0
+    k2, _ = pa.append_run_pages(k_pages, k_pages, k_new, k_new, tables,
+                                lengths, sink_page=base)
+    k2 = np.asarray(k2)
+    np.testing.assert_array_equal(k2[:, base + 3, 3], k_new[0, 0])
+    np.testing.assert_array_equal(k2[:, base + 4, 1], k_new[0, 2])
+    # Slot 1's position 7 is in its unowned column 1 (a zero entry:
+    # this layer's page 0 once offset); 8 and 9 are past the table and
+    # redirected: rows 3, 0 and 1 of this layer's sink, no other page.
+    np.testing.assert_array_equal(k2[:, base, 3], k_new[1, 0])
+    np.testing.assert_array_equal(k2[:, base, 0], k_new[1, 1])
+    np.testing.assert_array_equal(k2[:, base, 1], k_new[1, 2])
+    written = {int(p) for p in np.nonzero(k2.any(axis=(0, 2, 3)))[0]}
+    assert written == {base, base + 3, base + 4}
+
+
 def test_append_token_pages_lands_in_right_page_rows():
     hkv, P, page, hd, slots = 2, 6, 4, 8, 3
     k_pages = jnp.zeros((hkv, P, page, hd), jnp.float32)
@@ -314,6 +341,33 @@ def test_paged_engine_matches_dense_greedy():
     assert m['paged'] and m['preemptions'] == 0
     # All pages returned once requests finished.
     assert m['pages_free'] == m['pages_total'] - 1
+
+
+def test_paged_engine_pool_reads_back_by_layer_and_page():
+    """``gather_pages`` is the logical view of the folded pool: while a
+    request holds pages, every layer shows rows in exactly those pages
+    (plus the sink, where inactive slots' rows go) and the layers
+    differ from one another."""
+    _, paged = _engines()
+    prompt = [(i * 7 + 3) % 250 for i in range(40)]    # 3 pages of 16
+    req = paged.submit(prompt, max_new_tokens=4)
+    while not req.output_tokens:
+        paged.step()
+    owned = paged.allocator.owned_pages(paged._slots.index(req))
+    assert len(owned) == 3
+    cache = paged.cache
+    assert cache.k_pages.shape[1] == cache.n_layers * cache.n_pages
+    k, v, ks, vs = paged_cache_lib.gather_pages(
+        cache, np.arange(cache.n_pages))
+    assert ks is None and vs is None
+    assert k.shape == (cache.n_layers, 2, cache.n_pages, 16, 16)
+    used = np.asarray(k).any(axis=(1, 3, 4))           # [L, P]
+    for layer in range(cache.n_layers):
+        assert set(np.nonzero(used[layer])[0]) <= set(owned) | {0}
+        assert set(owned) <= set(np.nonzero(used[layer])[0])
+    assert np.abs(np.asarray(k[0]) - np.asarray(k[1])).max() > 0
+    while not req.done:
+        paged.step()
 
 
 def test_paged_engine_mixed_lengths_share_pool():

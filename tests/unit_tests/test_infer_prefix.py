@@ -150,15 +150,20 @@ def test_copy_page_duplicates_kv_bytes():
     cache = paged_cache_lib.init_paged_cache(
         n_layers=2, n_slots=2, n_pages=5, page_size=4, n_kv_heads=2,
         head_dim=8, dtype=jnp.float32)
-    marked = cache.k_pages.at[:, :, 2].set(7.0)
-    cache = paged_cache_lib.PagedKVCache(
-        k_pages=marked, v_pages=cache.v_pages.at[:, :, 2].set(3.0),
-        lengths=cache.lengths)
+    # Layer l's page 2 holds 7 + l (K) and 3 + l (V): a copy that mixed
+    # layers up would show.
+    mark = jnp.arange(2, dtype=jnp.float32).reshape(2, 1, 1, 1, 1)
+    cache = paged_cache_lib.scatter_pages(
+        cache, [2], jnp.zeros((2, 2, 1, 4, 8)) + 7.0 + mark,
+        jnp.zeros((2, 2, 1, 4, 8)) + 3.0 + mark)
     out = jax.jit(paged_cache_lib.copy_page)(
         cache, jnp.int32(2), jnp.int32(4))
-    assert (np.asarray(out.k_pages[:, :, 4]) == 7.0).all()
-    assert (np.asarray(out.v_pages[:, :, 4]) == 3.0).all()
-    assert (np.asarray(out.k_pages[:, :, 1]) == 0.0).all()
+    k, v, _, _ = paged_cache_lib.gather_pages(out, np.arange(5))
+    for layer in range(2):
+        assert (np.asarray(k[layer, :, 4]) == 7.0 + layer).all()
+        assert (np.asarray(v[layer, :, 4]) == 3.0 + layer).all()
+        assert (np.asarray(k[layer, :, 2]) == 7.0 + layer).all()
+    assert (np.asarray(k[:, :, [0, 1, 3]]) == 0.0).all()
     assert (np.asarray(out.lengths) == 0).all()
 
 
@@ -321,17 +326,17 @@ def test_forced_shared_frontier_page_is_cowed(params):
     old = al.owned_pages(0)
     al.incref(old[1])                           # simulate a tree ref
     on._attached_slots.add(0)                   # slot scans as attached
-    marked = on.cache.k_pages.at[:, :, old[1]].set(5.0)
-    on.cache = paged_cache_lib.PagedKVCache(
-        k_pages=marked, v_pages=on.cache.v_pages,
-        lengths=on.cache.lengths)
+    k, v, _, _ = paged_cache_lib.gather_pages(on.cache, [old[1]])
+    on.cache = paged_cache_lib.scatter_pages(
+        on.cache, [old[1]], jnp.full_like(k, 5.0), v)
     on._unshare_write_range(0, 17, 20)
     new = al.owned_pages(0)
     assert new[0] == old[0]                     # untouched: not in range
     assert new[1] != old[1]                     # swapped for a copy
     assert al.refcount(old[1]) == 1             # "tree" ref survives
     assert al.refcount(new[1]) == 1
-    assert (np.asarray(on.cache.k_pages[:, :, new[1]]) == 5.0).all()
+    k, _, _, _ = paged_cache_lib.gather_pages(on.cache, [new[1]])
+    assert (np.asarray(k) == 5.0).all()
     # Cleanup: drop the simulated refs; pool must balance.
     al.free(0)
     al.decref(old[1])

@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 from skypilot_tpu.infer import kv_wire
+from skypilot_tpu.infer import paged_cache as paged_cache_lib
 
 pytestmark = pytest.mark.jax
 
@@ -206,26 +207,67 @@ def test_int8_export_import_byte_exact_refcounts_untouched(params):
 
     blk = kv_wire.unpack(blob)
     assert blk.tokens == _PROMPT[:32]
-    np.testing.assert_array_equal(
-        blk.k, np.asarray(donor.cache.k_pages[:, :, pages]))
-    np.testing.assert_array_equal(
-        blk.k_scales, np.asarray(donor.cache.k_scales[:, :, pages]))
+    held = paged_cache_lib.gather_pages(donor.cache, pages)
+    np.testing.assert_array_equal(blk.k, np.asarray(held[0]))
+    np.testing.assert_array_equal(blk.k_scales, np.asarray(held[2]))
 
     puller = _engine(params)
     grafted = puller._kv_import(blob)
     assert grafted == 2
     got, n = puller.prefix.peek(_PROMPT, whole=True)
     assert n == 32
-    np.testing.assert_array_equal(
-        np.asarray(puller.cache.k_pages[:, :, got]), blk.k)
-    np.testing.assert_array_equal(
-        np.asarray(puller.cache.v_pages[:, :, got]), blk.v)
-    np.testing.assert_array_equal(
-        np.asarray(puller.cache.k_scales[:, :, got]), blk.k_scales)
-    np.testing.assert_array_equal(
-        np.asarray(puller.cache.v_scales[:, :, got]), blk.v_scales)
+    landed = paged_cache_lib.gather_pages(puller.cache, got)
+    for have, want in zip(landed, (blk.k, blk.v, blk.k_scales,
+                                   blk.v_scales)):
+        np.testing.assert_array_equal(np.asarray(have), want)
     # Export from the puller re-serializes to the identical blob.
     assert puller._kv_export(_PROMPT) == blob
+
+
+@pytest.mark.parametrize('src_pages,dst_pages', [
+    ([3, 1], [2, 5]),          # out of order on both sides
+    ([6], [6]),                # the same id, the last page
+    ([1, 2, 3, 4], [4, 3, 2, 1]),
+])
+def test_pool_round_trip_through_the_wire_is_bit_identical(src_pages,
+                                                           dst_pages):
+    """gather -> pack -> unpack -> scatter between two int8 pools: the
+    destination's pages hold the source's exact bytes in every layer,
+    and no other page of the destination pool (compared as the raw
+    physical arrays) changed."""
+    import jax
+    L, hkv, P, page, hd = 3, 2, 7, 4, 8
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    src = paged_cache_lib.init_paged_cache(L, 2, P, page, hkv, hd,
+                                           dtype='int8')
+    fill = paged_cache_lib.scatter_pages(
+        src, np.arange(P),
+        jax.random.randint(keys[0], (L, hkv, P, page, hd), -127, 128),
+        jax.random.randint(keys[1], (L, hkv, P, page, hd), -127, 128),
+        jax.random.uniform(keys[2], (L, hkv, P, page)),
+        jax.random.uniform(keys[3], (L, hkv, P, page)))
+    k, v, ks, vs = (np.asarray(a) for a in
+                    paged_cache_lib.gather_pages(fill, src_pages))
+    assert k.shape == (L, hkv, len(src_pages), page, hd)
+    tokens = list(range(len(src_pages) * page))
+    blk = kv_wire.unpack(kv_wire.pack(tokens, page, k, v, ks, vs))
+    dst = paged_cache_lib.scatter_pages(src, dst_pages, blk.k, blk.v,
+                                        blk.k_scales, blk.v_scales)
+    for have, want in zip(paged_cache_lib.gather_pages(dst, dst_pages),
+                          (k, v, ks, vs)):
+        np.testing.assert_array_equal(np.asarray(have), want)
+    rest = [p for p in range(P) if p not in dst_pages]
+    for arr in paged_cache_lib.gather_pages(dst, rest):
+        assert not np.asarray(arr).any()
+    # Physically: layer l's page p sits at l * P + p, for K, V and scales.
+    for layer in range(L):
+        for s, d in zip(src_pages, dst_pages):
+            np.testing.assert_array_equal(
+                np.asarray(dst.k_pages[:, layer * P + d]),
+                np.asarray(fill.k_pages[:, layer * P + s]))
+            np.testing.assert_array_equal(
+                np.asarray(dst.v_scales[:, layer * P + d]),
+                np.asarray(fill.v_scales[:, layer * P + s]))
 
 
 def test_import_grafts_only_past_local_boundary(params):
@@ -258,7 +300,8 @@ def test_bf16_round_trip_within_pinned_tolerance(params):
     pages, _ = donor.prefix.peek(_PROMPT, whole=True)
     blob = donor._kv_export(_PROMPT)
     blk = kv_wire.unpack(blob)
-    want = np.asarray(donor.cache.k_pages[:, :, pages], np.float32)
+    want = np.asarray(
+        paged_cache_lib.gather_pages(donor.cache, pages)[0], np.float32)
     deq = kv_wire.dequantize_rows_np(blk.k, blk.k_scales)
     err = np.abs(deq - want)
     bound = blk.k_scales[..., None] * 0.5 + 1e-6
@@ -268,7 +311,8 @@ def test_bf16_round_trip_within_pinned_tolerance(params):
     assert puller._kv_import(blob) == 2
     got, n = puller.prefix.peek(_PROMPT, whole=True)
     assert n == 32
-    land = np.asarray(puller.cache.k_pages[:, :, got], np.float32)
+    land = np.asarray(
+        paged_cache_lib.gather_pages(puller.cache, got)[0], np.float32)
     # Grafted pages are the dequantized wire values cast to the pool
     # dtype — nothing further drifts on import.
     np.testing.assert_array_equal(
