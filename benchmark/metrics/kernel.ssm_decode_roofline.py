@@ -1,0 +1,20 @@
+"""The Mamba-2 mixers of the decode program against their roofline."""
+from benchmark import scope_reduce, work_nemotron_h
+from benchmark.metrics import _common
+
+
+def read(run):
+    trace = run['trace']
+    if not trace:
+        return None
+    own = _common.own_file(__file__)
+    seconds, count = scope_reduce.seconds_of(
+        trace.get('scopes'), own['programs_match'], own['scope'])
+    slot_steps = _common.counter_delta(run, 'ssm_slot_steps', traced=True)
+    steps = _common.counter_delta(run, 'decode_steps', traced=True)
+    if not count or seconds <= 0 or not slot_steps or not steps:
+        return None
+    flops, bytes_ = work_nemotron_h.ssm_decode_work(run['config'],
+                                                    slot_steps, steps)
+    return work_nemotron_h.roofline_share(flops, bytes_, seconds,
+                                          trace['peak'])['percent']

@@ -36,7 +36,7 @@ import itertools
 import threading
 import time
 import zlib
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +50,7 @@ from skypilot_tpu.infer import paged_cache as paged_cache_lib
 from skypilot_tpu.infer import prefix_cache as prefix_cache_lib
 from skypilot_tpu.infer import sampling as sampling_lib
 from skypilot_tpu.infer import sched as sched_lib
+from skypilot_tpu.models import interface
 from skypilot_tpu.models import llama
 from skypilot_tpu.observability import stepline as stepline_lib
 from skypilot_tpu.utils import failpoints
@@ -488,6 +489,7 @@ class InferenceEngine:
         '_kv_transfer_failures': '_lock',
         '_kv_transfer_window': '_lock',
         '_kv_index_pub': '_lock',
+        '_model_counters': '_lock',  # consume adds vs metrics reads
     }
 
     def __init__(self, config: llama.LlamaConfig, params: llama.Params,
@@ -495,6 +497,20 @@ class InferenceEngine:
                  seed: int = 0) -> None:
         self.config = config
         self.ecfg = engine_config or EngineConfig()
+        # The serving half of the model interface (models/interface.py):
+        # what state the model's layers keep, its step programs, and
+        # the switches it refuses (raised here, with the reason).
+        interface.check_engine(config, self.ecfg)
+        spec = interface.cache_spec(config)
+        steps = model_lib.paged_steps(config)
+        self._state_spec = spec.state
+        # Counts the decode program returns beside its logits (a hybrid
+        # model's expert and state counters), carried out on the step's
+        # pair and summed here; /metrics shows them.
+        self._step_stats: Tuple[str, ...] = (
+            steps.stats if self.ecfg.paged else ())
+        self._model_counters: Dict[str, int] = {
+            name: 0 for name in self._step_stats}
         if self.ecfg.max_seq_len > config.max_seq_len:
             raise ValueError(
                 f'cache max_seq_len {self.ecfg.max_seq_len} exceeds model '
@@ -560,9 +576,8 @@ class InferenceEngine:
                     f'{self.ecfg.kv_dtype!r}')
             kv_dtype = (jnp.int8 if self.ecfg.kv_dtype == 'int8'
                         else jnp.dtype(self.ecfg.cache_dtype))
-            self.cache = paged_cache_lib.init_paged_cache(
-                config.n_layers, self.ecfg.n_slots, n_pages, page,
-                config.n_kv_heads, config.head_dim, dtype=kv_dtype)
+            self.cache = steps.init_cache(
+                spec, self.ecfg.n_slots, n_pages, page, kv_dtype)
         else:
             if self.ecfg.kv_dtype not in ('bfloat16',):
                 raise ValueError(
@@ -797,7 +812,7 @@ class InferenceEngine:
             def _prefill_chunk_paged(kv_cache, params, slot, table_row,
                                      tokens, offset, true_len, key,
                                      temp, last):
-                new_cache, logits = model_lib.paged_prefill_chunk(
+                new_cache, logits = steps.prefill_chunk(
                     config, params, kv_cache, slot, table_row, tokens,
                     offset, true_len)
                 tok = sampling_lib.sample(logits[None], key, temp[None],
@@ -809,26 +824,32 @@ class InferenceEngine:
 
             def _decode_paged(kv_cache, params, tables, tokens, key,
                               temps, active):
-                logits, new_cache = model_lib.paged_decode_step(
+                logits, new_cache, *stats = steps.decode(
                     config, params, kv_cache, tables, tokens, active)
                 sampled = sampling_lib.sample(logits, key, temps,
                                               top_k=self.ecfg.top_k)
                 toks_out = jnp.where(active, sampled, tokens)
                 rows = [tokens, toks_out]
+                # A family's step counts ride the pair as one row each
+                # (the value in every column), after the token rows and
+                # before the sentinel's, which consume reads as the last.
+                # (``stats`` holds one vector of them, or nothing.)
+                rows += [jnp.full_like(tokens, c)
+                         for counts in stats for c in counts]
                 if self._sentinel:
                     rows.append(_finite_row(logits))
                 return jnp.stack(rows), new_cache
             self._decode = _jit(_decode_paged, donate=(0,))
 
             def _free_paged(kv_cache, slot):
-                return paged_cache_lib.free_slot(kv_cache, slot)
+                return steps.free_slot(kv_cache, slot)
             self._free = _jit(_free_paged, donate=(0,))
 
             def _verify_paged(kv_cache, params, tables, last, drafts,
                               draft_len, key, temps, active):
                 tokens = jnp.concatenate([last[:, None], drafts],
                                          axis=1)
-                logits, new_cache = model_lib.paged_verify_step(
+                logits, new_cache = steps.verify(
                     config, params, kv_cache, tables, tokens)
                 pair, new_last, lengths = _accept(
                     tokens, logits, drafts, draft_len, key, temps,
@@ -849,7 +870,7 @@ class InferenceEngine:
                 # chunk's first token surfaces through the SAME host
                 # read as the decode tokens.
                 chunk_logits, dec_logits, new_cache = (
-                    model_lib.paged_mixed_step(
+                    steps.mixed(
                         config, params, kv_cache, slot, table_row,
                         chunk_tokens, offset, true_len, tables, last,
                         active))
@@ -1165,11 +1186,19 @@ class InferenceEngine:
         return prefix_hash.build_snapshot(gen, crc, page, journal,
                                           hashes, since_gen)
 
+    def _refuse_kv_wire(self) -> None:
+        reason = interface.refusals(self.config).get('kv_wire')
+        if reason:
+            raise ValueError(
+                f'{type(self.config).__name__} cannot export or import '
+                f'KV pages (kv_wire): {reason}')
+
     def request_kv_export(self, tokens: Sequence[int]) -> _KVJob:
         """Queue an export of the cached prefix of ``tokens`` (any
         thread). The stepping thread serializes it at its next step;
         ``job.result`` is the wire blob, or None when nothing is
         cached. The donor's refcounts are never touched."""
+        self._refuse_kv_wire()
         job = _KVJob('export', list(tokens))
         with self._lock:
             self._kv_jobs.append(job)
@@ -1181,6 +1210,7 @@ class InferenceEngine:
         ``fetch_s`` — the upstream pull's wall time — folds into the
         transfer-duration window so ``kv_transfer_p99_s`` prices the
         whole pull, not just the local attach."""
+        self._refuse_kv_wire()
         job = _KVJob('import', blob, fetch_s=fetch_s)
         with self._lock:
             self._kv_jobs.append(job)
@@ -2304,6 +2334,10 @@ class InferenceEngine:
             bad = {s for s in range(flags.shape[0]) if not flags[s]}
         touched: List[Request] = []
         with self._lock:
+            # The family's step counts: rows 2.. of a decode pair, the
+            # same value in every column (``_decode_paged``).
+            for j, name in enumerate(self._step_stats):
+                self._model_counters[name] += int(pair_host[2 + j, 0])
             for slot, req in prefilled:
                 if req is None or req.done or self._slots[slot] is not req:
                     continue   # finished/preempted since dispatch
@@ -2781,7 +2815,8 @@ class InferenceEngine:
                 kv_transfers=self._kv_transfers,
                 kv_bytes=self._kv_transfer_bytes,
                 kv_failures=self._kv_transfer_failures,
-                kv_window=list(self._kv_transfer_window))
+                kv_window=list(self._kv_transfer_window),
+                model_counters=dict(self._model_counters))
             return (list(self._ttfts), list(self._queue_waits),
                     self._sched.snapshot(), counters,
                     self.prefix.stats() if self.prefix is not None
@@ -2901,6 +2936,14 @@ class InferenceEngine:
                 'kv_dtype': self.ecfg.kv_dtype,
                 'kv_page_bytes': self.cache.page_bytes}
                if self.allocator is not None else {}),
+            # A model with recurrent layers (infer/state_cache.py):
+            # the HBM its per-slot state takes and the slots that hold
+            # a live one; then the decode program's own counts
+            # (model.HYBRID_STEP_STATS), summed over decode steps.
+            **({'state_bytes': self.cache.state_bytes,
+                'state_slots': c['num_active']}
+               if self._state_spec is not None else {}),
+            **c['model_counters'],
             **prefix_stats,
         }
 

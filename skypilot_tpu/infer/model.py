@@ -29,19 +29,34 @@ into the pages) and ``mlp``, then ``head``; the sampler adds
 ``sample``. A scope changes no instruction, only the ``op_name`` in
 its metadata, by which a profiler trace's device events can be
 grouped.
+
+**Heterogeneous stacks** (``models/nemotron_h.py``: Mamba-2, expert and
+attention blocks in one model) have their own two paged programs at
+the end of this file, ``hybrid_prefill_chunk`` and
+``hybrid_decode_step``, over ``state_cache.HybridCache``: the page pool
+of the attention layers AND the per-slot recurrent state of the
+state-space layers ride the step as donated carries, each layer
+rewriting its own rows in place. Their scopes are ``ssm`` (the
+Mamba-2 mixer), ``attn`` / ``kv_write`` (as above), ``moe.route`` /
+``moe.experts`` / ``moe.shared``. ``paged_steps(config)`` hands the
+engine the programs of a configuration's family: the serving half of
+the model interface (``models/interface.py``).
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from skypilot_tpu.infer import cache as cache_lib
 from skypilot_tpu.infer import paged_cache as paged_cache_lib
+from skypilot_tpu.infer import state_cache as state_cache_lib
+from skypilot_tpu.models import interface
 from skypilot_tpu.models import llama
+from skypilot_tpu.models import nemotron_h
 from skypilot_tpu.ops import norms
 from skypilot_tpu.ops import paged_attention as paged_attn
 from skypilot_tpu.ops import quant as quant_lib
@@ -664,3 +679,192 @@ def paged_mixed_step(config: llama.LlamaConfig, params: llama.Params,
     bump = active.astype(lengths_mid.dtype)
     return chunk_logits, dec_logits, dataclasses.replace(
         pkv, lengths=lengths_mid + bump)
+
+
+# ---------------------------------------------------------------------------
+# Heterogeneous stacks: recurrent state beside the page pool
+
+# What a hybrid decode step counts, summed over its layers (int32):
+# the expert layer's ``moe_dropless.STATS`` and the slots whose
+# recurrent state the step advanced.
+HYBRID_STEP_STATS = ('moe_local_assignments', 'moe_experts_touched',
+                     'moe_expert_load_max', 'ssm_slot_steps')
+
+
+def _hybrid_attn_chunk(config, x, layer, kv, table_row, offset, true_len):
+    """A ``*`` block over one chunk. kv: the folded pool of the
+    attention layers; table_row: this layer's PHYSICAL pages; x [C, d]."""
+    C = x.shape[0]
+    with jax.named_scope('attn'):
+        q, k, v = nemotron_h.attn_qkv(config, layer, x)
+    with jax.named_scope('kv_write'):
+        kv = _with_pages(kv, paged_attn.write_chunk_pages(
+            kv.k_pages, kv.v_pages, k, v, table_row, offset, None, None))
+    with jax.named_scope('attn'):
+        att = paged_attn.paged_prefill_attention(
+            q, kv.k_pages, kv.v_pages, table_row, offset, true_len)
+        att = att.reshape(C, -1).astype(x.dtype)
+        x = x + jnp.dot(att, layer['wo'])
+    return x, kv
+
+
+def _hybrid_attn_decode(config, x, layer, kv, block_tables, positions,
+                        sink_page):
+    """A ``*`` block for one token of every slot; x [slots, d]."""
+    slots = x.shape[0]
+    with jax.named_scope('attn'):
+        q, k, v = nemotron_h.attn_qkv(config, layer, x)
+    with jax.named_scope('kv_write'):
+        kv = _with_pages(kv, paged_attn.append_token_pages(
+            kv.k_pages, kv.v_pages, k, v, block_tables, positions, None,
+            None, sink_page=sink_page))
+    with jax.named_scope('attn'):
+        att = paged_attn.paged_decode_attention(
+            q, kv.k_pages, kv.v_pages, block_tables, positions + 1)
+        att = att.reshape(slots, -1).astype(x.dtype)
+        x = x + jnp.dot(att, layer['wo'])
+    return x, kv
+
+
+def hybrid_prefill_chunk(config: nemotron_h.NemotronHConfig,
+                         params: nemotron_h.Params,
+                         cache: state_cache_lib.HybridCache,
+                         slot: jnp.ndarray, table_row: jnp.ndarray,
+                         tokens: jnp.ndarray, offset: jnp.ndarray,
+                         true_len: jnp.ndarray
+                         ) -> Tuple[state_cache_lib.HybridCache,
+                                    jnp.ndarray]:
+    """``paged_prefill_chunk``'s contract over a heterogeneous stack.
+
+    Beside the K/V rows of the attention layers, the chunk carries the
+    slot's recurrent state forward: each ``M`` layer starts from the
+    slot's state (zero when ``offset`` is 0: a prefill from the start
+    IS the reset) and leaves the state as it stands after token
+    ``true_len - 1``. The padded tail advances nothing: not the SSM
+    state, not the convolution's window, and it reaches no expert."""
+    C = tokens.shape[0]
+    with jax.named_scope('embed'):
+        x = params['embed'][tokens]                       # [C, d]
+    valid = jnp.arange(C, dtype=jnp.int32) < true_len
+    kv = cache.kv
+    for kind, i in config.layers():
+        layer = params['layers'][kind][i]
+        if kind == 'M':
+            with jax.named_scope('ssm'):
+                ssm, conv = state_cache_lib.slot_state(cache, i, slot,
+                                                       offset)
+                y, ssm, conv = nemotron_h.mamba_chunk(
+                    config, layer, x, ssm, conv, true_len)
+                x = x + y
+                cache = state_cache_lib.with_slot_state(cache, i, slot,
+                                                        ssm, conv)
+        elif kind == '*':
+            row = paged_cache_lib.physical_pages(kv.n_pages, i, table_row)
+            x, kv = _hybrid_attn_chunk(config, x, layer, kv, row, offset,
+                                       true_len)
+        else:
+            y, _ = nemotron_h.moe_mixer(config, layer, x, valid)
+            x = x + y
+    with jax.named_scope('head'):
+        last = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=0,
+                                            keepdims=False)
+        logits = nemotron_h.head(config, params, last)
+    lengths = kv.lengths.at[slot].set((offset + true_len).astype(jnp.int32))
+    return dataclasses.replace(
+        cache, kv=dataclasses.replace(kv, lengths=lengths)), logits
+
+
+def hybrid_decode_step(config: nemotron_h.NemotronHConfig,
+                       params: nemotron_h.Params,
+                       cache: state_cache_lib.HybridCache,
+                       block_tables: jnp.ndarray, tokens: jnp.ndarray,
+                       active: Optional[jnp.ndarray] = None
+                       ) -> Tuple[jnp.ndarray, state_cache_lib.HybridCache,
+                                  jnp.ndarray]:
+    """``paged_decode_step``'s contract over a heterogeneous stack, and
+    a third result: the step's ``HYBRID_STEP_STATS`` counts.
+
+    A slot that is not ``active`` (free, or mid-way through a chunked
+    prefill) computes garbage, as in every decode program. Its K/V row
+    lands where the next real write covers it; its recurrent state
+    must not move at all, because nothing ever overwrites a state:
+    ``mamba_decode`` keeps it bit for bit. Nor does it reach an
+    expert."""
+    slots = tokens.shape[0]
+    if active is None:
+        active = jnp.ones((slots,), bool)
+    kv = cache.kv
+    positions = kv.lengths
+    with jax.named_scope('embed'):
+        x = params['embed'][tokens]                       # [slots, d]
+    moe_stats = jnp.zeros((3,), jnp.int32)
+    for kind, i in config.layers():
+        layer = params['layers'][kind][i]
+        if kind == 'M':
+            with jax.named_scope('ssm'):
+                y, ssm, conv = nemotron_h.mamba_decode(
+                    config, layer, x, cache.ssm[i], cache.conv[i], active)
+                x = x + y
+                cache = state_cache_lib.with_layer_state(cache, i, ssm,
+                                                         conv)
+        elif kind == '*':
+            physical = functools.partial(paged_cache_lib.physical_pages,
+                                         kv.n_pages, i)
+            x, kv = _hybrid_attn_decode(config, x, layer, kv,
+                                        physical(block_tables), positions,
+                                        physical(0))
+        else:
+            y, stats = nemotron_h.moe_mixer(config, layer, x, active)
+            x = x + y
+            moe_stats = moe_stats + stats
+    with jax.named_scope('head'):
+        logits = nemotron_h.head(config, params, x)
+    lengths = kv.lengths + active.astype(kv.lengths.dtype)
+    stats = jnp.concatenate(
+        [moe_stats, jnp.sum(active, dtype=jnp.int32)[None]])
+    return logits, dataclasses.replace(
+        cache, kv=dataclasses.replace(kv, lengths=lengths)), stats
+
+
+# ---------------------------------------------------------------------------
+# The serving half of the model interface: a family's step programs
+
+@dataclasses.dataclass(frozen=True)
+class PagedSteps:
+    """What the engine jits for a configuration's family. ``verify``
+    and ``mixed`` are None where the family has none (the engine
+    refuses the switches that would need them). ``decode`` returns
+    (logits, cache) and, where ``stats`` names any, a third value: one
+    int32 count per name, which the engine carries out on the step's
+    pair."""
+    prefill_chunk: Callable[..., Any]
+    decode: Callable[..., Any]
+    init_cache: Callable[..., Any]   # (spec, slots, pages, page, dtype)
+    free_slot: Callable[..., Any]
+    verify: Optional[Callable[..., Any]] = None
+    mixed: Optional[Callable[..., Any]] = None
+    stats: Tuple[str, ...] = ()
+
+
+def _init_paged(spec: interface.CacheSpec, n_slots, n_pages, page, dtype):
+    return paged_cache_lib.init_paged_cache(
+        spec.kv_layers, n_slots, n_pages, page, spec.n_kv_heads,
+        spec.head_dim, dtype=dtype)
+
+
+def hybrid_steps() -> PagedSteps:
+    """What ``NemotronHConfig.paged_steps()`` hands the engine."""
+    return PagedSteps(
+        prefill_chunk=hybrid_prefill_chunk, decode=hybrid_decode_step,
+        init_cache=state_cache_lib.init_hybrid_cache,
+        free_slot=state_cache_lib.free_slot, stats=HYBRID_STEP_STATS)
+
+
+def paged_steps(config: Any) -> PagedSteps:
+    """``config.paged_steps()``, or the dense block's programs."""
+    if hasattr(config, 'paged_steps'):
+        return config.paged_steps()
+    return PagedSteps(
+        prefill_chunk=paged_prefill_chunk, decode=paged_decode_step,
+        verify=paged_verify_step, mixed=paged_mixed_step,
+        init_cache=_init_paged, free_slot=paged_cache_lib.free_slot)

@@ -36,7 +36,9 @@ import jax
 from aiohttp import web
 
 from skypilot_tpu.infer import engine as engine_lib
+from skypilot_tpu.models import interface
 from skypilot_tpu.models import llama
+from skypilot_tpu.models import nemotron_h
 from skypilot_tpu.observability import prometheus as prom_lib
 from skypilot_tpu.utils import common as common_lib
 from skypilot_tpu.utils import failpoints
@@ -49,6 +51,14 @@ MODELS = {
     '350m': llama.LlamaConfig.bench_350m,
     '1b': llama.LlamaConfig.bench_1b,
     '8b': llama.LlamaConfig.llama3_8b,
+    # Hybrid (Mamba-2 / experts / attention; models/nemotron_h.py):
+    # the CPU-test preset, and Nemotron-3-Nano-30B-A3B as one of two
+    # chips that share each layer by expert parallelism (its first 16
+    # blocks, 64 of 128 experts, half the vocabulary). Paged only; see
+    # docs/serving.md "Models" for the switches they refuse.
+    'nemotron-h-tiny': nemotron_h.NemotronHConfig.tiny,
+    'nemotron-3-nano-30b-a3b-ep2':
+        nemotron_h.NemotronHConfig.nano_30b_a3b_ep2,
 }
 
 
@@ -1205,6 +1215,12 @@ def main() -> None:
                 jax_env.device_summary())
 
     config = MODELS[args.model]()
+    # A model's refusals (models/interface.py) come before any weight
+    # is made: the reason, not a shape error from the wrong init path.
+    interface.check_engine(config, engine_lib.EngineConfig(
+        tp=args.tp, quantize=args.quantize, paged=args.paged,
+        prefix_cache=args.prefix_cache, kv_dtype=args.kv_dtype,
+        fused_prefill=args.fused_prefill, spec_k=args.spec_k))
     if world > 1 and args.tp == 1:
         # A multi-host replica exists to shard the model; default the
         # tp axis to the whole slice.
@@ -1220,7 +1236,7 @@ def main() -> None:
             # extraction + quantize below move it to the device
             # leaf-by-leaf.
             abstract = jax.eval_shape(
-                lambda: llama.init_params(config, jax.random.PRNGKey(0)))
+                lambda: interface.init_params(config, jax.random.PRNGKey(0)))
             try:
                 restored = mgr.restore_to_host(abstract)
             except Exception as first_err:  # noqa: BLE001 — train-state
@@ -1238,7 +1254,7 @@ def main() -> None:
             from skypilot_tpu.parallel import sharding as sharding_lib
             mesh = engine_lib.tp_mesh(args.tp)
             abstract = jax.eval_shape(
-                lambda: llama.init_params(config, jax.random.PRNGKey(0)))
+                lambda: interface.init_params(config, jax.random.PRNGKey(0)))
             shardings = sharding_lib.param_shardings(mesh, abstract)
             target = jax.tree_util.tree_map(
                 lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
@@ -1283,7 +1299,7 @@ def main() -> None:
     else:
         logger.warning('no --checkpoint: serving random weights (%s)',
                        args.model)
-        params = llama.init_params(config, jax.random.PRNGKey(0))
+        params = interface.init_params(config, jax.random.PRNGKey(0))
     tenant_weights = parse_tenant_weights(args.tenant_weights)
     t_weights = time.time()
     logger.info('weights ready in %.1fs', t_weights - boot_t0)

@@ -1,15 +1,28 @@
-"""Mixture-of-Experts transformer (Mixtral-style) with expert parallelism.
+"""Mixture-of-Experts transformer (Mixtral-style): a TRAINING forward
+that no normal path reaches.
+
+Neither ``train/run.py`` (its ``moe-*`` entries exit) nor the serving
+path runs this file. What is SERVED is another layer:
+``ops/moe_dropless.py`` under ``models/nemotron_h.py`` (dropless,
+sort / gather grouped products, told which experts the chip holds).
+This file's dispatch drops the tokens past ``capacity`` and builds
+``[T, E, C]`` tensors that grow with ``T**2``; a served answer may not
+depend on a capacity, so it is not the layer to serve with.
 
 Absent from the reference (SURVEY.md §2.8: EP delegated to user
-frameworks); built TPU-first here:
+frameworks); as built here:
 
 - **GShard-style fixed-capacity dispatch**: routing produces dense
   dispatch/combine tensors, and expert compute is batched einsums over
   ``[experts, capacity, dim]`` — static shapes, MXU-shaped, no gather
   loops.
-- **Expert parallelism is a sharding, not code**: expert-stacked weights
-  carry ``P('ep')`` on the expert axis; under jit the dispatch/combine
-  einsums lower to all-to-alls over the ``ep`` mesh axis automatically.
+- **Expert parallelism, here, is a sharding annotation**:
+  expert-stacked weights carry ``P('ep')`` on the expert axis, and under
+  jit the dispatch/combine einsums would lower to all-to-alls over the
+  ``ep`` mesh axis. No cell or chip run has shown it. The served layer
+  takes the other road: expert parallelism as the chip's share
+  (``experts_held`` / ``expert_offset``), code that knows which experts
+  it holds; experts over more than one chip are not built (ROADMAP X1).
 - Attention/norms/RoPE are shared with ``models/llama.py`` (same layer
   fn); only the MLP is replaced by the routed expert MLP.
 - Router aux losses: load-balancing (Switch-style) + router z-loss,
