@@ -25,17 +25,49 @@ Kernel design (per /opt/skills/guides/pallas_guide.md):
   (``PrefetchScalarGridSpec``): they land in SMEM before the pipeline
   starts, so the K/V BlockSpec ``index_map`` can translate (slot, page
   step) -> physical page id. The pages a slot touches are
-  non-contiguous in HBM; the pipeline gathers them page by page.
-- Grid = (slots, kv_heads, max_pages) — but a slot only pays DMA for
-  the pages it OWNS: for steps past the slot's last page the index_map
-  re-maps to the previous step's page, and Pallas skips the fetch when
-  consecutive steps map the same block (the revisiting-block rule the
-  pipeline already implements). The kernel body masks those steps out.
-  Decode bandwidth is therefore sum(ceil(len_i/page)) pages, the whole
-  point of paging.
+  non-contiguous in HBM; the pipeline gathers them page by page, every
+  KV head's rows of a page in one block.
+- A slot only pays DMA for the pages it OWNS: for steps past the
+  slot's last page the index_map re-maps to a page the in_spec already
+  holds, and Pallas skips the fetch when consecutive steps map the same
+  block (the revisiting-block rule the pipeline already implements).
+  The kernel body skips those steps. Bandwidth is therefore
+  sum(ceil(len_i/page)) pages, the whole point of paging.
 - Online softmax across the page axis (sequential innermost grid dim on
   TPU), fp32 accumulators in VMEM scratch that persist across the page
-  steps of one (slot, head) and reinitialize at page 0.
+  steps of one slot and reinitialize at the first.
+
+The **decode** and **verify** kernels work a page at a time: grid =
+(slots, max_pages), one page (all KV heads) a step, an unrolled loop
+over the heads, each with its own [group, page] score tile and its
+m / l / accumulator updated once a page, operands cast to float32.
+Their rows are few (group, or R x group), so a tile is small whatever
+its width; no cell times them (the cells decode through jax's library
+kernel, speculation is off), and they keep that body until one does.
+
+The **prefill** kernel (``_prefill_kernel``) is tiled for a chunk's
+many rows. Grid = (unit blocks, max_pages / fan): a step fetches `fan`
+pages (each its own in_spec, 512 key columns of them) and works them as
+ONE block: the pages stacked along the row axis into one [fan*page, hd]
+operand, so the score tile fills its lanes and the MXU sees a
+full-width operand; operands in the pages' dtype (bfloat16 x bfloat16
+-> float32); m / l / accumulator updated once a block, the statistics
+kept lane-replicated ([rows, 128], l as lane-wise partial sums that
+are added up at the end) so that no update is a one-lane register; the
+causal mask built only in blocks that reach past `offset` or hold a
+dead page; a step with no live page skipped whole. One masked body at
+the block's full width serves the short prompt too: a ladder of
+narrower bodies for 2 / 4 / 6 live pages read the same time at every
+chat shape (33-47 us a call either way on a v5e: a short call is its
+fixed costs, not its columns) and cost a third more compile. The rows
+of a tile are a UNIT (some of a KV head's group x the chunk's
+queries); the units are walked by a loop inside the step, not by a
+grid axis, because what a grid step costs is the pipeline's
+bookkeeping for each of its in_specs (about 0.05 us an in_spec and
+step on a v5e, fetched or skipped: 50 us a call at one head a step,
+6 us at all heads a step), and the units share it. Block, unit and
+resident-unit sizes come from the call's shapes under a VMEM budget
+(``_prefill_tiles``).
 
 Two entry points, one numerically-identical reference each:
 
@@ -357,13 +389,54 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
 # ---------------------------------------------------------------------------
 # Prefill-chunk kernel
 # ---------------------------------------------------------------------------
-def _prefill_kernel(table_ref, meta_ref, q_ref, *refs,
+_LANES = 128
+# Key columns a grid step fetches and works as ONE block: wide enough
+# that the once-a-block bookkeeping (m, l, the accumulator's rescale:
+# about as many register operations as a 128-column score tile) is a
+# small share of the block's element-wise work.
+_PREFILL_BLOCK_COLS = 512
+_PREFILL_MAX_FAN = 16      # in_specs (DMAs) a step carries per K and V
+# The float32 score tile [rows, cols] of one unit's block, which (with
+# the probabilities beside it) is what a block's arithmetic holds in
+# VMEM besides the resident blocks.
+_PREFILL_TILE_BYTES = 2 << 20
+# What the call may hold in VMEM (queries, output, accumulator and
+# statistics of the resident units, the fetched pages twice, one
+# block's tiles), and the limit it asks the compiler for.
+_PREFILL_VMEM_BUDGET = 36 << 20
+_PREFILL_VMEM_LIMIT = 48 << 20
+
+
+def _across(x: jnp.ndarray, n: int) -> jnp.ndarray:
+    """A per-row statistic [rows, 1 or _LANES] (lane-replicated) spread
+    over n columns: broadcasting does the one-lane flavor, the
+    replicated one repeats whole registers (no cross-lane work)."""
+    lanes = x.shape[1]
+    if lanes in (1, n):
+        return x
+    return jnp.tile(x, (1, n // lanes))
+
+
+def _prefill_kernel(schedule_ref, meta_ref, q_ref, *refs,
                     page_size: int, sm_scale: float, n_groups: int,
-                    chunk: int, fan: int, quantized: bool):
-    """One grid step processes `fan` pages (each its own scalar-
-    prefetched in_spec/DMA): the fixed per-grid-step cost — not the
-    bytes — dominates a one-page-per-step kernel, so fanning pages into
-    a step amortizes it `fan`-fold."""
+                    chunk: int, members: int, units_per_head: int,
+                    fan: int, quantized: bool):
+    """One grid step works the `fan` pages it fetched (each its own
+    scalar-prefetched in_spec/DMA, every KV head's rows in one block)
+    as ONE block of keys: the pages stacked along the row axis into a
+    [fan*page, hd] operand, one score tile, and m / l / the accumulator
+    updated once a block. It does so for every resident UNIT in turn
+    (a loop, not a grid axis: what a grid step costs is its in_specs'
+    bookkeeping, which all units then share): a unit is `members`
+    query heads of one KV head's group x the chunk's queries,
+    member-major (row r is query r % chunk).
+
+    Which body a step runs follows its live pages: a full block at or
+    under `offset` is visible to every row and takes the body with no
+    mask; the blocks that reach into the chunk's own positions, and
+    the slot's last, partly dead one, take the masked body (a dead
+    page's columns lie past every real row's position, so causality
+    masks them too); a step with no live page does nothing."""
     k_refs = refs[:fan]
     v_refs = refs[fan:2 * fan]
     refs = refs[2 * fan:]
@@ -373,14 +446,21 @@ def _prefill_kernel(table_ref, meta_ref, q_ref, *refs,
         refs = refs[2 * fan:]
     else:
         ks_refs = vs_refs = None
-    o_ref = refs[0]
-    acc_ref, m_ref, l_ref = refs[1:]
+    o_ref, acc_ref, m_ref, l_ref = refs
     g = pl.program_id(1)
-    del table_ref
+    del schedule_ref  # consumed by the index_maps
     offset = meta_ref[0]
-    true_len = meta_ref[1]
-    total = offset + true_len                   # slot frontier
-    n_pages = pl.cdiv(total, page_size)
+    live = meta_ref[1] - g * fan    # live pages, this step's first onwards
+    base = g * (fan * page_size)    # this step's first key position
+    units, _, hd = acc_ref.shape
+    stat_lanes = m_ref.shape[2]
+    first_unit = pl.program_id(0) * units
+
+    def _for_units(body):
+        def step(u, carry):
+            body(u)
+            return carry
+        jax.lax.fori_loop(0, units, step, 0)
 
     @pl.when(g == 0)
     def _init():
@@ -388,50 +468,103 @@ def _prefill_kernel(table_ref, meta_ref, q_ref, *refs,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # q: [chunk*group, hd] (queries x group heads flattened so the MXU
-    # sees one [C*g, page] matmul per page).
-    q = q_ref[0].astype(jnp.float32) * sm_scale
-
-    def _accumulate_page(f: int):
-        p = g * fan + f
-
-        @pl.when(p < n_pages)
-        def _do():
-            k = k_refs[f][0, 0].astype(jnp.float32)   # [page, hd]
-            v = v_refs[f][0, 0].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)   # [C*g, page]
-            if quantized:
-                s = s * ks_refs[f][0, 0]
-            # Causality in GLOBAL positions: row r is query
-            # offset + r//g; column c is cached position p*page + c.
+    def _block(masked: bool, u):
+        cols = fan * page_size
+        head = (first_unit + u) // units_per_head
+        # Operands go to the MXU as stored (q arrives in their dtype);
+        # int8 pages convert exactly.
+        q = q_ref[u]                                    # [rows, hd]
+        k = jnp.concatenate([r[head, 0] for r in k_refs]).astype(q.dtype)
+        v = jnp.concatenate([r[head, 0] for r in v_refs]).astype(q.dtype)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)         # [rows, cols]
+        if quantized:
+            s = s * jnp.concatenate(
+                [r[head, 0] for r in ks_refs], axis=1)      # [1, cols]
+        if masked:
+            # Causality in GLOBAL positions, built for the chunk's
+            # queries and shared by the unit's members.
             qpos = offset + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0) // (s.shape[0] // chunk)
-            kpos = p * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(kpos <= qpos, s, _NEG_INF)
-            m_prev = m_ref[...]
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(s, axis=-1, keepdims=True))
-            pr = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[...] = l_ref[...] * alpha + jnp.sum(
-                pr, axis=-1, keepdims=True)
-            if quantized:
-                pr = pr * vs_refs[f][0, 0]
-            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                pr, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[...] = m_new
+                jnp.int32, (chunk, cols), 0)
+            kpos = base + jax.lax.broadcasted_iota(
+                jnp.int32, (chunk, cols), 1)
+            visible = kpos <= qpos
+            s = jnp.concatenate([
+                jnp.where(visible, s[i * chunk:(i + 1) * chunk], _NEG_INF)
+                for i in range(members)])
+        # m is kept in raw score units and the softmax scale rides the
+        # exponent's argument: the products stay the stored values'.
+        m_prev = m_ref[u]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp((m_prev - m_new) * sm_scale)
+        pr = jnp.exp((s - _across(m_new, cols)) * sm_scale)
+        if stat_lanes == 1:
+            l_blk = jnp.sum(pr, axis=-1, keepdims=True)
+        else:   # lane-wise partial sums; the lanes are summed at the end
+            l_blk = sum(pr[:, j:j + stat_lanes]
+                        for j in range(0, cols, stat_lanes))
+        l_ref[u] = l_ref[u] * alpha + l_blk
+        if quantized:
+            pr = pr * jnp.concatenate(
+                [r[head, 0] for r in vs_refs], axis=1)
+        acc_ref[u] = acc_ref[u] * _across(alpha, hd) + jnp.dot(
+            pr.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_ref[u] = m_new
 
-    for f in range(fan):
-        _accumulate_page(f)
+    open_block = jnp.logical_and(
+        live >= fan, base + fan * page_size - 1 <= offset)
+    pl.when(open_block)(functools.partial(
+        _for_units, functools.partial(_block, False)))
+    pl.when(jnp.logical_and(live > 0, jnp.logical_not(open_block)))(
+        functools.partial(_for_units, functools.partial(_block, True)))
 
     @pl.when(g == n_groups - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        def unit(u):
+            l = l_ref[u]
+            if stat_lanes > 1:
+                l = jnp.sum(l, axis=-1, keepdims=True)
+            o_ref[u] = (acc_ref[u] / jnp.maximum(l, 1e-30)).astype(
+                o_ref.dtype)
+        _for_units(unit)
+
+
+def _prefill_tiles(chunk: int, hkv: int, group: int, hd: int,
+                   page_size: int, max_pages: int, itemsize: int):
+    """``(fan, members, resident, stat_lanes)`` from what the
+    call can see of its shapes. fan: pages a grid step fetches and
+    works as one block (`_PREFILL_BLOCK_COLS` columns of them).
+    members: the group's query heads in a unit, the most whose score
+    tile [members*chunk, fan*page] stays under `_PREFILL_TILE_BYTES`;
+    where one member's rows alone pass it the block narrows instead.
+    resident: the units a grid row holds in VMEM at once, the most
+    under `_PREFILL_VMEM_BUDGET` (all of them at the served shapes;
+    the rest ride a grid axis and fetch the pages again). stat_lanes:
+    m / l one lane wide, or lane-replicated where the block's columns
+    and hd fill whole registers."""
+    tile = _PREFILL_TILE_BYTES // 4
+    fan = max(1, min(_PREFILL_MAX_FAN, max_pages,
+                     min(_PREFILL_BLOCK_COLS, max(tile // chunk, _LANES))
+                     // page_size))
+    cols = fan * page_size
+    members = max(m for m in range(1, group + 1)
+                  if group % m == 0
+                  and (m == 1 or m * chunk * cols <= tile))
+    rows = members * chunk
+    units = hkv * (group // members)
+    # A unit's queries and output (each double-buffered), float32
+    # accumulator and the two statistics (a lane-padded register row
+    # each, whatever stat_lanes); the pages twice; one block's score
+    # and probability tiles.
+    unit_bytes = rows * (hd * (4 * itemsize + 4) + 2 * _LANES * 4)
+    fixed = (4 * fan * hkv * page_size * hd * itemsize
+             + rows * cols * (8 + itemsize))
+    resident = max(
+        [n for n in range(1, units + 1) if units % n == 0
+         and fixed + n * unit_bytes <= _PREFILL_VMEM_BUDGET] or [1])
+    full_lanes = hd % _LANES == 0 and cols % _LANES == 0
+    return fan, members, resident, _LANES if full_lanes else 1
 
 
 def paged_prefill_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -441,7 +574,6 @@ def paged_prefill_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                             true_len: jnp.ndarray, *,
                             sm_scale: Optional[float] = None,
                             interpret: Optional[bool] = None,
-                            pages_per_step: int = 8,
                             k_scales: Optional[jnp.ndarray] = None,
                             v_scales: Optional[jnp.ndarray] = None
                             ) -> jnp.ndarray:
@@ -451,75 +583,98 @@ def paged_prefill_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     chunk's K/V already written into the pages); table_row: [maxp]
     int32; offset/true_len: scalars. Tokens beyond true_len are pad —
     their rows compute garbage the caller discards. Returns
-    [C, hkv, group, hd] fp32, O(C * len) bandwidth via the
-    skip-dead-pages index_maps, with `pages_per_step` pages fanned into
-    each grid step to amortize the fixed step cost.
+    [C, hkv, group, hd] in q's dtype (float32 statistics and
+    accumulator inside), O(C * len) bandwidth via the skip-dead-pages
+    index_maps; the pages a grid step fetches are worked as one block
+    of keys (`_prefill_kernel`), sized from the call's shapes
+    (`_prefill_tiles`). Both products take their
+    operands in ``promote_types(q.dtype, pages.dtype)`` — bfloat16 as
+    served, float32 throughout on float32 pages — and accumulate in
+    float32; the probabilities are cast to that dtype for the second.
     """
     C, hkv, group, hd = q.shape
     page_size = k_pages.shape[2]
     max_pages = table_row.shape[0]
-    fan = max(1, min(pages_per_step, max_pages))
+    mxu_dtype = jnp.promote_types(q.dtype, k_pages.dtype)
+    fan, members, resident, stat_lanes = _prefill_tiles(
+        C, hkv, group, hd, page_size, max_pages,
+        jnp.dtype(mxu_dtype).itemsize)
     n_groups = -(-max_pages // fan)
+    units_per_head = group // members
+    units = hkv * units_per_head
+    rows = members * C
     if sm_scale is None:
         sm_scale = hd ** -0.5
     interpret = _interpret_default(interpret)
-    # [hkv, C*group, hd]: queries x group flattened per KV head, group
-    # fastest so row r maps to query r // group (contiguous rows share
-    # a query position -> the causal iota stays a cheap div).
-    qf = q.transpose(1, 0, 2, 3).reshape(hkv, C * group, hd)
-    # meta in SMEM: [offset, true_len].
-    meta = jnp.stack([jnp.asarray(offset, jnp.int32),
-                      jnp.asarray(true_len, jnp.int32)])
+    # [units, members*C, hd]: a unit's rows member-major, so the causal
+    # mask is the chunk's own [C, cols] one, repeated.
+    qf = q.astype(mxu_dtype).transpose(1, 2, 0, 3).reshape(units, rows, hd)
+    offset = jnp.asarray(offset, jnp.int32)
+    n_pages = (offset + jnp.asarray(true_len, jnp.int32)
+               + page_size - 1) // page_size
+    # SMEM: [offset, live pages]; and the page every in_spec addresses
+    # at every step, looked up here once so that an index_map is one
+    # SMEM load. A dead page keeps its in_spec on the page it fetched
+    # last (its own last live one; the slot's last page where it never
+    # had one): consecutive steps then address the same block and the
+    # pipeline skips the fetch.
+    meta = jnp.stack([offset, n_pages])
+    last = jnp.maximum(n_pages - 1, 0)
+    j = jnp.arange(n_groups * fan, dtype=jnp.int32)
+    f = j % fan
+    own = f + fan * ((last - f) // fan)
+    j = jnp.where(j <= last, j, jnp.where(f <= last, own, last))
+    schedule = table_row[j]
 
     quantized = k_scales is not None
 
     def _page_index(f):
-        def index(h, g, table, meta_):
-            total = meta_[0] + meta_[1]
-            n_pages = jax.lax.div(total + page_size - 1, page_size)
-            j = jnp.minimum(g * fan + f, jnp.maximum(n_pages - 1, 0))
-            return (h, table[j], 0, 0)
-        return index
+        return lambda b, g, schedule_, meta_: (
+            0, schedule_[g * fan + f], 0, 0)
 
-    page_spec = [pl.BlockSpec((1, 1, page_size, hd), _page_index(f))
+    def _units_index(b, g, *_):
+        return (b, 0, 0)
+
+    page_spec = [pl.BlockSpec((hkv, 1, page_size, hd), _page_index(f))
                  for f in range(fan)]
     in_specs = [
-        pl.BlockSpec((1, C * group, hd),
-                     lambda h, g, *_: (h, 0, 0)),
+        pl.BlockSpec((resident, rows, hd), _units_index),
         *page_spec,          # k pages, fan of them
         *page_spec,          # v pages
     ]
     operands = [qf, *([k_pages] * fan), *([v_pages] * fan)]
     if quantized:
-        scale_spec = [pl.BlockSpec((1, 1, 1, page_size), _page_index(f))
+        scale_spec = [pl.BlockSpec((hkv, 1, 1, page_size), _page_index(f))
                       for f in range(fan)]
         in_specs += [*scale_spec, *scale_spec]
         operands += [*([_scale_rows(k_scales)] * fan),
                      *([_scale_rows(v_scales)] * fan)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(hkv, n_groups),
+        grid=(units // resident, n_groups),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, C * group, hd),
-                               lambda h, g, *_: (h, 0, 0)),
+        out_specs=pl.BlockSpec((resident, rows, hd), _units_index),
         scratch_shapes=[
-            pltpu.VMEM((C * group, hd), jnp.float32),
-            pltpu.VMEM((C * group, 1), jnp.float32),
-            pltpu.VMEM((C * group, 1), jnp.float32),
+            pltpu.VMEM((resident, rows, hd), jnp.float32),
+            pltpu.VMEM((resident, rows, stat_lanes), jnp.float32),
+            pltpu.VMEM((resident, rows, stat_lanes), jnp.float32),
         ],
     )
     kernel = functools.partial(_prefill_kernel, page_size=page_size,
                                sm_scale=sm_scale, n_groups=n_groups,
-                               chunk=C, fan=fan, quantized=quantized)
+                               chunk=C, members=members,
+                               units_per_head=units_per_head, fan=fan,
+                               quantized=quantized)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((hkv, C * group, hd),
-                                       jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((units, rows, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_PREFILL_VMEM_LIMIT),
         interpret=interpret,
         name='paged_prefill_attention',
-    )(table_row, meta, *operands)
-    return out.reshape(hkv, C, group, hd).transpose(1, 0, 2, 3)
+    )(schedule, meta, *operands)
+    return out.reshape(hkv, group, C, hd).transpose(2, 0, 1, 3)
 
 
 # ---------------------------------------------------------------------------
