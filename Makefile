@@ -6,7 +6,7 @@
 
 PYTHON ?= python
 
-.PHONY: lint lint-full lint-json test-analysis bench-ttft profile-smoke sim-smoke sim-crash-sweep slo-smoke cost-smoke integrity-smoke disagg-smoke golden-refresh incident-smoke simulate-smoke
+.PHONY: lint lint-full lint-json test-analysis profile-smoke sim-smoke sim-crash-sweep slo-smoke cost-smoke integrity-smoke disagg-smoke golden-refresh incident-smoke simulate-smoke
 
 lint:
 	$(PYTHON) -m skypilot_tpu.client.cli lint --changed
@@ -19,16 +19,6 @@ lint-json:
 
 test-analysis:
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/unit_tests/test_analysis.py -q
-
-# The fused-mixed-step + int8-KV sweep (docs/serving.md "Fused mixed
-# steps"): long-prompt aggressor mid-decode-batch, victim ITL fused vs
-# unfused, plus the kv-dtype residency axis. Override e.g.
-# `make bench-ttft TTFT_ARGS='--model 1b --slots 16'`.
-TTFT_OUT ?= auto
-TTFT_ARGS ?= --model tiny --slots 8 --concurrency 4 8
-
-bench-ttft:
-	$(PYTHON) bench_ttft.py --sweep chunked $(TTFT_ARGS) --output $(TTFT_OUT)
 
 # Flight-recorder smoke (docs/observability.md "Flight recorder"): a
 # tiny in-process workload with the recorder on, a forced anomaly

@@ -15,8 +15,8 @@ Two registries drive operability and MUST NOT drift from their docs:
    ``PrefixCache.stats``, the infer server's ``h_metrics`` additions,
    the LB's ``lb_metrics``) must appear in docs/observability.md's
    "Serving metrics" catalog tables, and vice versa. Dashboards and
-   the TTFT bench are built on these names; a renamed key is a
-   silently-flatlined graph.
+   the benchmark's readers are built on these names; a renamed key is
+   a silently-flatlined graph.
 
 Doc format contract: catalog entries are markdown table rows whose
 first cell is the backticked name —  ``| `site.name` | ... |`` —

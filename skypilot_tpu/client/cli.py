@@ -407,10 +407,6 @@ def profile_cmd(target: Optional[str], perfetto_path: Optional[str],
 
     if target and target.startswith(('http://', 'https://')):
         snap = _fetch_json(target.rstrip('/') + '/debug/stepline')
-        if not snap.get('enabled', True):
-            click.echo('flight recorder disabled on this replica '
-                       '(--no-stepline).')
-            return
         # Tolerate a replica on an older build whose records miss a
         # newer field — a version skew must degrade to zeros, not a
         # KeyError traceback.

@@ -181,10 +181,8 @@ class EngineConfig:
     # (stage wall-time shares, batch/chunk sizes, speculation accepts,
     # page pressure, per-tenant queue depth) plus per-request timeline
     # events, surfaced at GET /debug/stepline and snapshotted into the
-    # span store on anomalies. Pure observation: greedy outputs are
-    # BIT-IDENTICAL recorder on vs off (it reads clocks and counters,
-    # never scheduling state the step loop acts on).
-    stepline: bool = True
+    # span store on anomalies. Pure observation: it reads clocks and
+    # counters, never scheduling state the step loop acts on.
     # Ring capacity in step records (None -> SKY_TPU_STEPLINE_CAP or
     # 1024); the request-event ring holds 4x as many.
     stepline_cap: Optional[int] = None
@@ -249,10 +247,8 @@ class Request:
     # Per-request speculation opt-out (body {"spec": false}): the
     # request is never drafted for — it emits one token per step (it
     # may still co-ride another slot's verify dispatch as a
-    # draft_len=0 lane, which is compute-identical to decode for it) —
-    # the honest spec-off baseline lane of bench_ttft's speculative
-    # sweep (outputs are bit-identical either way; only step count
-    # differs).
+    # draft_len=0 lane, which is compute-identical to decode for it).
+    # Outputs are bit-identical either way; only step count differs.
     spec: bool = True
     # Verify-step accounting (engine thread only): steps this request
     # rode a verify dispatch, and tokens those steps emitted for it —
@@ -737,12 +733,10 @@ class InferenceEngine:
         self._queue_waits: collections.deque = collections.deque(
             maxlen=1024)
         # ---- flight recorder (observability/stepline.py) ----------------
-        # _sl_on is an immutable config flag (like wallclock_cancel's
-        # one-way discipline): read lock-free on hot paths; the rings
-        # behind it are the lock-guarded state.
-        self._sl_on = bool(self.ecfg.stepline)
-        self._stepline = (stepline_lib.StepRecorder(
-            self.ecfg.stepline_cap) if self._sl_on else None)
+        # Always on: every per-layer metric of the benchmark and
+        # /debug/stepline read it. The rings are lock-guarded state.
+        self._stepline = stepline_lib.StepRecorder(
+            self.ecfg.stepline_cap)
         self._pending_dumps: List[tuple] = []
         # Engine-thread stage timer, reset at each step start (never
         # read cross-thread): `with self._stage('dispatch'):` adds the
@@ -750,10 +744,8 @@ class InferenceEngine:
         # profiler trace. dispatch = device program launches, drain =
         # consume bookkeeping, readback = blocked on the pair's
         # device→host copy, sched = the step's admission section.
-        self._sl_clock = (stepline_lib.StageClock() if self._sl_on
-                          else None)
-        self._stage = (self._sl_clock.stage if self._sl_on
-                       else stepline_lib.no_stage)
+        self._sl_clock = stepline_lib.StageClock()
+        self._stage = self._sl_clock.stage
         self._sl_batch = 0
 
         # ---- compiled programs ------------------------------------------
@@ -1049,10 +1041,10 @@ class InferenceEngine:
         step loop. ``tenant`` is the fair-queueing identity
         (X-SkyTpu-Tenant). ``spec=False`` opts this request out of
         speculative drafting (outputs are identical; only step count
-        changes — the bench's spec-off baseline lane). ``recv_t`` /
-        ``lb_recv_t`` are the wall-clock moments the server's handler
-        and the serve LB received the request: observed only, they
-        ride the flight recorder's ``submit`` event. Raises
+        changes). ``recv_t`` / ``lb_recv_t`` are the wall-clock
+        moments the server's handler and the serve LB received the
+        request: observed only, they ride the flight recorder's
+        ``submit`` event. Raises
         :class:`AdmissionError` when the scheduler's (global or
         per-tenant) queue bound is hit."""
         if not prompt_tokens:
@@ -1131,17 +1123,16 @@ class InferenceEngine:
                         'prompt_tokens': len(req.prompt_tokens)})
                     raise
                 self._sched.enqueue(req)
-                if self._sl_on:
-                    self._stepline.note_event(
-                        req.request_id, req.tenant, 'submit',
-                        req.submitted_at,
-                        prompt_tokens=len(req.prompt_tokens),
-                        **({'resumed_from': req.resumed_from}
-                           if req.resumed_from else {}),
-                        **({'recv_t': recv_t}
-                           if recv_t is not None else {}),
-                        **({'lb_recv_t': lb_recv_t}
-                           if lb_recv_t is not None else {}))
+                self._stepline.note_event(
+                    req.request_id, req.tenant, 'submit',
+                    req.submitted_at,
+                    prompt_tokens=len(req.prompt_tokens),
+                    **({'resumed_from': req.resumed_from}
+                       if req.resumed_from else {}),
+                    **({'recv_t': recv_t}
+                       if recv_t is not None else {}),
+                    **({'lb_recv_t': lb_recv_t}
+                       if lb_recv_t is not None else {}))
         finally:
             # Outside the lock: the dump handoff takes the writer's
             # own condition, which must never nest under the engine
@@ -1480,11 +1471,10 @@ class InferenceEngine:
             with self._lock:
                 self._queue_waits.append(wait)
                 self._sched.note_queue_wait(req, wait)
-                if self._sl_on:
-                    self._stepline.note_event(
-                        req.request_id, req.tenant, 'first_dispatch',
-                        req.first_dispatch_at,
-                        queue_wait_s=round(wait, 6))
+                self._stepline.note_event(
+                    req.request_id, req.tenant, 'first_dispatch',
+                    req.first_dispatch_at,
+                    queue_wait_s=round(wait, 6))
 
     def _dispatch_chunk_plan(self, plan: _ChunkPlan) -> bool:
         """Standalone dispatch of a prepared chunk via the prefill
@@ -1516,7 +1506,7 @@ class InferenceEngine:
         the request's prompt. From here to ``first_token`` lie that
         step's device time and the dispatch-ahead pipeline's
         stale-by-one consume."""
-        if self._sl_on and plan.off + plan.tl >= plan.total:
+        if plan.off + plan.tl >= plan.total:
             self._stepline.note_event(
                 plan.req.request_id, plan.req.tenant,
                 'prefill_dispatched', time.time(), off=plan.off)
@@ -1581,18 +1571,17 @@ class InferenceEngine:
                     req, req.finished_at - req.submitted_at)
                 self._sl_first_token(
                     req, req.finished_at - req.submitted_at)
-            if self._sl_on:
-                self._stepline.note_event(
-                    req.request_id, req.tenant, 'done',
-                    req.finished_at, finish_reason=req.finish_reason,
-                    tokens=len(req.output_tokens))
-                if req.finish_reason == 'cache_full':
-                    # Anomaly trigger: the request was cut by cache
-                    # exhaustion — page pressure in the retained steps
-                    # explains why.
-                    self._note_anomaly('cache_full', {
-                        'request_id': req.request_id,
-                        'tenant': req.tenant, 'slot': slot})
+            self._stepline.note_event(
+                req.request_id, req.tenant, 'done',
+                req.finished_at, finish_reason=req.finish_reason,
+                tokens=len(req.output_tokens))
+            if req.finish_reason == 'cache_full':
+                # Anomaly trigger: the request was cut by cache
+                # exhaustion — page pressure in the retained steps
+                # explains why.
+                self._note_anomaly('cache_full', {
+                    'request_id': req.request_id,
+                    'tenant': req.tenant, 'slot': slot})
             self._slots[slot] = None
             # Release BEFORE zeroing _slot_len: donation covers exactly
             # the positions whose K/V the pages hold, which is what
@@ -1607,10 +1596,9 @@ class InferenceEngine:
         expired while waiting). Under the engine lock."""
         req.finish_reason = reason
         req.finished_at = time.time()
-        if self._sl_on:
-            self._stepline.note_event(
-                req.request_id, req.tenant, 'done', req.finished_at,
-                finish_reason=reason, tokens=len(req.output_tokens))
+        self._stepline.note_event(
+            req.request_id, req.tenant, 'done', req.finished_at,
+            finish_reason=reason, tokens=len(req.output_tokens))
         req._notify()
 
     def _finish_early(self, slot: int, req: Request, reason: str) -> None:
@@ -1625,11 +1613,10 @@ class InferenceEngine:
             prefilled_to = self._prefilling.pop(slot, None)
             req.finish_reason = reason
             req.finished_at = time.time()
-            if self._sl_on:
-                self._stepline.note_event(
-                    req.request_id, req.tenant, 'done',
-                    req.finished_at, finish_reason=reason,
-                    tokens=len(req.output_tokens))
+            self._stepline.note_event(
+                req.request_id, req.tenant, 'done',
+                req.finished_at, finish_reason=reason,
+                tokens=len(req.output_tokens))
             self._slots[slot] = None
             self._matched.discard(slot)
             self._release_slot_pages(slot, req, prefilled_to)
@@ -1821,15 +1808,12 @@ class InferenceEngine:
         one token for every fully-prefilled slot. Returns the number of
         slots worked on.
 
-        With the flight recorder on (the default), the step body runs
-        between a counter pre-snapshot and a ring append: the record
-        is derived purely from clocks and counter deltas, so the
-        recorded step is bit-identical to the unrecorded one. The
-        step and its stages are also ``jax.profiler`` annotations
+        The step body runs between a counter pre-snapshot and a ring
+        append of the flight recorder: the record is derived purely
+        from clocks and counter deltas. The step and its stages are
+        also ``jax.profiler`` annotations
         (``engine.step`` carries the record's index as ``step_num``):
         a profiler trace shows them beside the device's operations."""
-        if not self._sl_on:
-            return self._step_inner()
         t0 = time.perf_counter()
         t_wall = time.time()
         self._sl_batch = 0
@@ -1863,7 +1847,7 @@ class InferenceEngine:
                     self._slots[slot] = req   # reserve before releasing
                     self._prefilling[slot] = 0
                     self._matched.discard(slot)
-                    if self._sl_on and req.first_dispatch_at is not None:
+                    if req.first_dispatch_at is not None:
                         # A request re-entering a slot with a dispatch
                         # already stamped is a preemption resume — the
                         # timeline shows the gap it paid.
@@ -2528,8 +2512,8 @@ class InferenceEngine:
 
     def set_scheduler(self, name: str,
                       tenant_weights=None) -> None:
-        """Swap the scheduling policy at runtime (a bench/ops knob —
-        the same engine, compiled programs and KV state serve on).
+        """Swap the scheduling policy at runtime (an ops knob — the
+        same engine, compiled programs and KV state serve on).
         Queued requests migrate in the OLD policy's service order;
         per-tenant windows/counters restart with the new policy."""
         cfg = sched_lib.SchedulerConfig(
@@ -2566,8 +2550,6 @@ class InferenceEngine:
                         ttft: float) -> None:
         """Timeline event + the TTFT-SLO anomaly trigger, at the one
         moment TTFT becomes known."""
-        if not self._sl_on:
-            return
         self._stepline.note_event(
             req.request_id, req.tenant, 'first_token',
             req.first_token_at, ttft_s=round(ttft, 6))
@@ -2587,8 +2569,6 @@ class InferenceEngine:
         profile` and the span dumps see exactly when the replica
         became serviceable relative to its first requests. Request id
         -1 keys the pseudo-timeline (real ids start at 1)."""
-        if not self._sl_on:
-            return
         with self._lock:
             self._stepline.note_event(-1, '_lifecycle', event,
                                       t if t is not None else time.time(),
@@ -2599,8 +2579,6 @@ class InferenceEngine:
         ``first_flush``: its first token line has left the handler)
         onto the request's timeline, now. One brief lock take a
         request, as ``submit`` has; never on the per-token path."""
-        if not self._sl_on:
-            return
         with self._lock:
             self._stepline.note_event(req.request_id, req.tenant,
                                       event, time.time())
@@ -2612,8 +2590,6 @@ class InferenceEngine:
         the stepline writer thread strictly AFTER the engine lock is
         released (`_flush_stepline_dumps`) — nothing blocks, and the
         engine lock never nests another lock."""
-        if not self._sl_on:
-            return
         now = time.time()
         detail = dict(detail, t=now,
                       step_idx=self._stepline.steps.total)
@@ -2630,8 +2606,6 @@ class InferenceEngine:
         OUTSIDE the engine lock (step()/submit() tails): the ring
         snapshot is copied under the lock; the enqueue — which takes
         the writer's own condition — runs strictly after release."""
-        if not self._sl_on:
-            return
         with self._lock:
             if not self._pending_dumps:
                 return
@@ -2711,8 +2685,6 @@ class InferenceEngine:
         """Locked copy of the flight-recorder rings — the
         ``GET /debug/stepline`` payload (the ``ttft_window`` snapshot
         contract: HTTP readers never touch the live rings)."""
-        if not self._sl_on:
-            return {'enabled': False, 'steps': [], 'events': []}
         with self._lock:
             raw = self._stepline.raw()   # O(n) pointer copy only
         snap = stepline_lib.render_snapshot(raw)
@@ -2721,11 +2693,8 @@ class InferenceEngine:
         return snap
 
     def stepline_summary(self) -> Dict[str, Any]:
-        """Aggregate stage breakdown over the retained window (the
-        bench's recorder-derived step-time decomposition). The
+        """Aggregate stage breakdown over the retained window. The
         summarize math runs OUTSIDE the lock on a snapshot copy."""
-        if not self._sl_on:
-            return {'enabled': False}
         with self._lock:
             recs = self._stepline.steps.snapshot()
         out = stepline_lib.summarize(recs)
@@ -2806,10 +2775,8 @@ class InferenceEngine:
                 tokens_in_flight=sum(self._inflight_tok),
                 pages_free=(self.allocator.free_pages
                             if self.allocator is not None else 0),
-                stepline_steps=(self._stepline.steps.total
-                                if self._sl_on else 0),
-                stepline_dumps=(self._stepline.dumps
-                                if self._sl_on else 0),
+                stepline_steps=self._stepline.steps.total,
+                stepline_dumps=self._stepline.dumps,
                 sdc_events=self._sdc_events,
                 integrity_suspect=self._integrity_suspect,
                 kv_transfers=self._kv_transfers,
@@ -3117,7 +3084,7 @@ class EnginePool:
         interleave on the shared wall clock)."""
         tiers = [e.stepline_snapshot() for e in self.engines]
         return {
-            'enabled': any(t.get('enabled') for t in tiers),
+            'enabled': True,
             'dumps': sum(t.get('dumps', 0) for t in tiers),
             'steps_total': sum(t.get('steps_total', 0)
                                for t in tiers),
@@ -3132,12 +3099,9 @@ class EnginePool:
 
     def stepline_summary(self) -> Dict[str, Any]:
         tiers = [e.stepline_summary() for e in self.engines]
-        on = [t for t in tiers if t.get('enabled')]
-        if not on:
-            return {'enabled': False}
-        if len(on) == 1:
-            return on[0]
-        return {'enabled': True, 'tiers': on}
+        if len(tiers) == 1:
+            return tiers[0]
+        return {'enabled': True, 'tiers': tiers}
 
     def integrity_suspect(self) -> bool:
         return any(e.integrity_suspect() for e in self.engines)

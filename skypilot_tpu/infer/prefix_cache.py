@@ -334,9 +334,9 @@ class PrefixCache:
             'prefix_tokens_saved': self.tokens_saved,
             'prefix_cached_pages': self.cached_pages,
             'prefix_evictions': self.evictions,
-            # Raw counters so consumers (bench_ttft's shared-prefix
-            # sweep) can compute WINDOWED hit rates from deltas — the
-            # rate above is cumulative since engine start.
+            # Raw counters so consumers can compute WINDOWED hit
+            # rates from deltas — the rate above is cumulative since
+            # engine start.
             'prefix_hits': self.hits,
             'prefix_misses': self.misses,
             # Fleet-index advertisement size (<= index_cap; lags

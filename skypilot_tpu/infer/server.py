@@ -529,8 +529,8 @@ class InferenceServer:
                 since_gen)
         # `?format=prometheus` wraps the same gauges in text
         # exposition (docs/observability.md "Prometheus exposition");
-        # JSON stays the default — the LB sync tick and the bench
-        # parse it.
+        # JSON stays the default — the LB sync tick and the
+        # benchmark's counters parse it.
         if req.query.get('format') == 'prometheus':
             return web.Response(text=prom_lib.render_replica(m),
                                 content_type='text/plain',
@@ -835,9 +835,8 @@ class InferenceServer:
                         deadline=deadline,
                         tenant=tenant,
                         # Per-request speculation opt-out ("spec":
-                        # false) — the spec-off baseline lane of
-                        # bench_ttft --sweep speculative; outputs are
-                        # bit-identical either way.
+                        # false): one token a step for this request;
+                        # outputs are bit-identical either way.
                         spec=bool(body.get('spec', True)),
                         recv_t=recv_t,
                         lb_recv_t=_header_time(request.headers.get(
@@ -922,7 +921,7 @@ class InferenceServer:
                              'finish_reason': req.finish_reason,
                              'ttft_s': req.ttft,
                              # TTFT's scheduling share (submit → first
-                             # chunk dispatch): lets the bench
+                             # chunk dispatch): lets a client
                              # attribute queueing apart from prefill.
                              'queue_wait_s': req.queue_wait,
                              # Prompt tokens served from the shared-
@@ -1120,19 +1119,15 @@ def main() -> None:
     parser.add_argument('--spec-ngram', type=int, default=3,
                         help='Longest trailing n-gram the drafter '
                              'matches (falls back to shorter grams).')
-    parser.add_argument('--no-stepline', action='store_true',
-                        help='Disable the engine flight recorder '
+    parser.add_argument('--stepline-cap', type=int, default=None,
+                        help='Ring capacity, in step records, of the '
+                             'engine flight recorder '
                              '(docs/observability.md "Flight '
-                             'recorder"). On by default: a fixed-size '
-                             'ring of per-step records + request '
+                             'recorder": per-step records + request '
                              'timelines at GET /debug/stepline, '
                              'snapshotted into the span store on '
-                             'anomalies (TTFT-SLO breach, preemption, '
-                             'cache_full, admission shed).')
-    parser.add_argument('--stepline-cap', type=int, default=None,
-                        help='Flight-recorder ring capacity in step '
-                             'records (default: SKY_TPU_STEPLINE_CAP '
-                             'or 1024).')
+                             'anomalies). Default: '
+                             'SKY_TPU_STEPLINE_CAP or 1024.')
     parser.add_argument('--ttft-slo-s', type=float, default=None,
                         help='TTFT SLO in seconds: a first token '
                              'slower than this triggers a flight-'
@@ -1319,7 +1314,6 @@ def main() -> None:
             max_queue_tokens=args.max_queue_tokens,
             scheduler=args.scheduler,
             tenant_weights=tenant_weights,
-            stepline=not args.no_stepline,
             stepline_cap=args.stepline_cap,
             ttft_slo_s=args.ttft_slo_s,
             sdc_sentinel=not args.no_sdc_sentinel))
@@ -1347,7 +1341,6 @@ def main() -> None:
                 max_queue_tokens=args.max_queue_tokens,
                 scheduler=args.scheduler,
                 tenant_weights=tenant_weights,
-                stepline=not args.no_stepline,
                 stepline_cap=args.stepline_cap,
                 ttft_slo_s=args.ttft_slo_s,
                 sdc_sentinel=not args.no_sdc_sentinel),

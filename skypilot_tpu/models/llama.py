@@ -54,7 +54,7 @@ class LlamaConfig:
     dtype: str = 'bfloat16'
     attention_impl: str = 'auto'    # 'auto' | 'flash' | 'dense'
     # Flash-attention tile sizes (None → ops/attention defaults). Tuned
-    # per chip generation; bench.py sweeps these on the real device.
+    # per chip generation.
     attn_block_q: Optional[int] = None
     attn_block_k: Optional[int] = None
     remat: bool = True              # rematerialize each layer in backward

@@ -50,20 +50,29 @@ _HOLD_MARGIN_S = 600.0
 
 
 def list_dumps(store) -> List[Dict[str, Any]]:
-    """Flight-recorder dumps in the span store, newest first:
-    ``{'dump_id', 'root', 'trigger', 'start', 'n_spans'}``."""
+    """Flight-recorder dumps in the span store, the last WRITTEN
+    first: ``{'dump_id', 'root', 'trigger', 'start', 'written',
+    'n_spans'}``. A trace's ``start`` is its oldest span, and a dump's
+    spans carry ring time: successive dumps of one incident share
+    their oldest ring record, so ``start`` ties them and the store
+    then orders them by their random ids. ``written`` is the root
+    span's end, the wall-clock moment the dump was taken."""
     out = []
     for tr in store.list_traces(limit=200,
                                 trace_id_prefix='stepline-'):
         if tr.get('root') not in ROOT_NAMES:
             continue
         spans = store.get_trace(tr['trace_id'])
-        root = _root_span(spans)
+        root = _root_span(spans) or {}
         out.append({
             'dump_id': tr['trace_id'], 'root': tr['root'],
-            'trigger': (root or {}).get('attrs', {}).get('trigger'),
-            'start': tr.get('start_ts'), 'n_spans': tr['n_spans'],
+            'trigger': root.get('attrs', {}).get('trigger'),
+            'start': tr.get('start_ts'),
+            'written': (float(root.get('start') or 0.0)
+                        + float(root.get('dur_s') or 0.0)),
+            'n_spans': tr['n_spans'],
         })
+    out.sort(key=lambda d: (-d['written'], d['dump_id']))
     return out
 
 

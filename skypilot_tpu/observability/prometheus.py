@@ -1,7 +1,7 @@
 """Prometheus text exposition for the serving tier.
 
 The LB's ``/-/metrics`` and each replica's ``/metrics`` are JSON by
-design (they feed `serve status` and the TTFT bench directly); this
+design (they feed `serve status` and the benchmark directly); this
 module is the exposition wrapper both grow behind
 ``?format=prometheus`` so a scrape-based stack ingests the same
 numbers without a JSON exporter sidecar.

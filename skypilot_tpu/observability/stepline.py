@@ -46,7 +46,6 @@ fused/pipeline/spec golden tests).
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
 import os
 import threading
@@ -175,14 +174,6 @@ class StageClock:
         return _Stage(self, name)
 
 
-_NO_STAGE = contextlib.nullcontext()
-
-
-def no_stage(name: str) -> Any:
-    """:meth:`StageClock.stage` of an engine whose recorder is off."""
-    return _NO_STAGE
-
-
 class Ring:
     """Fixed-capacity ring buffer: O(1) append, oldest-first
     ``snapshot``, and a monotonic ``total`` so wraparound is
@@ -290,9 +281,9 @@ def render_snapshot(raw: Dict[str, Any]) -> Dict[str, Any]:
 
 def summarize(recs: List[StepRecord]) -> Dict[str, Any]:
     """Aggregate step-time breakdown over a snapshot of step records:
-    total and fractional share per stage — the recorder-derived
-    decomposition ``bench_ttft`` stamps into the TTFT json. Runs on a
-    COPY, so callers can (and do) compute it outside any lock."""
+    total and fractional share per stage (``/debug/stepline``'s
+    ``summary``). Runs on a COPY, so callers can (and do) compute it
+    outside any lock."""
     tot = {s: 0.0 for s in STAGES}
     kinds: Dict[str, int] = {}
     dur = 0.0

@@ -656,8 +656,7 @@ class LoadBalancer:
                     m = await r.json()
                     # Decode-efficiency gauges ride the same fetch:
                     # tokens/step (>1 under speculative decoding) and
-                    # the spec acceptance stats the bench and
-                    # dashboards watch.
+                    # the spec acceptance stats dashboards watch.
                     eff = {
                         k: m.get(k) for k in (
                             'tokens_per_step',
@@ -1255,7 +1254,7 @@ class LoadBalancer:
     # -- request path ------------------------------------------------------
     # NOTE: JSON (not the API server's Prometheus registry) stays the
     # default — the LB runs as its own process on the serve controller
-    # and this shape feeds `serve status` + the TTFT bench directly;
+    # and this shape feeds `serve status` directly;
     # `?format=prometheus` wraps lb_metrics() in text exposition
     # (observability/prometheus.py) for scrape-based stacks.
     # Tenant ids are client-controlled: bound the per-tenant map so an
@@ -1981,7 +1980,7 @@ class LoadBalancer:
             return web.json_response(
                 {'ready_replica_urls': list(self.policy.ready_urls)})
         if request.path == '/-/metrics':
-            # JSON by default (feeds `serve status` + the TTFT bench);
+            # JSON by default (feeds `serve status`);
             # `?format=prometheus` wraps the same gauges in text
             # exposition for a scrape-based stack.
             if request.query.get('format') == 'prometheus':

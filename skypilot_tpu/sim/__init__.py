@@ -8,9 +8,10 @@ REAL ``LoadBalancer`` (policies, breakers, resume splicing, shed
 routing), the REAL ``ServeController`` tick + autoscalers, the REAL
 ``ReplicaManager`` lifecycle state machine, and the REAL
 ``infer/sched`` admission code (fcfs/EDF/wfq quotas) — against modeled
-replicas parameterized by measured TTFT/ITL curves from the bench
-JSONs. A 24h diurnal trace at 1000 modeled replicas, with spot-reclaim
-storms and tenant bursts, replays in seconds of tier-1 wall clock;
+replicas whose step time follows an invented curve
+(``replica.PerfModel.default``). A 24h diurnal trace at 1000 modeled
+replicas, with spot-reclaim storms and tenant bursts, replays in
+seconds of tier-1 wall clock;
 the same seed produces a byte-identical decision log.
 
 Layout:
@@ -19,7 +20,7 @@ Layout:
   trampoline that drives the LB's real ``async def handle`` without an
   asyncio loop.
 - ``replica``: modeled replicas — a REAL scheduler instance fronting
-  virtual decode slots whose step time follows the bench ITL curves.
+  virtual decode slots whose step time follows ``PerfModel``'s curve.
 - ``cloud``: the ``CloudAdapter`` implementation (virtual provisioner,
   probes, preemption notices, drains) + the deterministic executor the
   replica manager's thread pool is swapped for.

@@ -5,9 +5,9 @@ Each modeled replica embeds a real ``infer/sched`` policy instance
 production step loop drives), so fleet-scale gates prove the REAL
 per-tenant shed and starvation behavior. Only the device is modeled:
 decode advances one token per slot per virtual step, and the step
-cadence follows the measured ITL-vs-concurrency curve from the bench
-JSONs (TTFT_r06/r07) — so queueing, batching pressure, and admission
-interact with arrival shapes the way the real engine's do.
+cadence follows an ITL-vs-concurrency curve (``PerfModel.default``:
+invented, not a chip's) — so queueing, batching pressure, and
+admission interact with arrival shapes the way the real engine's do.
 
 Failure surface (what the scenarios drive):
 
@@ -88,16 +88,15 @@ def oracle_fingerprint() -> str:
 
 @dataclasses.dataclass
 class PerfModel:
-    """Measured performance curves: virtual step time as a function of
-    decode concurrency (piecewise-linear over the bench sweep levels),
-    plus the prefill budget per step that sets modeled TTFT."""
+    """The modeled device: virtual step time as a function of decode
+    concurrency (piecewise-linear between the curve's points), plus
+    the prefill budget per step that sets modeled TTFT."""
 
     # (concurrency, step_seconds), ascending concurrency.
     itl_curve: List[Tuple[float, float]]
     prefill_tokens_per_step: float = 256.0
-    # Uniform stretch: the bench box's tiny-model ITLs are ~ms; a
-    # scenario can scale toward production-shaped tens of ms without
-    # re-deriving the curve's SHAPE.
+    # Uniform stretch: a scenario slows or speeds every step without
+    # changing the curve's SHAPE.
     scale: float = 1.0
 
     def step_s(self, concurrency: int) -> float:
@@ -117,32 +116,15 @@ class PerfModel:
 
     @classmethod
     def default(cls, scale: float = 1.0) -> 'PerfModel':
+        """The twin's step-time curve. The numbers are INVENTED (a
+        plausible shape: a step slows as more requests share it), and
+        every gate of ``tests/sim`` is tuned to them, so they stay as
+        they are. They are not a chip's: the benchmark's chat cell
+        reads 19.98 ms a decode step at four requests in flight
+        (``itl_p50_ms``; ledger, PR 28). The twin proves ordering and
+        control-loop behaviour in virtual time, never speed."""
         return cls(itl_curve=[(1, 0.020), (8, 0.030), (16, 0.045)],
                    scale=scale)
-
-    @classmethod
-    def from_bench_json(cls, path: str, *, scale: float = 1.0,
-                        lane: str = 'spec_on') -> 'PerfModel':
-        """Derive the curve from a ``bench_ttft`` sweep JSON
-        (TTFT_r06-style: per-level ``concurrency`` + per-lane
-        ``itl_p50_ms``). Falls back to :meth:`default` when the file
-        has no usable sweep — a missing bench must not fail a replay."""
-        try:
-            with open(path, encoding='utf-8') as f:
-                doc = json.load(f)
-            pts: List[Tuple[float, float]] = []
-            for level in doc.get('sweep') or []:
-                conc = level.get('concurrency')
-                row = level.get(lane) if isinstance(level.get(lane),
-                                                   dict) else level
-                itl = (row or {}).get('itl_p50_ms')
-                if conc and itl:
-                    pts.append((float(conc), float(itl) / 1e3))
-            if pts:
-                return cls(itl_curve=sorted(pts), scale=scale)
-        except (OSError, ValueError, TypeError):
-            pass
-        return cls.default(scale=scale)
 
 
 class _Req:

@@ -108,7 +108,6 @@ class Scenario:
     max_queue_tokens: Optional[int] = None
     slots: int = 8
     perf_scale: float = 1.0
-    bench_json: Optional[str] = None
     # Virtual cloud.
     provision_delay_s: Tuple[float, float] = (30.0, 90.0)
     zones: Optional[List[Tuple[str, str]]] = None

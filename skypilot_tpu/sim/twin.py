@@ -261,12 +261,7 @@ class DigitalTwin:
 
     # ---- pieces --------------------------------------------------------
     def _make_perf(self) -> replica_lib.PerfModel:
-        if self.sc.bench_json:
-            perf = replica_lib.PerfModel.from_bench_json(
-                self.sc.bench_json, scale=self.sc.perf_scale)
-        else:
-            perf = replica_lib.PerfModel.default(
-                scale=self.sc.perf_scale)
+        perf = replica_lib.PerfModel.default(scale=self.sc.perf_scale)
         if self.sc.prefill_tokens_per_step is not None:
             perf.prefill_tokens_per_step = float(
                 self.sc.prefill_tokens_per_step)

@@ -1,7 +1,7 @@
 """What a compute process runs on, and where it keeps compiled programs.
 
 Every process that owns an accelerator (``infer.server``, ``train.run``,
-``bench.py``, ``__graft_entry__``, the children of ``chip_smoke.py``)
+``__graft_entry__``, the children of ``chip_smoke.py``)
 answers both questions through this module, so a result can always say
 which device produced it and two processes of one checkout always share
 one persistent XLA compilation cache.

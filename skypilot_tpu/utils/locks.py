@@ -49,41 +49,3 @@ def named_lock(name: str, timeout: float = 60.0) -> Iterator[None]:
             _named_locks[path] = lock
     with lock:
         yield
-
-
-# The bench-owns-the-chip lock lives at a FIXED machine-wide path, NOT
-# under SKY_TPU_HOME: benches and the test suite run with different
-# (per-test, per-run) homes, and the whole point is that they contend
-# on the one physical accelerator.
-CHIP_LOCK_ENV = 'SKY_TPU_CHIP_LOCK'
-
-
-def chip_lock_path() -> str:
-    import tempfile
-    return (os.environ.get(CHIP_LOCK_ENV) or
-            os.path.join(tempfile.gettempdir(), 'sky_tpu_chip0.lock'))
-
-
-def acquire_chip_lock(tag: str, timeout: float = 3600.0
-                      ) -> filelock.FileLock:
-    """Blocking chip-lock acquisition for benches: logs the wait and
-    holds until process exit (flock dies with the process)."""
-    import sys
-    lock = chip_lock(timeout=timeout)
-    print(f'[{tag}] acquiring chip lock {chip_lock_path()}',
-          file=sys.stderr)
-    lock.acquire()
-    return lock
-
-
-def chip_lock(timeout: float = -1) -> filelock.FileLock:
-    """Machine-wide accelerator ownership (VERDICT r5 weak #2: perf
-    artifacts were produced while the test suite burned the box).
-
-    Benches (bench.py / bench_ttft.py) hold it for their measured
-    section with a long blocking timeout; the test session try-acquires
-    it at startup (tests/conftest.py) so a bench launched mid-suite
-    waits instead of measuring noise. flock-backed, so a crashed
-    holder's lock dies with its process.
-    """
-    return filelock.FileLock(chip_lock_path(), timeout=timeout)

@@ -5,8 +5,7 @@ production traffic actually has — bursty arrivals, heavy-tail prompt
 lengths, shared-prefix cohorts, per-tenant mixes, mid-stream
 disconnects — and replays them either directly against an
 ``InferenceEngine`` (the tier-1 starvation gates in
-``test_scheduler_fairness.py``) or over HTTP through the serve LB
-(``bench_ttft --sweep tenants``).
+``test_scheduler_fairness.py``) or over HTTP through the serve LB.
 
 Determinism contract: ``synthesize(seed=s, ...)`` returns an
 identical event list for identical arguments (one ``random.Random(s)``
@@ -37,8 +36,7 @@ from skypilot_tpu.sim.tracefmt import TraceEvent
 
 
 def _block(rng: random.Random, n: int) -> List[int]:
-    """n token ids in [2, 201] — inside every model's vocab (the same
-    id range bench_ttft uses)."""
+    """n token ids in [2, 201] — inside every model's vocab."""
     return [2 + rng.randrange(200) for _ in range(n)]
 
 
@@ -46,7 +44,7 @@ def rate_envelope(spec: Any) -> Optional[Tuple[Callable[[float], float],
                                                float]]:
     """Compile a tenant's ``envelope`` spec into ``(multiplier(t),
     peak)`` — the rate SHAPE over (virtual) trace time that the
-    digital twin and ``bench_ttft --sweep tenants`` both replay.
+    digital twin replays.
     ``rps`` stays the rate at multiplier 1.0. Shapes:
 
     - ``{'kind': 'diurnal', 'period_s': 86400, 'low': 0.2}`` — a
@@ -116,10 +114,10 @@ def synthesize(seed: int, tenants: Dict[str, Dict[str, Any]],
       (defaults 0 / duration_s)
     - ``envelope``: a rate SHAPE over trace time (see
       :func:`rate_envelope`): diurnal day-curves and flash crowds for
-      the digital twin's 24h replays and ``bench_ttft --sweep
-      tenants``. ``rps`` is the rate at multiplier 1.0; arrivals are
-      thinned deterministically (same seed → same trace). Absent ⇒
-      the legacy constant-rate shape, byte-identical to before.
+      the digital twin's 24h replays. ``rps`` is the rate at
+      multiplier 1.0; arrivals are thinned deterministically (same
+      seed → same trace). Absent ⇒ the legacy constant-rate shape,
+      byte-identical to before.
     """
     events: List[TraceEvent] = []
     for name in sorted(tenants):
@@ -324,11 +322,11 @@ def replay_over_http(events: List[TraceEvent], gen_url: str,
                      tenant_header: str = 'X-SkyTpu-Tenant',
                      speed: float = 1.0, timeout: float = 300.0,
                      max_workers: int = 64) -> List[Dict[str, Any]]:
-    """Replay a trace through a live /generate endpoint (the serve LB
-    in ``bench_ttft --sweep tenants``): each event fires at its
-    speed-scaled offset on a worker thread, streams its response, and
-    reports client-observed TTFT/ITL, the done-line ``queue_wait_s``,
-    and shed/disconnect outcomes."""
+    """Replay a trace through a live /generate endpoint (the serve
+    LB): each event fires at its speed-scaled offset on a worker
+    thread, streams its response, and reports client-observed
+    TTFT/ITL, the done-line ``queue_wait_s``, and shed/disconnect
+    outcomes."""
     t0 = time.perf_counter()
 
     def run(ev: TraceEvent) -> Dict[str, Any]:

@@ -15,8 +15,6 @@ import pytest
 
 pytestmark = pytest.mark.jax
 
-import jax  # noqa: E402
-
 from skypilot_tpu.infer import engine as engine_lib  # noqa: E402
 from skypilot_tpu.models import llama  # noqa: E402
 
@@ -32,8 +30,10 @@ _WORKLOAD = [_PREFIX + [101, 55, 3, 9],
 
 
 @pytest.fixture(scope='module')
-def params():
-    return llama.init_params(CFG, jax.random.PRNGKey(0))
+def params(spec_params):
+    """Weights whose greedy continuation repeats (conftest's
+    ``spec_params``), so the spec-on lane has drafts to verify."""
+    return spec_params
 
 
 def _engine(params, spec_k=0):

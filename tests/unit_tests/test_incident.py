@@ -128,6 +128,22 @@ def test_double_export_is_byte_identical(tmp_path):
     assert len(trace.events) == 30
 
 
+def test_list_dumps_puts_the_last_written_first(tmp_path):
+    """Successive dumps of one incident hold the same oldest ring
+    record, so the store's own order (oldest span, then the random
+    trace id) cannot tell which is newest: the ids here are chosen so
+    that neither id order is the order of writing."""
+    store = store_lib.SpanStore(db_path=str(tmp_path / 's.db'))
+    for written, tag in ((1000.0, 'b'), (2000.0, 'a'), (3000.0, 'c')):
+        spans = _dump(request_events=[_req(100.0), _req(written / 10)])
+        for sp in spans:
+            sp['trace_id'] = f'stepline-fleet-{tag}'
+        spans[0]['start'] = written    # the root: when it was taken
+        store.add_spans(spans)
+    assert [d['dump_id'] for d in incident.list_dumps(store)] == [
+        'stepline-fleet-c', 'stepline-fleet-a', 'stepline-fleet-b']
+
+
 def test_find_dump_rejects_unknown_and_ambiguous(tmp_path):
     store = store_lib.SpanStore(db_path=str(tmp_path / 's.db'))
     with pytest.raises(ValueError, match='no flight-recorder dump'):

@@ -4,7 +4,7 @@ The paged engine must be a drop-in for the dense engine: same tokens
 out (greedy), same continuous-batching behavior — while HBM scales with
 tokens-in-flight and preemption/resume handles pool exhaustion.
 Kernels run in interpret mode on the CPU mesh; the same code path runs
-compiled on TPU (bench_ttft drives it on the real chip).
+compiled on TPU (every cell of the benchmark serves from it).
 """
 import numpy as np
 import pytest
@@ -17,9 +17,16 @@ from skypilot_tpu.infer import paged_cache as paged_cache_lib
 from skypilot_tpu.models import llama
 from skypilot_tpu.ops import paged_attention as pa
 
-jax.config.update('jax_default_matmul_precision', 'highest')
-
 pytestmark = pytest.mark.jax
+
+
+@pytest.fixture(scope='module', autouse=True)
+def _highest_matmul_precision():
+    """This module's comparisons are made at the highest matmul
+    precision; scoped to its own tests, and restored, so that no other
+    test in the process runs under a precision it did not ask for."""
+    with jax.default_matmul_precision('highest'):
+        yield
 
 
 # ---------- kernels vs references -----------------------------------------

@@ -21,8 +21,6 @@ import pytest
 
 pytestmark = pytest.mark.jax
 
-import jax  # noqa: E402
-
 from skypilot_tpu.infer import engine as engine_lib  # noqa: E402
 from skypilot_tpu.infer import server as server_lib  # noqa: E402
 from skypilot_tpu.models import llama  # noqa: E402
@@ -31,8 +29,8 @@ CFG = llama.LlamaConfig.tiny()
 
 
 @pytest.fixture(scope='module')
-def params():
-    return llama.init_params(CFG, jax.random.PRNGKey(0))
+def params(tiny_params):
+    return tiny_params
 
 
 # The determinism workload: mixed short/multi-chunk prompts, more
@@ -82,8 +80,11 @@ def paged_runs(params):
     return eng, out0, out1, preempt_d1, pages_after_d1
 
 
-def test_greedy_identical_depth0_vs_depth1_dense(dense_runs):
+def test_greedy_identical_depth0_vs_depth1_dense(dense_runs,
+                                                 greedy_oracle):
     _, out0, out1 = dense_runs
+    assert out0 == greedy_oracle(_PROMPTS, 6), (
+        'the dense depth-0 engine left the no-cache float32 forward')
     assert out0 == out1, (
         'dispatch-ahead changed greedy output (dense)')
 

@@ -331,34 +331,30 @@ def test_aggregate_stats_merges_tiers_exactly():
 
 
 # ---------- engine level ----------------------------------------------------
-import jax  # noqa: E402
-
 from skypilot_tpu.infer import engine as engine_lib  # noqa: E402
 from skypilot_tpu.models import llama  # noqa: E402
 
 CFG = llama.LlamaConfig.tiny()
 
-# Greedy outputs of the PRE-REFACTOR inline step loop (captured at
-# commit 85bfa13, before the scheduler extraction) over the
-# test_infer_pipeline workload: mixed multi-chunk/short prompts, 3
-# slots, paged pool small enough to force preemption. Identical at
-# pipeline depth 0 and 1, dense and paged.
+# The test_infer_pipeline workload: mixed multi-chunk/short prompts, 3
+# slots, paged pool small enough to force preemption.
 _PROMPTS = [[11] * 60, [23] * 60, [37] * 60,
             [5, 17, 101, 7], [9, 8, 7, 6, 5]]
-GOLD = [[5, 121, 205, 23, 23, 23], [25, 61, 205, 219, 30, 31],
-        [37, 37, 37, 37, 37, 37], [53, 128, 218, 127, 121, 194],
-        [240, 242, 233, 205, 219, 44]]
 
 
 @pytest.fixture(scope='module')
-def params():
-    return llama.init_params(CFG, jax.random.PRNGKey(0))
+def params(tiny_params):
+    return tiny_params
 
 
-def test_fcfs_bit_identical_to_pre_refactor_goldens(params):
-    """The refactored step loop under fcfs reproduces the captured
-    pre-refactor outputs, at depth 1 and (same engine, the multihost
-    reconfiguration path) depth 0, with paged preemption in play."""
+def test_fcfs_bit_identical_to_the_oracle_at_depth_1_and_0(params,
+        greedy_oracle):
+    """The step loop under fcfs reproduces the greedy tokens of the
+    no-cache float32 forward (conftest's oracle), at depth 1 and (same
+    engine, the multihost reconfiguration path) depth 0, with paged
+    preemption in play: scheduling decides when a token is computed,
+    never which."""
+    gold = greedy_oracle(_PROMPTS, 6)
     eng = engine_lib.InferenceEngine(
         CFG, params,
         engine_lib.EngineConfig(n_slots=3, max_seq_len=128,
@@ -367,13 +363,13 @@ def test_fcfs_bit_identical_to_pre_refactor_goldens(params):
                                 paged=True, page_size=16, n_pages=13))
     out1 = [r.output_tokens
             for r in eng.generate(_PROMPTS, max_new_tokens=6)]
-    assert out1 == GOLD, 'depth 1 diverged from the pre-refactor run'
+    assert out1 == gold, 'depth 1 diverged from the oracle'
     assert eng.metrics()['preemptions'] >= 1, (
         'workload no longer exercises page pressure')
     eng.set_pipeline_depth(0)
     out0 = [r.output_tokens
             for r in eng.generate(_PROMPTS, max_new_tokens=6)]
-    assert out0 == GOLD, 'depth 0 diverged from the pre-refactor run'
+    assert out0 == gold, 'depth 0 diverged from the oracle'
 
 
 def test_deadline_engine_serves_edf(params):

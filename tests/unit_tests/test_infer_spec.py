@@ -29,8 +29,6 @@ import pytest
 
 pytestmark = pytest.mark.jax
 
-import jax  # noqa: E402
-
 from skypilot_tpu.infer import drafter as drafter_lib  # noqa: E402
 from skypilot_tpu.infer import engine as engine_lib  # noqa: E402
 from skypilot_tpu.infer import server as server_lib  # noqa: E402
@@ -42,8 +40,11 @@ CFG = llama.LlamaConfig.tiny()
 
 
 @pytest.fixture(scope='module')
-def params():
-    return llama.init_params(CFG, jax.random.PRNGKey(0))
+def params(spec_params):
+    """Weights whose greedy continuation repeats (conftest's
+    ``spec_params`` asserts it once): every "a draft was accepted"
+    assertion below stands on that."""
+    return spec_params
 
 
 # The determinism workload of test_infer_pipeline: mixed short/
@@ -157,8 +158,11 @@ def paged_runs(params):
     return off, on, out_off, out_on1, out_on0, preempt
 
 
-def test_greedy_identical_spec_on_vs_off_dense(dense_runs):
+def test_greedy_identical_spec_on_vs_off_dense(dense_runs,
+                                               greedy_oracle):
     _, on, out_off, out_on1, out_on0 = dense_runs
+    assert out_off == greedy_oracle(_PROMPTS, 12), (
+        'the spec-off engine left the no-cache float32 forward')
     assert out_on1 == out_off, 'speculation changed greedy output'
     assert out_on0 == out_off, (
         'speculation changed greedy output at pipeline depth 0')
@@ -191,8 +195,7 @@ def test_spec_run_conserves_pages(paged_runs):
 
 def test_spec_off_requests_ride_plain_decode(params):
     """Per-request opt-out: an all-opt-out workload on a spec-enabled
-    engine never dispatches a verify step (the bench's baseline lane
-    is honest), and outputs still match."""
+    engine never dispatches a verify step, and outputs still match."""
     eng = _engine(params, spec_k=4)
     reqs = [eng.submit(p, max_new_tokens=8, spec=False)
             for p in _PROMPTS]

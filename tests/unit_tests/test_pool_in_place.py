@@ -43,9 +43,16 @@ from skypilot_tpu.ops import paged_attention as pa
 from skypilot_tpu.ops import quant as quant_lib
 from skypilot_tpu.ops import rope as rope_lib
 
-jax.config.update('jax_default_matmul_precision', 'highest')
-
 pytestmark = pytest.mark.jax
+
+
+@pytest.fixture(scope='module', autouse=True)
+def _highest_matmul_precision():
+    """This module's comparisons are made at the highest matmul
+    precision; scoped to its own tests, and restored, so that no other
+    test in the process runs under a precision it did not ask for."""
+    with jax.default_matmul_precision('highest'):
+        yield
 
 # A model much smaller than its pool, as in serving: one layer's slab
 # (64 KB of int8 K) is several times all of a layer's weights (28 KB),

@@ -209,15 +209,12 @@ def test_step_records_carry_the_scheduling_share(served):
     assert any(st['sched_s'] > 0 for st in steps)
 
 
-def test_greedy_outputs_identical_recorder_on_off(served, params):
-    """The existing contract, over the new stamps too: what the server
-    streamed with the recorder on is what a bare engine with the
-    recorder off generates."""
-    eng = engine_lib.InferenceEngine(CFG, params, _ecfg(stepline=False))
+def test_server_streams_what_a_bare_engine_generates(served, params):
+    """What the server streamed, with its front-end stamps, is what a
+    bare engine generates (both recording)."""
+    eng = engine_lib.InferenceEngine(CFG, params, _ecfg())
     plain = [r.output_tokens for r in eng.generate(PROMPTS,
                                                    max_new_tokens=6)]
-    assert eng.stepline_snapshot() == {'enabled': False, 'steps': [],
-                                       'events': []}
     for group in ('direct', 'lb'):
         got = [served['tokens'][rid] for rid in served[group]]
         assert got == plain
@@ -303,13 +300,6 @@ def test_stages_are_annotations_in_a_profiler_trace(params, tmp_path):
                 if ev.name == 'engine.step':
                     nums.append(dict(ev.stats).get('step_num'))
     assert sorted(nums) == [rec['idx'] for rec in records]
-
-
-def test_recorder_off_opens_no_stage(params):
-    eng = engine_lib.InferenceEngine(CFG, params, _ecfg(stepline=False))
-    assert eng._stage is stepline.no_stage and eng._sl_clock is None
-    with eng._stage('dispatch') as nothing:
-        assert nothing is None
 
 
 def test_stage_clock_adds_up_and_starts_each_step_from_zero():
