@@ -178,7 +178,9 @@ def _child_kernels(rehearse: bool, out_path: str) -> int:
 
     @functools.lru_cache(maxsize=None)     # one pool per page dtype
     def paged(int8: bool):
-        n_pages = slots * maxp + 1
+        # Page 0 and seven spares that no table names: the int8 pool's
+        # scale rows then pair up into whole 128-lane rows, as served.
+        n_pages = slots * maxp + 8
         k = normal((hkv, n_pages, page, hd), jnp.float32)
         v = normal((hkv, n_pages, page, hd), jnp.float32)
         if int8:
@@ -187,8 +189,8 @@ def _child_kernels(rehearse: bool, out_path: str) -> int:
         else:
             k, v, scales = k.astype(jnp.bfloat16), v.astype(
                 jnp.bfloat16), {}
-        tables = jnp.asarray(rng.permutation(
-            np.arange(1, n_pages)).reshape(slots, maxp), jnp.int32)
+        tables = jnp.asarray(rng.permutation(np.arange(1, n_pages))[
+            :slots * maxp].reshape(slots, maxp), jnp.int32)
         top = maxp * page - g['verify_r']
         lengths = rng.integers(1, top + 1, size=slots)
         lengths[:3] = (1, page, top)       # edges: first row, page end, full
@@ -197,12 +199,18 @@ def _child_kernels(rehearse: bool, out_path: str) -> int:
     def decode(int8: bool, impl: str):
         k, v, tables, lengths, sc = paged(int8)
         q = normal((slots, hkv, group, hd))
+        if impl != 'jax':       # a slot that is not decoding: length 0
+            lengths = lengths.at[3].set(0)
+        live = np.asarray(lengths) > 0
         out = pa.paged_decode_attention(q, k, v, tables, lengths,
                                         interpret=interpret, impl=impl,
                                         **sc)
         ref = reference(pa.paged_decode_attention_reference, q, k, v,
                         tables, lengths, **sc)
-        return [(out, ref)]
+        pairs = [(out[live], ref[live])]
+        if not live.all():      # what the kernel left alone: zeros
+            pairs.append((out[~live], jnp.zeros_like(out[~live])))
+        return pairs
 
     def prefill(int8: bool):
         k, v, tables, _, sc = paged(int8)
@@ -265,10 +273,11 @@ def _child_kernels(rehearse: bool, out_path: str) -> int:
         (f'flash_fwd_bwd_hd{d}', lambda a=(hq, hk, d): flash(*a))
         for hq, hk, d in g['flash_heads']]
     entries += [
-        ('paged_decode_native_bf16', lambda: decode(False, 'native')),
-        ('paged_decode_native_int8', lambda: decode(True, 'native')),
-        # What impl='auto' picks: on TPU the jax library kernel.
-        ('paged_decode_auto_bf16', lambda: decode(False, 'auto')),
+        # impl='auto', what the step programs call: this repo's kernel.
+        ('paged_decode_bf16', lambda: decode(False, 'auto')),
+        ('paged_decode_int8', lambda: decode(True, 'auto')),
+        # jax's library kernel, while impl='jax' exists (ROADMAP D7).
+        ('paged_decode_library_bf16', lambda: decode(False, 'jax')),
         ('paged_prefill_bf16', lambda: prefill(False)),
         ('paged_prefill_int8', lambda: prefill(True)),
         ('paged_verify_bf16', lambda: verify(False)),

@@ -271,14 +271,19 @@ def paged_decode_step(config: llama.LlamaConfig, params: llama.Params,
                       active: Optional[jnp.ndarray] = None
                       ) -> Tuple[jnp.ndarray,
                                  paged_cache_lib.PagedKVCache]:
-    """decode_step over the paged cache: one token for every slot, HBM
-    traffic ∝ sum(ceil(len_i/page)) pages via the scalar-prefetch decode
-    kernel (dead page steps skip their DMA; ops/paged_attention.py).
+    """decode_step over the paged cache: one token for every slot. The
+    attention kernel (ops/paged_attention.py ``_decode_kernel``) copies
+    the pages that the ACTIVE slots own, sum(ceil(len_i/page)) of
+    them, and nothing for a slot that is not active (`_attended` hands
+    it over with length 0): its row of the logits is computed from a
+    zero attention output and ignored by the engine, like its K/V row,
+    which still lands at its frontier.
 
     The engine guarantees every active slot's table covers position
     lengths[slot] (the incoming token's write target).
     """
     positions = pkv.lengths
+    attend = _attended(positions, active)
     with jax.named_scope('embed'):
         x = quant_lib.qembed(params['embed'], tokens)[:, None]
     cos, sin = rope_lib.rope_frequencies(config.head_dim,
@@ -288,7 +293,7 @@ def paged_decode_step(config: llama.LlamaConfig, params: llama.Params,
     def layer_fn(h, layer, kv, physical):
         return _paged_decode_layer(
             config, h, layer, cos, sin, kv, physical(block_tables),
-            positions, physical(0))
+            positions, attend, physical(0))
 
     x, pkv = _scan_layers(params, pkv, x, layer_fn)
     with jax.named_scope('head'):
@@ -300,11 +305,21 @@ def paged_decode_step(config: llama.LlamaConfig, params: llama.Params,
     return logits, dataclasses.replace(pkv, lengths=pkv.lengths + bump)
 
 
+def _attended(positions, active):
+    """What the decode kernel attends to in each slot: the positions up
+    to the token just written, and nothing (length 0: no page read, a
+    zero row out) in a slot that is not decoding, whose row the engine
+    throws away."""
+    if active is None:
+        return positions + 1
+    return jnp.where(active, positions + 1, 0)
+
+
 def _paged_decode_layer(config, x, layer, cos, sin, pkv, block_tables,
-                        positions, sink_page):
+                        positions, attend, sink_page):
     """One layer of the paged decode step. pkv: the whole folded pool;
     block_tables / sink_page: this layer's PHYSICAL page ids (the sink
-    is the layer's own page 0); x: [slots, 1, d]."""
+    is the layer's own page 0); x: [slots, 1, d]; attend: `_attended`."""
     slots, _, d = x.shape
     hq, hkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     group = hq // hkv
@@ -326,9 +341,9 @@ def _paged_decode_layer(config, x, layer, cos, sin, pkv, block_tables,
     with jax.named_scope('attn'):
         qg = q[:, 0].reshape(slots, hkv, group, hd)
         att = paged_attn.paged_decode_attention(
-            qg, pkv.k_pages, pkv.v_pages, block_tables, positions + 1,
+            qg, pkv.k_pages, pkv.v_pages, block_tables, attend,
             k_scales=pkv.k_scales, v_scales=pkv.v_scales)
-        att = att.reshape(slots, 1, hq * hd).astype(x.dtype)
+        att = att.reshape(slots, 1, hq * hd)
         x = x + quant_lib.qdot(att, layer['wo'])
     with jax.named_scope('mlp'):
         x = llama.mlp_block(config, x, layer)
@@ -655,6 +670,7 @@ def paged_mixed_step(config: llama.LlamaConfig, params: llama.Params,
     lengths_mid = pkv.lengths.at[slot].set(
         (offset + true_len).astype(jnp.int32))
     dpos = lengths_mid
+    attend = _attended(dpos, active)
 
     def layer_fn(h, layer, kv, physical):
         hc, hd_ = h
@@ -663,7 +679,7 @@ def paged_mixed_step(config: llama.LlamaConfig, params: llama.Params,
             offset, true_len)
         hd_, kv = _paged_decode_layer(
             config, hd_, layer, cos, sin, kv, physical(block_tables),
-            dpos, physical(0))
+            dpos, attend, physical(0))
         return (hc, hd_), kv
 
     (xc, xd), pkv = _scan_layers(params, pkv, (xc, xd), layer_fn)
@@ -709,8 +725,9 @@ def _hybrid_attn_chunk(config, x, layer, kv, table_row, offset, true_len):
 
 
 def _hybrid_attn_decode(config, x, layer, kv, block_tables, positions,
-                        sink_page):
-    """A ``*`` block for one token of every slot; x [slots, d]."""
+                        attend, sink_page):
+    """A ``*`` block for one token of every slot; x [slots, d];
+    attend: `_attended`."""
     slots = x.shape[0]
     with jax.named_scope('attn'):
         q, k, v = nemotron_h.attn_qkv(config, layer, x)
@@ -720,8 +737,8 @@ def _hybrid_attn_decode(config, x, layer, kv, block_tables, positions,
             None, sink_page=sink_page))
     with jax.named_scope('attn'):
         att = paged_attn.paged_decode_attention(
-            q, kv.k_pages, kv.v_pages, block_tables, positions + 1)
-        att = att.reshape(slots, -1).astype(x.dtype)
+            q, kv.k_pages, kv.v_pages, block_tables, attend)
+        att = att.reshape(slots, -1)
         x = x + jnp.dot(att, layer['wo'])
     return x, kv
 
@@ -795,6 +812,7 @@ def hybrid_decode_step(config: nemotron_h.NemotronHConfig,
         active = jnp.ones((slots,), bool)
     kv = cache.kv
     positions = kv.lengths
+    attend = _attended(positions, active)
     with jax.named_scope('embed'):
         x = params['embed'][tokens]                       # [slots, d]
     moe_stats = jnp.zeros((3,), jnp.int32)
@@ -812,7 +830,7 @@ def hybrid_decode_step(config: nemotron_h.NemotronHConfig,
                                          kv.n_pages, i)
             x, kv = _hybrid_attn_decode(config, x, layer, kv,
                                         physical(block_tables), positions,
-                                        physical(0))
+                                        attend, physical(0))
         else:
             y, stats = nemotron_h.moe_mixer(config, layer, x, active)
             x = x + y

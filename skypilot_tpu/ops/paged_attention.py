@@ -22,28 +22,46 @@ knows the difference.
 Kernel design (per /opt/skills/guides/pallas_guide.md):
 
 - The block table and lengths ride **scalar prefetch**
-  (``PrefetchScalarGridSpec``): they land in SMEM before the pipeline
-  starts, so the K/V BlockSpec ``index_map`` can translate (slot, page
-  step) -> physical page id. The pages a slot touches are
-  non-contiguous in HBM; the pipeline gathers them page by page, every
-  KV head's rows of a page in one block.
-- A slot only pays DMA for the pages it OWNS: for steps past the
-  slot's last page the index_map re-maps to a page the in_spec already
-  holds, and Pallas skips the fetch when consecutive steps map the same
-  block (the revisiting-block rule the pipeline already implements).
-  The kernel body skips those steps. Bandwidth is therefore
-  sum(ceil(len_i/page)) pages, the whole point of paging.
-- Online softmax across the page axis (sequential innermost grid dim on
-  TPU), fp32 accumulators in VMEM scratch that persist across the page
-  steps of one slot and reinitialize at the first.
+  (``PrefetchScalarGridSpec``): they land in SMEM before the kernel
+  starts, so a page id is one scalar load. The pages a slot touches
+  are non-contiguous in HBM; they are gathered page by page, every KV
+  head's rows of a page in one copy.
+- A slot only pays DMA for the pages it OWNS, and bandwidth is
+  sum(ceil(len_i/page)) pages, the whole point of paging. The prefill
+  kernel gets there through its in_specs: for steps past the slot's
+  last page the index_map re-maps to a page the in_spec already holds,
+  and Pallas skips the fetch when consecutive steps map the same block
+  (the revisiting-block rule the pipeline already implements). The
+  decode kernel issues its own copies and simply issues none.
+- Online softmax across the keys, fp32 accumulators in VMEM scratch.
 
-The **decode** and **verify** kernels work a page at a time: grid =
-(slots, max_pages), one page (all KV heads) a step, an unrolled loop
-over the heads, each with its own [group, page] score tile and its
-m / l / accumulator updated once a page, operands cast to float32.
-Their rows are few (group, or R x group), so a tile is small whatever
-its width; no cell times them (the cells decode through jax's library
-kernel, speculation is off), and they keep that body until one does.
+The **decode** kernel (``_decode_kernel``) is ONE grid step: the pools
+stay in HBM (``memory_space=pl.ANY``), q and the output are whole in
+VMEM, and the kernel walks the live slots itself, so that a slot with
+length 0 (one that is not decoding) costs a scalar compare: no copy,
+no loop, a zero row out. A live slot's pages are worked in blocks of
+512 key columns (``_decode_tiles``): one ``make_async_copy`` a live
+page and pool (``pool.at[:, pid]``: [hkv, page, hd], 128 KB at
+Mistral's shapes), none for a page past the frontier, into one of two
+VMEM buffers; while a block is worked the next is in flight, the
+slot's own next or, after its last, the NEXT LIVE SLOT's first, so
+only the call's first block is waited for in full. Each KV head then
+takes the block as one [cols, hd] operand (operands in
+``promote_types(q.dtype, pages.dtype)``, float32 accumulation): one
+[rows, cols] score tile and m / l / accumulator updated once a block.
+The block's dead columns are masked, and the buffers start from zeros
+so that a masked column never multiplies a NaN. Why one step and not a
+grid over slots and pages: a grid step costs about 0.05 us for each
+blocked operand, fetched or skipped (PR 28), and a page an in_spec
+would have cost 160 us a call at 24 slots, dead or alive. The
+**verify** kernel is the same body with R queries a slot: rows = R x
+group, row r seeing r // group positions more; its blocks do not
+depend on R and its rows do not mix, so query 0 is bitwise the decode
+step at that position. The library kernel this replaced on the chip
+(jax's ``paged_attention``, still behind ``impl='jax'``) rounds every
+context up to a block of 8 pages and reads a dead slot's first block:
+49 MB a call at Mistral's shapes where four chat contexts own 7-10
+(PERF.md section 6, PR 30, has the timings).
 
 The **prefill** kernel (``_prefill_kernel``) is tiled for a chunk's
 many rows. Grid = (unit blocks, max_pages / fan): a step fetches `fan`
@@ -72,7 +90,7 @@ resident-unit sizes come from the call's shapes under a VMEM budget
 Two entry points, one numerically-identical reference each:
 
 - ``paged_decode_attention``: one query token per slot (the decode hot
-  path; HBM-bandwidth-bound).
+  path; HBM-bandwidth-bound), nothing for a slot of length 0.
 - ``paged_prefill_attention``: a C-token chunk of one slot's prompt
   attending to the slot's cached prefix + itself (causal) — the tiled
   replacement for the dense [C, S] einsum, O(C*len) instead of O(C*S).
@@ -86,16 +104,20 @@ fp32 absmax scale per cached token row per KV head
 Quantization happens ON WRITE (each row is quantized independently, so
 appending never rescales earlier rows) and dequantization happens IN
 KERNEL (the row scales multiply the score and probability columns, see
-``_scale_rows``) — the HBM stream is int8, roughly doubling the
-resident pages per chip. Every entry
-point takes optional ``k_scales``/``v_scales``; None means the bf16
-path, which is bit-for-bit the pre-quantization code.
+``_scale_rows``; the decode kernel copies them by whole 128-lane rows,
+``_scale_lane_rows``) — the HBM stream is int8, roughly doubling the
+resident pages per chip. Every entry point takes optional
+``k_scales``/``v_scales`` and runs the same kernel body with or
+without them; None means the pages hold their own values.
 
-Each ``pallas_call`` carries its entry point's name
-(``paged_decode_attention``, ``paged_prefill_attention``,
-``paged_verify_attention``): that is the operation's name in a
-profiler trace. Without one the compiled custom call takes the name
-of whatever scope encloses it (``closed_call`` inside a layer scan).
+Each ``pallas_call`` carries a name (``paged_attention_decode``,
+``paged_prefill_attention``, ``paged_verify_attention``): that is the
+operation's name in a profiler trace, and what the benchmark's
+roofline readers find the decode and prefill kernels by
+(``benchmark/metrics/kernel.paged_*_roofline.json``, ``ops_match``;
+tests/unit_tests/test_paged_decode_kernel.py holds each name to its
+own reader). Without one the compiled custom call takes the name of
+whatever scope encloses it (``closed_call`` inside a layer scan).
 """
 from __future__ import annotations
 
@@ -108,6 +130,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+_LANES = 128
 
 
 def _interpret_default(interpret: Optional[bool]) -> bool:
@@ -216,65 +239,270 @@ def paged_prefill_attention_reference(
 
 
 # ---------------------------------------------------------------------------
-# Decode kernel
+# Decode kernel (and, with R queries a slot, the verify kernel)
 # ---------------------------------------------------------------------------
-def _decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *refs,
-                   page_size: int, sm_scale: float, max_pages: int,
-                   hkv: int, quantized: bool):
-    if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    else:
-        o_ref, acc_ref, m_ref, l_ref = refs
-        ks_ref = vs_ref = None
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    del tables_ref  # consumed by the index_maps
-    length = lengths_ref[b]
-    n_pages = pl.cdiv(length, page_size)
+# Key columns of a block: the pages a slot's loop step fetches (each
+# its own copy, every KV head's rows in it) and works as one operand.
+_DECODE_BLOCK_COLS = 512
+# What the K and V blocks may hold in VMEM, each twice (the block at
+# work and the one in flight), and the limit the call asks for.
+_DECODE_VMEM_BUDGET = 16 << 20
+_DECODE_VMEM_LIMIT = 48 << 20
 
-    @pl.when(p == 0)
-    def _init():
+
+def _decode_tiles(hkv: int, hd: int, page_size: int, max_pages: int,
+                  itemsize: int) -> int:
+    """Pages a block, from what the call can see of its shapes:
+    `_DECODE_BLOCK_COLS` columns of them, no more than the table has or
+    than `_DECODE_VMEM_BUDGET` holds four times over. The number of
+    queries is no part of it: a verify run's blocks are a decode
+    step's."""
+    per_page = 4 * hkv * page_size * hd * itemsize
+    return max(1, min(max_pages, _DECODE_BLOCK_COLS // page_size,
+                      _DECODE_VMEM_BUDGET // per_page))
+
+
+def _scale_lane_rows(scales: jnp.ndarray) -> jnp.ndarray:
+    """[hkv, P, page] row scales -> [hkv, P / n, n * page], n pages'
+    scales side by side in a row that fills whole 128-lane tiles: what
+    the decode kernel's own copies can address. Mosaic slices a copy's
+    source only along whole tiles, and one page's scale row (64 lanes
+    at the served page size, `_scale_rows`' block) is half of one; the
+    kernel copies the row a page lies in and keeps the page's lanes. A
+    reshape where n divides P (the served pools), a padded copy of the
+    scales elsewhere."""
+    hkv, n_pages, page_size = scales.shape
+    if page_size % _LANES == 0:
+        return scales
+    if _LANES % page_size:
+        raise ValueError(
+            f'int8 KV pages of {page_size} rows: the decode kernel reads '
+            f'scale rows by {_LANES} lanes, which a page has to divide '
+            'or be a multiple of')
+    n = _LANES // page_size
+    if n_pages % n:
+        scales = jnp.pad(scales, ((0, 0), (0, -n_pages % n), (0, 0)))
+    return scales.reshape(hkv, -1, _LANES)
+
+
+def _decode_kernel(tables_ref, horizon_ref, q_ref, k_hbm, v_hbm, *refs,
+                   page_size: int, sm_scale: float, max_pages: int,
+                   group: int, r_queries: int, block_pages: int,
+                   quantized: bool):
+    """R queries of every slot over the slot's own pages, in ONE grid
+    step: the pools stay in HBM and the kernel walks the live slots
+    itself, so a dead slot (horizon 0) costs a scalar compare.
+
+    horizon_ref[b] is what query 0 of slot b attends to (positions <
+    horizon); query i sees i more. A slot's pages are worked in blocks
+    of `block_pages`: one copy a live page and pool (``pool.at[:,
+    pid]``: every KV head's rows of the page), none for a page past the
+    frontier, into one of two buffers; while a block is worked the next
+    one is in flight, the slot's own next or, after its last, the next
+    live slot's first. Each KV head then takes the block as one
+    [cols, hd] operand: one score tile of rows = R x group (group
+    fastest), m / l / accumulator updated once a block."""
+    if quantized:
+        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems,
+         acc_ref, m_ref, l_ref) = refs
+        scales = ((ks_hbm, ks_buf), (vs_hbm, vs_buf))
+    else:
+        o_ref, k_buf, v_buf, sems, acc_ref, m_ref, l_ref = refs
+        scales = ()
+    pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+    slots, hkv, rows, _ = q_ref.shape
+    cols = block_pages * page_size
+    pages_per_row = max(1, _LANES // page_size)     # `_scale_lane_rows`
+
+    def owned_pages(b):
+        horizon = horizon_ref[b]
+        return jnp.where(
+            horizon > 0,
+            jnp.minimum(pl.cdiv(horizon + r_queries - 1, page_size),
+                        max_pages), 0)
+
+    def next_live(b):
+        return jax.lax.while_loop(
+            lambda b: jnp.logical_and(
+                b < slots, horizon_ref[jnp.minimum(b, slots - 1)] <= 0),
+            lambda b: b + 1, b)
+
+    def each_copy(b, i, buf, act):
+        """`act` on the copy of every live page of slot b's block i
+        into buffer `buf`, pool by pool (a wait names the copy it
+        waits for by the same descriptor)."""
+        first = i * block_pages
+
+        def page(j, carry):
+            pid = tables_ref[b, first + j]
+            at = pl.multiple_of(j * page_size, page_size)
+            for n, (hbm, vmem) in enumerate(pools):
+                act(pltpu.make_async_copy(
+                    hbm.at[:, pid], vmem.at[buf, :, pl.ds(at, page_size)],
+                    sems.at[n, buf]))
+            for n, (hbm, vmem) in enumerate(scales, len(pools)):
+                act(pltpu.make_async_copy(
+                    hbm.at[:, pid // pages_per_row], vmem.at[buf, j],
+                    sems.at[n, buf]))
+            return carry
+        jax.lax.fori_loop(
+            0, jnp.minimum(owned_pages(b) - first, block_pages), page, 0)
+
+    def start(b, i, buf):
+        each_copy(b, i, buf, lambda copy: copy.start())
+
+    def wait(b, i, buf):
+        each_copy(b, i, buf, lambda copy: copy.wait())
+
+    # A block's dead columns (its pages past the frontier) are masked,
+    # not absent: a score there is replaced, whatever it is, but a
+    # probability of 0 still multiplies its value row, and 0 x NaN is
+    # NaN. VMEM that was never written may hold anything, so the value
+    # buffers start from zeros and from then on hold zeros or an
+    # earlier block's rows. A dead slot's output is zeros.
+    v_buf[...] = jnp.zeros_like(v_buf)
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    def slot(carry):
+        b, first_buf = carry
+        following = next_live(b + 1)
+        horizon = horizon_ref[b]
+        blocks = pl.cdiv(owned_pages(b), block_pages)
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
+        # Row r is query r // group, which sees r // group positions
+        # more than query 0.
+        reach = horizon + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, cols), 0) // group
+        column = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
 
-    @pl.when(p < n_pages)
-    def _accumulate():
-        pos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        valid = pos < length
-        # All KV heads of the page in one grid step (an unrolled loop of
-        # hkv small MXU matmuls): 8x fewer grid steps and 8x larger
-        # DMAs than a per-head grid — the fixed per-step cost, not the
-        # bytes, dominates paged decode.
-        for h in range(hkv):
-            q = q_ref[0, h].astype(jnp.float32) * sm_scale  # [group, hd]
-            k = k_ref[h, 0].astype(jnp.float32)             # [page, hd]
-            v = v_ref[h, 0].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)         # [group, page]
+        def block(i, carry):
+            buf = (first_buf + i) % 2
+            pl.when(i + 1 < blocks)(
+                lambda: start(b, i + 1, 1 - buf))
+            pl.when(jnp.logical_and(i + 1 == blocks, following < slots))(
+                lambda: start(following, 0, 1 - buf))
+            wait(b, i, buf)
+            visible = column < reach - i * cols
             if quantized:
-                s = s * ks_ref[h, 0]        # [1, page] K row scales
-            s = jnp.where(valid, s, _NEG_INF)
-            m_prev = m_ref[h]
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(s, axis=-1, keepdims=True))
-            pr = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[h] = l_ref[h] * alpha + jnp.sum(pr, axis=-1,
-                                                  keepdims=True)
-            if quantized:
-                pr = pr * vs_ref[h, 0]      # [1, page] V row scales
-            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-                pr, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[h] = m_new
+                # Where in its lane row each page's scales lie.
+                lane = [tables_ref[b, jnp.minimum(i * block_pages + j,
+                                                  max_pages - 1)]
+                        % pages_per_row for j in range(block_pages)]
 
-    @pl.when(p == max_pages - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+                def scale_columns(ref, h):
+                    """Head h's [1, cols] scales of the block in `buf`."""
+                    pages = []
+                    for j in range(block_pages):
+                        row = ref[buf, j, h:h + 1]
+                        mine = row[:, :page_size]
+                        for n in range(1, pages_per_row):
+                            mine = jnp.where(
+                                lane[j] == n,
+                                row[:, n * page_size:(n + 1) * page_size],
+                                mine)
+                        pages.append(mine)
+                    return jnp.concatenate(pages, axis=1)
+            for h in range(hkv):
+                # Operands go to the MXU as stored (q arrives in their
+                # dtype); int8 pages convert exactly.
+                q = q_ref[b, h]                             # [rows, hd]
+                k = k_buf[buf, h].astype(q.dtype)           # [cols, hd]
+                v = v_buf[buf, h].astype(q.dtype)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)     # [rows, cols]
+                if quantized:
+                    s = s * scale_columns(ks_buf, h)
+                s = jnp.where(visible, s, _NEG_INF)
+                # m is kept in raw score units and the softmax scale
+                # rides the exponent's argument, as in the prefill
+                # kernel: the products stay the stored values'.
+                m_prev = m_ref[h]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                alpha = jnp.exp((m_prev - m_new) * sm_scale)
+                pr = jnp.exp((s - m_new) * sm_scale)
+                l_ref[h] = l_ref[h] * alpha + jnp.sum(pr, axis=-1,
+                                                      keepdims=True)
+                if quantized:
+                    # A dead column's scale is whatever the buffer or
+                    # its table entry's lane row holds: out of the
+                    # product.
+                    pr = jnp.where(visible,
+                                   pr * scale_columns(vs_buf, h), 0.0)
+                acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+                    pr.astype(v.dtype), v,
+                    preferred_element_type=jnp.float32)
+                m_ref[h] = m_new
+            return carry
+        jax.lax.fori_loop(0, blocks, block, 0)
+        o_ref[b] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
+            o_ref.dtype)
+        return following, (first_buf + blocks) % 2
+
+    first_live = next_live(0)
+    pl.when(first_live < slots)(lambda: start(first_live, 0, 0))
+    jax.lax.while_loop(lambda carry: carry[0] < slots, slot,
+                       (first_live, 0))
+
+
+def _paged_queries(q: jnp.ndarray, k_pages: jnp.ndarray,
+                   v_pages: jnp.ndarray, block_tables: jnp.ndarray,
+                   horizon: jnp.ndarray, *, group: int,
+                   sm_scale: Optional[float], interpret: Optional[bool],
+                   k_scales: Optional[jnp.ndarray],
+                   v_scales: Optional[jnp.ndarray], name: str
+                   ) -> jnp.ndarray:
+    """`_decode_kernel` over q [slots, hkv, R*group, hd] (group
+    fastest); horizon [slots]: the positions query 0 attends to, 0 for
+    a slot to leave alone. Returns q's shape and dtype."""
+    slots, hkv, rows, hd = q.shape
+    page_size = k_pages.shape[2]
+    max_pages = block_tables.shape[1]
+    if sm_scale is None:
+        sm_scale = hd ** -0.5
+    interpret = _interpret_default(interpret)
+    quantized = k_scales is not None
+    mxu_dtype = jnp.promote_types(q.dtype, k_pages.dtype)
+    block_pages = _decode_tiles(hkv, hd, page_size, max_pages,
+                                k_pages.dtype.itemsize)
+    cols = block_pages * page_size
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    operands = [q.astype(mxu_dtype), k_pages, v_pages]
+    scratch = [pltpu.VMEM((2, hkv, cols, hd), k_pages.dtype)] * 2
+    if quantized:
+        operands += [_scale_lane_rows(k_scales), _scale_lane_rows(v_scales)]
+        lane_row = operands[-1].shape[2]
+        scratch += [pltpu.VMEM((2, block_pages, hkv, lane_row),
+                               jnp.float32)] * 2
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(1,),
+        in_specs=[whole] + [in_hbm] * (len(operands) - 1),
+        out_specs=whole,
+        scratch_shapes=scratch + [
+            pltpu.SemaphoreType.DMA((len(operands) - 1, 2)),
+            pltpu.VMEM((hkv, rows, hd), jnp.float32),
+            pltpu.VMEM((hkv, rows, 1), jnp.float32),
+            pltpu.VMEM((hkv, rows, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _decode_kernel, page_size=page_size, sm_scale=sm_scale,
+        max_pages=max_pages, group=group, r_queries=rows // group,
+        block_pages=block_pages, quantized=quantized)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_DECODE_VMEM_LIMIT),
+        interpret=interpret,
+        name=name,
+    )(block_tables, horizon, *operands)
 
 
 def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -293,33 +521,30 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     block_tables: [slots, maxp] int32; lengths: [slots] int32 (the
     kernel attends to positions < length — callers that write the new
     token's K/V first pass the already-bumped length, mirroring the
-    dense decode path's write-then-attend contract).
+    dense decode path's write-then-attend contract). **Length 0 leaves
+    the slot alone**: no page of it is read and its output is zeros,
+    which is what callers pass for a slot that is not decoding.
     k_scales/v_scales: [hkv, P, page] f32 row scales on the int8
-    flavor (forces the native kernel — the library path here is wired
-    for bf16 pages only); None = bf16 pages, the pre-quantization path.
+    flavor; None = the pages' own values. Returns q's shape and dtype:
+    both products take their operands in ``promote_types(q.dtype,
+    pages.dtype)`` (bfloat16 as served, float32 throughout on float32
+    pages) and accumulate in float32, m / l / accumulator float32.
 
-    impl: 'native' runs this module's grid kernel everywhere; 'jax'
-    runs jax's tuned JetStream decode kernel (same page layout —
-    convergent design — but an internal double-buffered DMA loop
-    instead of grid steps, measured ~1.6x faster on v5e); 'auto' picks
-    'jax' on real TPU and 'native' in interpret mode. The native kernel
-    is always the ground truth in tests.
+    impl: 'auto' and 'native' run this module's kernel
+    (``_decode_kernel``), compiled on a TPU and interpreted elsewhere.
+    'jax' runs jax's library kernel on a TPU (bfloat16 pages, the
+    default scale, hd a multiple of 128; it rounds every context up to
+    a block of pages and reads a dead slot's first block): nothing
+    serves through it since PR 30, it is kept for
+    tests/benchmark/test_tpu_compile.py, which compiles both (ROADMAP
+    D7).
     """
     slots, hkv, group, hd = q.shape
-    quantized = k_scales is not None
-    interpret_resolved = _interpret_default(interpret)
-    if impl == 'auto':
-        # The library kernel needs lane-aligned blocks (hd multiple of
-        # 128; its output block carries `group` in the sublane dim, so
-        # tiny test models fall back to the native kernel).
-        jax_ok = (hd % 128 == 0 and k_pages.shape[2] % 8 == 0
-                  and not quantized)
-        impl = ('jax' if jax_ok and not interpret_resolved
-                else 'native')
-    if impl == 'jax' and quantized:
+    interpret = _interpret_default(interpret)
+    if impl == 'jax' and k_scales is not None:
         raise ValueError("impl='jax' is wired for bf16 pages only; "
                          "use the native kernel for kv_dtype=int8")
-    if impl == 'jax' and not interpret_resolved:
+    if impl == 'jax' and not interpret:
         from jax.experimental.pallas.ops.tpu.paged_attention import (
             paged_attention as jax_paged_attention)
         if sm_scale is not None and sm_scale != hd ** -0.5:
@@ -334,62 +559,16 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
             (qf * (hd ** -0.5)).astype(k_pages.dtype),
             k_pages, v_pages, lengths, block_tables,
             pages_per_compute_block=ppcb)
-        return out.reshape(slots, hkv, group, hd).astype(jnp.float32)
-    page_size = k_pages.shape[2]
-    max_pages = block_tables.shape[1]
-    if sm_scale is None:
-        sm_scale = hd ** -0.5
-    interpret = _interpret_default(interpret)
-
-    def _page_index(b, p, tables, lengths_):
-        # Pages past the slot's frontier re-map to the slot's LAST real
-        # page: consecutive grid steps then address the same block and
-        # the pipeline skips the fetch (the "revisiting block" rule) —
-        # dead steps cost neither DMA nor bandwidth.
-        n_pages = jax.lax.div(lengths_[b] + page_size - 1, page_size)
-        j = jnp.minimum(p, jnp.maximum(n_pages - 1, 0))
-        return (0, tables[b, j], 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, hkv, group, hd),
-                     lambda b, p, *_: (b, 0, 0, 0)),
-        pl.BlockSpec((hkv, 1, page_size, hd), _page_index),
-        pl.BlockSpec((hkv, 1, page_size, hd), _page_index),
-    ]
-    operands = [q, k_pages, v_pages]
-    if quantized:
-        # Scales ride the pages' own index map (_scale_rows).
-        in_specs += [pl.BlockSpec((hkv, 1, 1, page_size), _page_index)] * 2
-        operands += [_scale_rows(k_scales), _scale_rows(v_scales)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(slots, max_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, hkv, group, hd),
-                               lambda b, p, *_: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((hkv, group, hd), jnp.float32),
-            pltpu.VMEM((hkv, group, 1), jnp.float32),
-            pltpu.VMEM((hkv, group, 1), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_decode_kernel, page_size=page_size,
-                               sm_scale=sm_scale, max_pages=max_pages,
-                               hkv=hkv, quantized=quantized)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, hkv, group, hd),
-                                       jnp.float32),
-        interpret=interpret,
-        name='paged_decode_attention',
-    )(block_tables, lengths, *operands)
+        return out.reshape(slots, hkv, group, hd).astype(q.dtype)
+    return _paged_queries(
+        q, k_pages, v_pages, block_tables, lengths, group=group,
+        sm_scale=sm_scale, interpret=interpret, k_scales=k_scales,
+        v_scales=v_scales, name='paged_attention_decode')
 
 
 # ---------------------------------------------------------------------------
 # Prefill-chunk kernel
 # ---------------------------------------------------------------------------
-_LANES = 128
 # Key columns a grid step fetches and works as ONE block: wide enough
 # that the once-a-block bookkeeping (m, l, the accumulator's rescale:
 # about as many register operations as a 128-column score tile) is a
@@ -710,69 +889,6 @@ def paged_verify_attention_reference(
     return jnp.einsum('brkgs,bksd->brkgd', p, v.astype(jnp.float32))
 
 
-def _verify_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *refs,
-                   page_size: int, sm_scale: float, max_pages: int,
-                   hkv: int, group: int, r_queries: int,
-                   quantized: bool):
-    """The decode kernel with R queries per (slot, head): rows are
-    queries x group flattened (group fastest), each row's causal
-    horizon is its query's position — one extra iota/div over the
-    decode kernel, the same online-softmax accumulation per page."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    else:
-        o_ref, acc_ref, m_ref, l_ref = refs
-        ks_ref = vs_ref = None
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    del tables_ref  # consumed by the index_maps
-    length = lengths_ref[b]
-    # Pages holding ANY attendable position: the furthest query
-    # (r_queries-1) sees positions < length + r_queries.
-    n_pages = pl.cdiv(length + r_queries, page_size)
-
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(p < n_pages)
-    def _accumulate():
-        for h in range(hkv):
-            q = q_ref[0, h].astype(jnp.float32) * sm_scale  # [R*g, hd]
-            k = k_ref[h, 0].astype(jnp.float32)             # [page, hd]
-            v = v_ref[h, 0].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)         # [R*g, page]
-            if quantized:
-                s = s * ks_ref[h, 0]
-            kpos = p * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            qi = jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0) // group
-            s = jnp.where(kpos < length + qi + 1, s, _NEG_INF)
-            m_prev = m_ref[h]
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(s, axis=-1, keepdims=True))
-            pr = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[h] = l_ref[h] * alpha + jnp.sum(pr, axis=-1,
-                                                  keepdims=True)
-            if quantized:
-                pr = pr * vs_ref[h, 0]
-            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-                pr, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[h] = m_new
-
-    @pl.when(p == max_pages - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
 def paged_verify_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                            v_pages: jnp.ndarray,
                            block_tables: jnp.ndarray,
@@ -799,60 +915,13 @@ def paged_verify_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     Returns [slots, R, hkv, group, hd] fp32.
     """
     slots, R, hkv, group, hd = q.shape
-    page_size = k_pages.shape[2]
-    max_pages = block_tables.shape[1]
-    if sm_scale is None:
-        sm_scale = hd ** -0.5
-    interpret = _interpret_default(interpret)
     # [slots, hkv, R*group, hd], group fastest: row r is query
     # r // group — same flattening rule as the prefill kernel.
     qf = q.transpose(0, 2, 1, 3, 4).reshape(slots, hkv, R * group, hd)
-
-    quantized = k_scales is not None
-
-    def _page_index(b, p, tables, lengths_):
-        # Same revisiting-block rule as decode: steps past the slot's
-        # attendable pages re-map to its last real page (no DMA).
-        n_pages = jax.lax.div(lengths_[b] + R + page_size - 1,
-                              page_size)
-        j = jnp.minimum(p, jnp.maximum(n_pages - 1, 0))
-        j = jnp.minimum(j, max_pages - 1)
-        return (0, tables[b, j], 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, hkv, R * group, hd),
-                     lambda b, p, *_: (b, 0, 0, 0)),
-        pl.BlockSpec((hkv, 1, page_size, hd), _page_index),
-        pl.BlockSpec((hkv, 1, page_size, hd), _page_index),
-    ]
-    operands = [qf, k_pages, v_pages]
-    if quantized:
-        in_specs += [pl.BlockSpec((hkv, 1, 1, page_size), _page_index)] * 2
-        operands += [_scale_rows(k_scales), _scale_rows(v_scales)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(slots, max_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, hkv, R * group, hd),
-                               lambda b, p, *_: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((hkv, R * group, hd), jnp.float32),
-            pltpu.VMEM((hkv, R * group, 1), jnp.float32),
-            pltpu.VMEM((hkv, R * group, 1), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_verify_kernel, page_size=page_size,
-                               sm_scale=sm_scale, max_pages=max_pages,
-                               hkv=hkv, group=group, r_queries=R,
-                               quantized=quantized)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, hkv, R * group, hd),
-                                       jnp.float32),
-        interpret=interpret,
-        name='paged_verify_attention',
-    )(block_tables, lengths, *operands)
+    out = _paged_queries(
+        qf, k_pages, v_pages, block_tables, lengths + 1, group=group,
+        sm_scale=sm_scale, interpret=interpret, k_scales=k_scales,
+        v_scales=v_scales, name='paged_verify_attention')
     return out.reshape(slots, hkv, R, group, hd).transpose(0, 2, 1, 3, 4)
 
 

@@ -304,17 +304,20 @@ def _reference(params, cache, runs):
     rows each taking R tokens at positions ``lengths + i``: written
     row by row (past the table's coverage: page 0), then attended
     causally through ``paged_verify_attention_reference``. A decode
-    step is R = 1, a prefill chunk one row with R = C. Returns the
-    logits of every run ([n, R, vocab]) and the logical pool."""
+    step is R = 1, a prefill chunk one row with R = C; a run may name
+    as a fourth entry the rows that are ``active``: the decode programs
+    attend nothing in the others (their K/V row is still written, their
+    attention output is zeros). Returns the logits of every run
+    ([n, R, vocab]) and the logical pool."""
     k_all, v_all, ks_all, vs_all = _logical(cache)
     quantized = ks_all is not None
     cos, sin = rope_lib.rope_frequencies(HD, CFG.max_seq_len,
                                          CFG.rope_theta)
     hq, group = CFG.n_heads, CFG.n_heads // HKV
-    xs = [quant_lib.qembed(params['embed'], toks) for _, _, toks in runs]
+    xs = [quant_lib.qembed(params['embed'], run[2]) for run in runs]
     for layer_idx in range(CFG.n_layers):
         layer = jax.tree.map(lambda a: a[layer_idx], params['layers'])
-        for r, (tables, lengths, toks) in enumerate(runs):
+        for r, (tables, lengths, toks, *active) in enumerate(runs):
             n, R = toks.shape
             x = xs[r]
             positions = lengths[:, None] + np.arange(R)[None, :]
@@ -347,6 +350,8 @@ def _reference(params, cache, runs):
                 jnp.asarray(v_all[layer_idx]), jnp.asarray(tables),
                 jnp.asarray(lengths), **scales)
             att = att.reshape(n, R, hq * HD).astype(x.dtype)
+            if active:
+                att = jnp.where(active[0][:, None, None], att, 0)
             x = x + quant_lib.qdot(att, layer['wo'])
             xs[r] = llama.mlp_block(CFG, x, layer)
     logits = [quant_lib.qdot(
@@ -367,7 +372,7 @@ def _runs(name, args):
                 lambda lg: lg[0][0, int(true_len) - 1])
     if name == 'decode':
         tables, toks, _ = args
-        return ([(TABLES, LENGTHS, np.asarray(toks)[:, None])],
+        return ([(TABLES, LENGTHS, np.asarray(toks)[:, None], ACTIVE)],
                 lambda lg: lg[0][:, 0])
     if name == 'verify':
         tables, toks = args
@@ -376,7 +381,7 @@ def _runs(name, args):
     mid = LENGTHS.copy()
     mid[CHUNK_SLOT] = int(offset) + int(true_len)
     return ([chunk_run(row, toks, int(offset)),
-             (TABLES, mid, np.asarray(dtoks)[:, None])],
+             (TABLES, mid, np.asarray(dtoks)[:, None], ACTIVE)],
             lambda lg: (lg[0][0, int(true_len) - 1], lg[1][:, 0]))
 
 
@@ -420,7 +425,7 @@ def test_logits_and_pool_match_the_per_layer_reference(name, flavor,
     # The step wrote something in every layer, and only a few rows.
     for layer_idx in range(CFG.n_layers):
         changed = (got[0][layer_idx] != before[0][layer_idx]).any(-1)
-        rows = sum(int(np.prod(t.shape)) for _, _, t in runs)
+        rows = sum(int(np.prod(run[2].shape)) for run in runs)
         assert 0 < changed.any(0).sum() <= rows, (layer_idx, rows)
 
 
