@@ -96,6 +96,14 @@ class EngineConfig:
     # engine step.
     prefill_chunk: int = 256
     prefill_chunks_per_step: int = 4
+    # Paged only: a prompt's last chunk pads to the smallest of a ladder
+    # of buckets under the chunk (page, 2 x page, ...), one compiled
+    # prefill program a bucket. False: every chunk pads to the chunk
+    # itself, ONE prefill program. For a model whose chunk program is
+    # dear to compile and whose prompts are many chunks long: half a
+    # chunk of padding a prompt against a start-up of one program, not
+    # one a bucket.
+    prefill_tail_buckets: bool = True
     # Fused mixed steps (docs/serving.md "Fused mixed steps"): while
     # any slot is decoding, ONE prefill chunk rides the decode
     # dispatch as a single fused device program (model.mixed_step /
@@ -531,6 +539,7 @@ class InferenceEngine:
                 params = quant_lib.quantize_params(params)
         self.params = params
         self.allocator: Optional[paged_cache_lib.PageAllocator] = None
+        self.window_alloc: Optional[paged_cache_lib.WindowAllocator] = None
         if self.ecfg.paged:
             if self.ecfg.tp > 1:
                 raise ValueError(
@@ -555,6 +564,8 @@ class InferenceEngine:
             self._buckets = sorted(
                 {b for b in self._buckets if b % page == 0}
                 | ladder | {self._chunk_cap})
+            if not self.ecfg.prefill_tail_buckets:
+                self._buckets = [self._chunk_cap]
             max_pages_per_slot = self.ecfg.max_seq_len // page
             n_pages = self.ecfg.n_pages
             if n_pages is None:
@@ -572,8 +583,17 @@ class InferenceEngine:
                     f'{self.ecfg.kv_dtype!r}')
             kv_dtype = (jnp.int8 if self.ecfg.kv_dtype == 'int8'
                         else jnp.dtype(self.ecfg.cache_dtype))
+            extra = {}
+            if spec.latent is not None and spec.latent.window_layers:
+                # Window layers keep their rows in a pool of their own,
+                # bounded by the window and one chunk a slot whatever
+                # the contexts are (paged_cache.WindowAllocator).
+                self.window_alloc = paged_cache_lib.WindowAllocator(
+                    page, self.ecfg.n_slots, max_pages_per_slot,
+                    spec.latent.window, self._chunk_cap)
+                extra['window_pages'] = self.window_alloc.n_pages
             self.cache = steps.init_cache(
-                spec, self.ecfg.n_slots, n_pages, page, kv_dtype)
+                spec, self.ecfg.n_slots, n_pages, page, kv_dtype, **extra)
         else:
             if self.ecfg.kv_dtype not in ('bfloat16',):
                 raise ValueError(
@@ -1452,6 +1472,10 @@ class InferenceEngine:
                     self.prefix.tokens_saved -= just_attached
                 return None
             table_row = jnp.asarray(self.allocator.table()[slot])
+            if self.window_alloc is not None:
+                self.window_alloc.cover(slot, off, off + bucket)
+                table_row = (table_row, jnp.asarray(
+                    self.window_alloc.table()[slot]))
         else:
             table_row = None
         padded = np.zeros((bucket,), np.int32)
@@ -1547,6 +1571,8 @@ class InferenceEngine:
         in program order) in the cache."""
         if self.allocator is None:
             return
+        if self.window_alloc is not None:
+            self.window_alloc.free(slot)
         self._attached_slots.discard(slot)
         if self.prefix is None or not self.allocator.pages_of(slot):
             self.allocator.free(slot)
@@ -1797,6 +1823,11 @@ class InferenceEngine:
         # Drains above may have finished/preempted slots validated
         # earlier in the walk — only currently-decoding slots may ride
         # into the dispatch's active mask.
+        if self.window_alloc is not None:
+            for slot in decoding:
+                if self._slots[slot] is not None:
+                    self.window_alloc.cover(
+                        slot, int(self._slot_len[slot]), target(slot))
         return [s for s in decoding
                 if self._slots[s] is not None
                 and s not in self._prefilling]
@@ -2053,10 +2084,16 @@ class InferenceEngine:
             active_mask[decoding] = True
             self._active_dev = jnp.asarray(active_mask)
             self._active_key = key
-        if (self.allocator is not None
-                and self._table_version != self.allocator.version):
-            self._table_dev = jnp.asarray(self.allocator.table())
-            self._table_version = self.allocator.version
+        if self.allocator is not None:
+            version = self.allocator.version
+            if self.window_alloc is not None:
+                version = (version, self.window_alloc.version)
+            if self._table_version != version:
+                self._table_dev = jnp.asarray(self.allocator.table())
+                if self.window_alloc is not None:
+                    self._table_dev = (self._table_dev, jnp.asarray(
+                        self.window_alloc.table()))
+                self._table_version = version
 
     def _dispatch_decode(self, decoding: List[int],
                          just_prefilled: List[int]) -> None:
@@ -2910,6 +2947,12 @@ class InferenceEngine:
             **({'state_bytes': self.cache.state_bytes,
                 'state_slots': c['num_active']}
                if self._state_spec is not None else {}),
+            # Window layers (paged_cache.WindowAllocator): their pool,
+            # and the rows live slots hold of it right now.
+            **({'window_pages_total': self.window_alloc.n_pages,
+                'window_rows_held': self.window_alloc.rows_held(),
+                'window_bytes': self.cache.window_bytes}
+               if self.window_alloc is not None else {}),
             **c['model_counters'],
             **prefix_stats,
         }

@@ -351,6 +351,101 @@ class PageAllocator:
         return sum(len(o) for o in self._owned) * self.page_size
 
 
+def window_pages_behind(window: int, page_size: int) -> int:
+    """Whole pages that rows a window still reaches can lie in, behind
+    the page of the first row written: a query at a page's first row
+    sees ``window - 1`` rows before it."""
+    return -(-(window - 1) // page_size)
+
+
+class WindowAllocator:
+    """Pages of a pool whose layers attend to a WINDOW: a slot holds
+    the pages its next queries can still reach and nothing behind
+    them, so the pool is bounded by ``n_slots x (window + one write)``
+    whatever the contexts are.
+
+    The block table is indexed by LOGICAL page (position // page_size)
+    as ``PageAllocator``'s is, so a program finds a position's page
+    the same way in both; a page behind the window reads 0, the sink.
+    ``cover(slot, start, end)`` is the one mutation while a request
+    runs: rows ``[start, end)`` are about to be written, and the
+    oldest query of that write sees back to ``start - (window - 1)``.
+    It frees what lies wholly before that and hands out what the
+    write needs. The pool is sized so that it cannot fail: every slot
+    may hold ``pages_behind + max_write // page_size + 1`` pages at
+    once. Host only, engine thread only, as ``PageAllocator``.
+    """
+
+    _GUARDED_BY = {'_spare': 'owner', '_held': 'owner',
+                   '_first': 'owner', '_map': 'owner'}
+
+    def __init__(self, page_size: int, n_slots: int,
+                 max_pages_per_slot: int, window: int,
+                 max_write: int) -> None:
+        self.page_size = page_size
+        self.window = window
+        self.behind = window_pages_behind(window, page_size)
+        self.per_slot = self.behind + -(-max_write // page_size) + 1
+        self.n_pages = n_slots * self.per_slot + 1     # page 0: the sink
+        self._spare: List[int] = list(range(self.n_pages - 1, 0, -1))
+        # A slot's pages are those of logical pages
+        # [_first, _first + len(_held)).
+        self._held: List[List[int]] = [[] for _ in range(n_slots)]
+        self._first = [0] * n_slots
+        self._map = np.zeros((n_slots, max_pages_per_slot), np.int32)
+        self.version = 0
+
+    @property
+    def free_pages(self) -> int:
+        return len(self._spare)
+
+    def pages_of(self, slot: int) -> int:
+        return len(self._held[slot])
+
+    def rows_held(self) -> int:
+        """Rows that live slots hold, whole pages (observability)."""
+        return sum(len(o) for o in self._held) * self.page_size
+
+    def table(self) -> np.ndarray:
+        return self._map.copy()
+
+    def cover(self, slot: int, start: int, end: int) -> None:
+        page = self.page_size
+        keep_from = max(start - (self.window - 1), 0) // page
+        upto = -(-end // page)
+        owned, first = self._held[slot], self._first[slot]
+        if not owned:
+            first = keep_from
+        drop = min(max(keep_from - first, 0), len(owned))
+        for i in range(drop):
+            self._spare.append(owned[i])
+            self._map[slot, first + i] = 0
+        del owned[:drop]
+        first += drop
+        if not owned:
+            first = keep_from
+        need = upto - (first + len(owned))
+        assert need <= len(self._spare), (
+            f'window pool dry: slot {slot} needs {need} pages for rows '
+            f'[{start}, {end}), {len(self._spare)} free')
+        for _ in range(max(need, 0)):
+            pid = self._spare.pop()
+            self._map[slot, first + len(owned)] = pid
+            owned.append(pid)
+        self._first[slot] = first
+        if drop or need > 0:
+            self.version += 1
+
+    def free(self, slot: int) -> None:
+        owned, first = self._held[slot], self._first[slot]
+        if owned:
+            self.version += 1
+            self._spare.extend(reversed(owned))
+            self._map[slot, first:first + len(owned)] = 0
+        self._held[slot] = []
+        self._first[slot] = 0
+
+
 def free_slot(cache: PagedKVCache, slot: int) -> PagedKVCache:
     """Device half of freeing: zero the slot's length (the allocator's
     ``free`` is the host half)."""

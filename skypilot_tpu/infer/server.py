@@ -59,7 +59,19 @@ MODELS = {
     'nemotron-h-tiny': nemotron_h.NemotronHConfig.tiny,
     'nemotron-3-nano-30b-a3b-ep2':
         nemotron_h.NemotronHConfig.nano_30b_a3b_ep2,
+    # dots3-note (latent attention of two kinds, a learned top-k
+    # selection, gated experts; models/dots3.py): the CPU-test preset,
+    # and dots3-note-prev as one of eight chips that share each layer
+    # by expert parallelism (blocks 0-4, 32 of 256 experts, an eighth
+    # of the vocabulary). The module is imported when one is asked for.
+    'dots3-tiny': lambda **kw: _dots3().tiny(**kw),
+    'dots3-note-prev-ep8': lambda **kw: _dots3().note_prev_ep8(**kw),
 }
+
+
+def _dots3():
+    from skypilot_tpu.models import dots3
+    return dots3.Dots3Config
 
 
 class Tokenizer:

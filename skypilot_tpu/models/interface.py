@@ -6,8 +6,10 @@ configuration in place of ``llama.LlamaConfig``:
 - ``max_seq_len``, ``vocab_size``: plain attributes;
 - ``cache_spec()``: what kinds of per-request state the model's layers
   keep, kind by kind (``CacheSpec``): how many layers write K/V pages
-  and their head geometry, and how many keep recurrent per-slot state
-  and its shapes. The engine builds the cache from it and nothing else;
+  and their head geometry, how many keep recurrent per-slot state and
+  its shapes, and how many keep LATENT rows (one row a token in place
+  of K and V per head), growing with the context or bounded by a
+  window. The engine builds the cache from it and nothing else;
 - ``serving_refusals()``: engine switches the model cannot run with,
   each with its reason (a model with none need not define it);
 - ``paged_steps()``: its step programs (``infer/model.PagedSteps``),
@@ -37,11 +39,29 @@ class StateSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentSpec:
+    """Latent-attention layers: ONE row a token and layer, shared by
+    every head. ``full`` layers keep every row of the context (and,
+    where ``index_row`` is not 0, an indexer key beside it, in a pool
+    of its own); ``window`` layers attend to the last ``window``
+    positions, the query's own included, and keep no row behind that:
+    their pool is bounded by the window and a prefill chunk a slot,
+    whatever the context."""
+    full_layers: int
+    full_row: int                   # values a row: latent | rope key
+    index_row: int                  # the indexer's key (0: none)
+    window_layers: int = 0
+    window_row: int = 0
+    window: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
 class CacheSpec:
     kv_layers: int                  # layers that write K/V pages
     n_kv_heads: int
     head_dim: int
     state: Optional[StateSpec] = None
+    latent: Optional[LatentSpec] = None
 
 
 def cache_spec(config: Any) -> CacheSpec:

@@ -85,13 +85,16 @@ def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
 def local_experts(h: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
                   w_up: jnp.ndarray, w_down: jnp.ndarray,
                   valid: Optional[jnp.ndarray] = None, offset: int = 0,
-                  *, impl: str = 'auto', interpret: bool = False
+                  *, w_gate: Optional[jnp.ndarray] = None,
+                  impl: str = 'auto', interpret: bool = False
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The held experts' part of ``sum_chosen w_e * relu(h @ U_e)**2 @
-    D_e``. h ``[T, d]``; idx / w ``[T, k]`` from ``route``; w_up and
-    w_down ``[held, f, d]`` (experts ``offset .. offset + held``);
-    valid ``[T]`` bool: rows that are padding touch no expert. Returns
-    (``[T, d]`` float32, the ``STATS`` counts ``[3]`` int32)."""
+    D_e`` or, given ``w_gate`` (the gated form), of ``sum_chosen w_e *
+    (silu(h @ G_e) * (h @ U_e)) @ D_e``. h ``[T, d]``; idx / w ``[T,
+    k]`` from ``route``; w_up, w_down and w_gate ``[held, f, d]``
+    (experts ``offset .. offset + held``); valid ``[T]`` bool: rows
+    that are padding touch no expert. Returns (``[T, d]`` float32, the
+    ``STATS`` counts ``[3]`` int32)."""
     T, d = h.shape
     k, held = idx.shape[1], w_up.shape[0]
     local = (idx >= offset) & (idx < offset + held)
@@ -111,8 +114,14 @@ def local_experts(h: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
     xs = jnp.where(rows[:, None], h[token], 0)
     up = grouped_matmul(xs, w_up, sizes, transpose_rhs=True, impl=impl,
                         interpret=interpret)
-    act = jnp.square(jax.nn.relu(jnp.where(rows[:, None], up, 0)
-                                 .astype(jnp.float32))).astype(h.dtype)
+    up = jnp.where(rows[:, None], up, 0).astype(jnp.float32)
+    if w_gate is None:
+        act = jnp.square(jax.nn.relu(up)).astype(h.dtype)
+    else:
+        gate = grouped_matmul(xs, w_gate, sizes, transpose_rhs=True,
+                              impl=impl, interpret=interpret)
+        gate = jnp.where(rows[:, None], gate, 0).astype(jnp.float32)
+        act = (jax.nn.silu(gate) * up).astype(h.dtype)
     down = grouped_matmul(act, w_down, sizes, transpose_rhs=False,
                           impl=impl, interpret=interpret)
     down = jnp.where(rows[:, None], down, 0).astype(jnp.float32)

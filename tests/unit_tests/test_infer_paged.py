@@ -350,6 +350,25 @@ def test_paged_engine_matches_dense_greedy():
     assert m['pages_free'] == m['pages_total'] - 1
 
 
+def test_one_prefill_program_when_tails_pad_to_the_chunk():
+    """``prefill_tail_buckets=False``: every chunk pads to the chunk,
+    so ONE prefill program compiles whatever the prompts' lengths, and
+    the tokens are those of the engine with the ladder of buckets."""
+    _, ladder = _engines()
+    _, single = _engines(prefill_tail_buckets=False)
+    assert ladder._buckets == [16, 32] and single._buckets == [32]
+    prompts = [[5, 17, 101, 7], [9, 8, 7, 6, 5, 4, 3],
+               [(i * 7 + 3) % 250 for i in range(40)],   # a chunk and a tail
+               [(i * 5 + 1) % 250 for i in range(64)]]   # whole chunks
+    out = [[r.output_tokens for r in e.generate(prompts, max_new_tokens=8)]
+           for e in (ladder, single)]
+    assert out[0] == out[1]
+    assert ladder.compiled_counts()['prefill'] == 2
+    assert single.compiled_counts()['prefill'] == 1
+    al = single.allocator
+    assert al.free_pages == al.n_pages - 1
+
+
 def test_paged_engine_pool_reads_back_by_layer_and_page():
     """``gather_pages`` is the logical view of the folded pool: while a
     request holds pages, every layer shows rows in exactly those pages
