@@ -63,6 +63,30 @@ from skypilot_tpu.ops import quant as quant_lib
 from skypilot_tpu.ops import rope as rope_lib
 
 
+def _qkv(config, h, layer, cos, sin, positions):
+    """The q, k and v projections of one dense-block layer, q and k
+    rotated. h: [B, T, d] (normed); positions: [B, T]. Returns q
+    [B, T, hq, hd], k and v [B, T, hkv, hd].
+
+    The projections' results are held as the dot leaves them, flat
+    ``[B, T, heads * hd]``, before anything reshapes them. Without the
+    barrier the TPU compiler fuses the consumer (rope; for ``v`` the
+    heads-major page write) into the dot's output, wants that output
+    heads-major, and gets it by re-laying the WEIGHT: a slice of the
+    layer's 16 MB ``wq`` (4 MB ``wk``, ``wv``) into fast memory and a
+    transposing copy of it, every layer of every step (17% of a decode
+    step, PERF.md section 6, PR 32). Held, each weight is read once
+    where it lies and it is the activation, a few KB to 3 MB, that is
+    re-laid. No shape this block serves wants the other choice."""
+    B, T, _ = h.shape
+    hq, hkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    q, k, v = jax.lax.optimization_barrier(
+        tuple(quant_lib.qdot(h, layer[w]) for w in ('wq', 'wk', 'wv')))
+    q = rope_lib.apply_rope(q.reshape(B, T, hq, hd), cos, sin, positions)
+    k = rope_lib.apply_rope(k.reshape(B, T, hkv, hd), cos, sin, positions)
+    return q, k, v.reshape(B, T, hkv, hd)
+
+
 def prefill_chunk(config: llama.LlamaConfig, params: llama.Params,
                   kv: cache_lib.KVCache, slot: jnp.ndarray,
                   tokens: jnp.ndarray, offset: jnp.ndarray,
@@ -124,11 +148,7 @@ def _chunk_layer(config, x, layer, cos, sin, k_cache, v_cache, slot,
     group = hq // hkv
 
     h = norms.rms_norm(x, layer['attn_norm'], config.norm_eps)
-    q = quant_lib.qdot(h, layer['wq']).reshape(1, C, hq, hd)
-    k = quant_lib.qdot(h, layer['wk']).reshape(1, C, hkv, hd)
-    v = quant_lib.qdot(h, layer['wv']).reshape(1, C, hkv, hd)
-    q = rope_lib.apply_rope(q, cos, sin, positions[None])
-    k = rope_lib.apply_rope(k, cos, sin, positions[None])
+    q, k, v = _qkv(config, h, layer, cos, sin, positions[None])
 
     # Write the chunk's K/V into the slot FIRST, then attend over the
     # cache — the chunk sees itself through the causal mask.
@@ -240,11 +260,7 @@ def _paged_chunk_layer(config, x, layer, cos, sin, pkv, table_row,
 
     with jax.named_scope('attn'):
         h = norms.rms_norm(x, layer['attn_norm'], config.norm_eps)
-        q = quant_lib.qdot(h, layer['wq']).reshape(1, C, hq, hd)
-        k = quant_lib.qdot(h, layer['wk']).reshape(1, C, hkv, hd)
-        v = quant_lib.qdot(h, layer['wv']).reshape(1, C, hkv, hd)
-        q = rope_lib.apply_rope(q, cos, sin, positions[None])
-        k = rope_lib.apply_rope(k, cos, sin, positions[None])
+        q, k, v = _qkv(config, h, layer, cos, sin, positions[None])
 
     # Write-then-attend, page edition (quant-on-write on int8 pages:
     # the chunk's own self-attention reads its rows back dequantized,
@@ -326,11 +342,7 @@ def _paged_decode_layer(config, x, layer, cos, sin, pkv, block_tables,
 
     with jax.named_scope('attn'):
         h = norms.rms_norm(x, layer['attn_norm'], config.norm_eps)
-        q = quant_lib.qdot(h, layer['wq']).reshape(slots, 1, hq, hd)
-        k = quant_lib.qdot(h, layer['wk']).reshape(slots, 1, hkv, hd)
-        v = quant_lib.qdot(h, layer['wv']).reshape(slots, 1, hkv, hd)
-        q = rope_lib.apply_rope(q, cos, sin, positions[:, None])
-        k = rope_lib.apply_rope(k, cos, sin, positions[:, None])
+        q, k, v = _qkv(config, h, layer, cos, sin, positions[:, None])
 
     # Write the new K/V into the slot's current page, then attend over
     # positions <= length (the new token sees itself).
@@ -406,11 +418,7 @@ def _verify_layer(config, x, layer, cos, sin, k_cache, v_cache,
     group = hq // hkv
 
     h = norms.rms_norm(x, layer['attn_norm'], config.norm_eps)
-    q = quant_lib.qdot(h, layer['wq']).reshape(slots, R, hq, hd)
-    k = quant_lib.qdot(h, layer['wk']).reshape(slots, R, hkv, hd)
-    v = quant_lib.qdot(h, layer['wv']).reshape(slots, R, hkv, hd)
-    q = rope_lib.apply_rope(q, cos, sin, positions)
-    k = rope_lib.apply_rope(k, cos, sin, positions)
+    q, k, v = _qkv(config, h, layer, cos, sin, positions)
 
     k_cache, v_cache = cache_lib.append_run(
         k_cache, v_cache, k, v, positions[:, 0])
@@ -474,11 +482,7 @@ def _paged_verify_layer(config, x, layer, cos, sin, pkv, block_tables,
 
     with jax.named_scope('attn'):
         h = norms.rms_norm(x, layer['attn_norm'], config.norm_eps)
-        q = quant_lib.qdot(h, layer['wq']).reshape(slots, R, hq, hd)
-        k = quant_lib.qdot(h, layer['wk']).reshape(slots, R, hkv, hd)
-        v = quant_lib.qdot(h, layer['wv']).reshape(slots, R, hkv, hd)
-        q = rope_lib.apply_rope(q, cos, sin, positions)
-        k = rope_lib.apply_rope(k, cos, sin, positions)
+        q, k, v = _qkv(config, h, layer, cos, sin, positions)
 
     # Write-then-attend, run edition (sink-redirected past coverage).
     with jax.named_scope('kv_write'):
@@ -547,11 +551,7 @@ def _decode_layer(config, x, layer, cos, sin, k_cache, v_cache,
     group = hq // hkv
 
     h = norms.rms_norm(x, layer['attn_norm'], config.norm_eps)
-    q = quant_lib.qdot(h, layer['wq']).reshape(slots, 1, hq, hd)
-    k = quant_lib.qdot(h, layer['wk']).reshape(slots, 1, hkv, hd)
-    v = quant_lib.qdot(h, layer['wv']).reshape(slots, 1, hkv, hd)
-    q = rope_lib.apply_rope(q, cos, sin, positions[:, None])
-    k = rope_lib.apply_rope(k, cos, sin, positions[:, None])
+    q, k, v = _qkv(config, h, layer, cos, sin, positions[:, None])
 
     # Write the new K/V into the cache FIRST, then attend over the cache —
     # the new token sees itself through the mask (pos <= length).
