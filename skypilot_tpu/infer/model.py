@@ -30,15 +30,17 @@ into the pages) and ``mlp``, then ``head``; the sampler adds
 its metadata, by which a profiler trace's device events can be
 grouped.
 
-**Heterogeneous stacks** (``models/nemotron_h.py``: Mamba-2, expert and
-attention blocks in one model) have their own two paged programs at
-the end of this file, ``hybrid_prefill_chunk`` and
-``hybrid_decode_step``, over ``state_cache.HybridCache``: the page pool
-of the attention layers AND the per-slot recurrent state of the
-state-space layers ride the step as donated carries, each layer
-rewriting its own rows in place. Their scopes are ``ssm`` (the
-Mamba-2 mixer), ``attn`` / ``kv_write`` (as above), ``moe.route`` /
-``moe.experts`` / ``moe.shared``. ``paged_steps(config)`` hands the
+**Stacks that keep recurrent state** (``models/nemotron_h.py``: Mamba-2,
+expert and attention blocks in one model, one mixer a block;
+``models/falcon_h1.py``: attention AND a Mamba-2 mixer in every block)
+share two paged programs at the end of this file,
+``hybrid_prefill_chunk`` and ``hybrid_decode_step``, over
+``state_cache.HybridCache``: the page pool of the layers that attend
+AND the per-slot recurrent state of the state-space layers ride the
+step as donated carries, each layer rewriting its own rows in place.
+Their scopes are ``ssm`` (the Mamba-2 mixer), ``attn`` / ``kv_write``
+/ ``mlp`` (as above), ``moe.route`` / ``moe.experts`` /
+``moe.shared``. ``paged_steps(config)`` hands the
 engine the programs of a configuration's family: the serving half of
 the model interface (``models/interface.py``).
 """
@@ -54,6 +56,7 @@ import jax.numpy as jnp
 from skypilot_tpu.infer import cache as cache_lib
 from skypilot_tpu.infer import paged_cache as paged_cache_lib
 from skypilot_tpu.infer import state_cache as state_cache_lib
+from skypilot_tpu.models import falcon_h1
 from skypilot_tpu.models import interface
 from skypilot_tpu.models import llama
 from skypilot_tpu.models import nemotron_h
@@ -701,36 +704,32 @@ def paged_mixed_step(config: llama.LlamaConfig, params: llama.Params,
 # Heterogeneous stacks: recurrent state beside the page pool
 
 # What a hybrid decode step counts, summed over its layers (int32):
-# the expert layer's ``moe_dropless.STATS`` and the slots whose
-# recurrent state the step advanced.
+# the expert layer's ``moe_dropless.STATS`` (a model with ``E`` blocks
+# only) and the slots whose recurrent state the step advanced.
 HYBRID_STEP_STATS = ('moe_local_assignments', 'moe_experts_touched',
                      'moe_expert_load_max', 'ssm_slot_steps')
 
 
-def _hybrid_attn_chunk(config, x, layer, kv, table_row, offset, true_len):
-    """A ``*`` block over one chunk. kv: the folded pool of the
-    attention layers; table_row: this layer's PHYSICAL pages; x [C, d]."""
-    C = x.shape[0]
-    with jax.named_scope('attn'):
-        q, k, v = nemotron_h.attn_qkv(config, layer, x)
+def _state_attn_chunk(q, k, v, kv, table_row, offset, true_len):
+    """The cache's half of a state family's attention over one chunk:
+    the chunk's K/V rows into this layer's pages, then the paged
+    prefill kernel. q / k / v: the family's projections (its norm, its
+    rope or none: ``nemotron_h.attn_qkv``, ``falcon_h1.attn_qkv``);
+    kv: the folded pool of the layers that page K/V; table_row: this
+    layer's PHYSICAL pages. Returns (att ``[C, hq*hd]``, kv)."""
     with jax.named_scope('kv_write'):
         kv = _with_pages(kv, paged_attn.write_chunk_pages(
             kv.k_pages, kv.v_pages, k, v, table_row, offset, None, None))
     with jax.named_scope('attn'):
         att = paged_attn.paged_prefill_attention(
             q, kv.k_pages, kv.v_pages, table_row, offset, true_len)
-        att = att.reshape(C, -1).astype(x.dtype)
-        x = x + jnp.dot(att, layer['wo'])
-    return x, kv
+        return att.reshape(q.shape[0], -1), kv
 
 
-def _hybrid_attn_decode(config, x, layer, kv, block_tables, positions,
-                        attend, sink_page):
-    """A ``*`` block for one token of every slot; x [slots, d];
-    attend: `_attended`."""
-    slots = x.shape[0]
-    with jax.named_scope('attn'):
-        q, k, v = nemotron_h.attn_qkv(config, layer, x)
+def _state_attn_decode(q, k, v, kv, block_tables, positions, attend,
+                       sink_page):
+    """``_state_attn_chunk`` for one token of every slot; attend:
+    `_attended`. Returns (att ``[slots, hq*hd]``, kv)."""
     with jax.named_scope('kv_write'):
         kv = _with_pages(kv, paged_attn.append_token_pages(
             kv.k_pages, kv.v_pages, k, v, block_tables, positions, None,
@@ -738,32 +737,35 @@ def _hybrid_attn_decode(config, x, layer, kv, block_tables, positions,
     with jax.named_scope('attn'):
         att = paged_attn.paged_decode_attention(
             q, kv.k_pages, kv.v_pages, block_tables, attend)
-        att = att.reshape(slots, -1)
-        x = x + jnp.dot(att, layer['wo'])
-    return x, kv
+        return att.reshape(q.shape[0], -1), kv
 
 
-def hybrid_prefill_chunk(config: nemotron_h.NemotronHConfig,
-                         params: nemotron_h.Params,
+def hybrid_prefill_chunk(config: Any, params: Any,
                          cache: state_cache_lib.HybridCache,
                          slot: jnp.ndarray, table_row: jnp.ndarray,
                          tokens: jnp.ndarray, offset: jnp.ndarray,
                          true_len: jnp.ndarray
                          ) -> Tuple[state_cache_lib.HybridCache,
                                     jnp.ndarray]:
-    """``paged_prefill_chunk``'s contract over a heterogeneous stack.
+    """``paged_prefill_chunk``'s contract over a stack that keeps
+    recurrent state: ``nemotron_h``'s (one mixer a block: ``M`` | ``E``
+    | ``*``) or ``falcon_h1``'s (``P``: attention and a Mamba-2 mixer
+    side by side on one norm's output, then a gated MLP).
 
-    Beside the K/V rows of the attention layers, the chunk carries the
-    slot's recurrent state forward: each ``M`` layer starts from the
-    slot's state (zero when ``offset`` is 0: a prefill from the start
-    IS the reset) and leaves the state as it stands after token
+    Beside the K/V rows of the layers that attend, the chunk carries
+    the slot's recurrent state forward: each state layer starts from
+    the slot's state (zero when ``offset`` is 0: a prefill from the
+    start IS the reset) and leaves the state as it stands after token
     ``true_len - 1``. The padded tail advances nothing: not the SSM
     state, not the convolution's window, and it reaches no expert."""
     C = tokens.shape[0]
     with jax.named_scope('embed'):
-        x = params['embed'][tokens]                       # [C, d]
+        x = config.embed(params, tokens)                  # [C, d]
     valid = jnp.arange(C, dtype=jnp.int32) < true_len
     kv = cache.kv
+    if config.count('P'):
+        rope = falcon_h1.rope_at(config,
+                                 offset + jnp.arange(C, dtype=jnp.int32))
     for kind, i in config.layers():
         layer = params['layers'][kind][i]
         if kind == 'M':
@@ -777,35 +779,56 @@ def hybrid_prefill_chunk(config: nemotron_h.NemotronHConfig,
                                                         ssm, conv)
         elif kind == '*':
             row = paged_cache_lib.physical_pages(kv.n_pages, i, table_row)
-            x, kv = _hybrid_attn_chunk(config, x, layer, kv, row, offset,
-                                       true_len)
+            with jax.named_scope('attn'):
+                q, k, v = nemotron_h.attn_qkv(config, layer, x)
+            att, kv = _state_attn_chunk(q, k, v, kv, row, offset, true_len)
+            with jax.named_scope('attn'):
+                x = x + jnp.dot(att.astype(x.dtype), layer['wo'])
+        elif kind == 'P':
+            row = paged_cache_lib.physical_pages(kv.n_pages, i, table_row)
+            with jax.named_scope('attn'):
+                h = falcon_h1.block_norm(config, layer, x)
+                q, k, v = falcon_h1.attn_qkv(config, layer, h, rope)
+            att, kv = _state_attn_chunk(q, k, v, kv, row, offset, true_len)
+            with jax.named_scope('attn'):
+                a = falcon_h1.attn_out(config, layer, att.astype(x.dtype))
+            with jax.named_scope('ssm'):
+                ssm, conv = state_cache_lib.slot_state(cache, i, slot,
+                                                       offset)
+                s, ssm, conv = falcon_h1.ssm_chunk(config, layer, h, ssm,
+                                                   conv, true_len)
+                cache = state_cache_lib.with_slot_state(cache, i, slot,
+                                                        ssm, conv)
+                x = falcon_h1.mixed(x, a, s)
+            with jax.named_scope('mlp'):
+                x = falcon_h1.mlp(config, layer, x)
         else:
             y, _ = nemotron_h.moe_mixer(config, layer, x, valid)
             x = x + y
     with jax.named_scope('head'):
         last = jax.lax.dynamic_index_in_dim(x, true_len - 1, axis=0,
                                             keepdims=False)
-        logits = nemotron_h.head(config, params, last)
+        logits = config.head(params, last)
     lengths = kv.lengths.at[slot].set((offset + true_len).astype(jnp.int32))
     return dataclasses.replace(
         cache, kv=dataclasses.replace(kv, lengths=lengths)), logits
 
 
-def hybrid_decode_step(config: nemotron_h.NemotronHConfig,
-                       params: nemotron_h.Params,
+def hybrid_decode_step(config: Any, params: Any,
                        cache: state_cache_lib.HybridCache,
                        block_tables: jnp.ndarray, tokens: jnp.ndarray,
                        active: Optional[jnp.ndarray] = None
                        ) -> Tuple[jnp.ndarray, state_cache_lib.HybridCache,
                                   jnp.ndarray]:
-    """``paged_decode_step``'s contract over a heterogeneous stack, and
-    a third result: the step's ``HYBRID_STEP_STATS`` counts.
+    """``paged_decode_step``'s contract over a stack that keeps
+    recurrent state (``hybrid_prefill_chunk`` names the two families),
+    and a third result: the step's counts, ``hybrid_steps(config).stats``.
 
     A slot that is not ``active`` (free, or mid-way through a chunked
     prefill) computes garbage, as in every decode program. Its K/V row
     lands where the next real write covers it; its recurrent state
     must not move at all, because nothing ever overwrites a state:
-    ``mamba_decode`` keeps it bit for bit. Nor does it reach an
+    ``mamba_mixer.decode`` keeps it bit for bit. Nor does it reach an
     expert."""
     slots = tokens.shape[0]
     if active is None:
@@ -814,10 +837,14 @@ def hybrid_decode_step(config: nemotron_h.NemotronHConfig,
     positions = kv.lengths
     attend = _attended(positions, active)
     with jax.named_scope('embed'):
-        x = params['embed'][tokens]                       # [slots, d]
+        x = config.embed(params, tokens)                  # [slots, d]
     moe_stats = jnp.zeros((3,), jnp.int32)
+    if config.count('P'):
+        rope = falcon_h1.rope_at(config, positions)
     for kind, i in config.layers():
         layer = params['layers'][kind][i]
+        physical = functools.partial(paged_cache_lib.physical_pages,
+                                     kv.n_pages, i)
         if kind == 'M':
             with jax.named_scope('ssm'):
                 y, ssm, conv = nemotron_h.mamba_decode(
@@ -826,20 +853,40 @@ def hybrid_decode_step(config: nemotron_h.NemotronHConfig,
                 cache = state_cache_lib.with_layer_state(cache, i, ssm,
                                                          conv)
         elif kind == '*':
-            physical = functools.partial(paged_cache_lib.physical_pages,
-                                         kv.n_pages, i)
-            x, kv = _hybrid_attn_decode(config, x, layer, kv,
-                                        physical(block_tables), positions,
-                                        attend, physical(0))
+            tables, sink = physical(block_tables), physical(0)
+            with jax.named_scope('attn'):
+                q, k, v = nemotron_h.attn_qkv(config, layer, x)
+            att, kv = _state_attn_decode(q, k, v, kv, tables, positions,
+                                         attend, sink)
+            with jax.named_scope('attn'):
+                x = x + jnp.dot(att, layer['wo'])
+        elif kind == 'P':
+            tables, sink = physical(block_tables), physical(0)
+            with jax.named_scope('attn'):
+                h = falcon_h1.block_norm(config, layer, x)
+                q, k, v = falcon_h1.attn_qkv(config, layer, h, rope)
+            att, kv = _state_attn_decode(q, k, v, kv, tables, positions,
+                                         attend, sink)
+            with jax.named_scope('attn'):
+                a = falcon_h1.attn_out(config, layer, att)
+            with jax.named_scope('ssm'):
+                s, ssm, conv = falcon_h1.ssm_decode(
+                    config, layer, h, cache.ssm[i], cache.conv[i], active)
+                cache = state_cache_lib.with_layer_state(cache, i, ssm,
+                                                         conv)
+                x = falcon_h1.mixed(x, a, s)
+            with jax.named_scope('mlp'):
+                x = falcon_h1.mlp(config, layer, x)
         else:
             y, stats = nemotron_h.moe_mixer(config, layer, x, active)
             x = x + y
             moe_stats = moe_stats + stats
     with jax.named_scope('head'):
-        logits = nemotron_h.head(config, params, x)
+        logits = config.head(params, x)
     lengths = kv.lengths + active.astype(kv.lengths.dtype)
-    stats = jnp.concatenate(
-        [moe_stats, jnp.sum(active, dtype=jnp.int32)[None]])
+    stats = jnp.sum(active, dtype=jnp.int32)[None]
+    if config.count('E'):
+        stats = jnp.concatenate([moe_stats, stats])
     return logits, dataclasses.replace(
         cache, kv=dataclasses.replace(kv, lengths=lengths)), stats
 
@@ -870,12 +917,15 @@ def _init_paged(spec: interface.CacheSpec, n_slots, n_pages, page, dtype):
         spec.head_dim, dtype=dtype)
 
 
-def hybrid_steps() -> PagedSteps:
-    """What ``NemotronHConfig.paged_steps()`` hands the engine."""
+def hybrid_steps(config: Any) -> PagedSteps:
+    """What the ``paged_steps()`` of a configuration whose layers keep
+    recurrent state hands the engine."""
     return PagedSteps(
         prefill_chunk=hybrid_prefill_chunk, decode=hybrid_decode_step,
         init_cache=state_cache_lib.init_hybrid_cache,
-        free_slot=state_cache_lib.free_slot, stats=HYBRID_STEP_STATS)
+        free_slot=state_cache_lib.free_slot,
+        stats=(HYBRID_STEP_STATS if config.count('E')
+               else HYBRID_STEP_STATS[-1:]))
 
 
 def paged_steps(config: Any) -> PagedSteps:

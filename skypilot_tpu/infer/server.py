@@ -37,6 +37,7 @@ from aiohttp import web
 
 from skypilot_tpu.infer import engine as engine_lib
 from skypilot_tpu.models import interface
+from skypilot_tpu.models import falcon_h1
 from skypilot_tpu.models import llama
 from skypilot_tpu.models import nemotron_h
 from skypilot_tpu.observability import prometheus as prom_lib
@@ -66,6 +67,13 @@ MODELS = {
     # of the vocabulary). The module is imported when one is asked for.
     'dots3-tiny': lambda **kw: _dots3().tiny(**kw),
     'dots3-note-prev-ep8': lambda **kw: _dots3().note_prev_ep8(**kw),
+    # Falcon-H1 (attention AND a Mamba-2 mixer in every block, a gated
+    # MLP, maximal-update multipliers; models/falcon_h1.py): the
+    # CPU-test preset, and Falcon-H1-34B-Instruct as one of the eight
+    # stages of a pipeline (9 of its 72 blocks, an eighth of the
+    # vocabulary). Paged only, refusals as the hybrid's.
+    'falcon-h1-tiny': falcon_h1.FalconH1Config.tiny,
+    'falcon-h1-34b-pp8': falcon_h1.FalconH1Config.h1_34b_pp8,
 }
 
 

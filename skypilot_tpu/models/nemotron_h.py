@@ -8,9 +8,11 @@ This file is the model's half of the serving interface
 (``models/interface.py``): the configuration with its per-kind cache
 spec, the parameter tree grouped by layer kind, its init, and the mixer
 bodies as pure functions of (activations, the layer's weights, the
-layer's state). ``infer/model.py`` walks the pattern and owns what
-touches the caches (the page pool of the ``*`` layers, the per-slot
-recurrent state of the ``M`` layers). There is no training half yet.
+layer's state); the Mamba-2 mixer's body is ``models/mamba_mixer.py``,
+shared with the other family that has one. ``infer/model.py`` walks
+the pattern and owns what touches the caches (the page pool of the
+``*`` layers, the per-slot recurrent state of the ``M`` layers). There
+is no training half yet.
 
 Parameters (``Params``): ``embed [vocab, d]``, ``final_norm [d]``,
 ``lm_head [d, vocab]`` and ``layers``, a dict by kind of LISTS of
@@ -47,7 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from skypilot_tpu.models import interface
-from skypilot_tpu.ops import mamba2
+from skypilot_tpu.models import mamba_mixer
 from skypilot_tpu.ops import moe_dropless
 from skypilot_tpu.ops import norms
 
@@ -148,10 +150,16 @@ class NemotronHConfig:
         in ``infer/model.py`` beside the dense block's, which imports
         this module for the mixer bodies: hence the late import."""
         from skypilot_tpu.infer import model
-        return model.hybrid_steps()
+        return model.hybrid_steps(self)
 
     def init_params(self, key) -> 'Params':
         return init_params(self, key)
+
+    def embed(self, params: 'Params', tokens):
+        return params['embed'][tokens]
+
+    def head(self, params: 'Params', x):
+        return head(self, params, x)
 
     def serving_refusals(self) -> Dict[str, str]:
         """Engine switches this model cannot run with, each with the
@@ -279,81 +287,19 @@ def init_params(config: NemotronHConfig, key) -> Params:
 # ---------------------------------------------------------------------------
 # mixer bodies: pure functions of (activations, weights, state)
 
-def _in_proj(config: NemotronHConfig, layer, h):
-    """``[z | xBC | dt] = h @ W_in``: z and xBC in the activation
-    dtype, dt in float32 with its bias and softplus applied."""
-    di, cd = config.d_inner, config.conv_dim
-    zxd = jnp.dot(h, layer['w_in'], preferred_element_type=jnp.float32)
-    z, xbc, dt = jnp.split(zxd, [di, di + cd], axis=-1)
-    dt = jax.nn.softplus(dt + layer['dt_bias'])
-    return z.astype(h.dtype), xbc.astype(h.dtype), dt
-
-
-def _split_xbc(config: NemotronHConfig, xbc):
-    """``[.., conv_dim]`` float32 after conv + silu -> x ``[.., H, P]``,
-    B and C ``[.., G, N]``."""
-    di, gn = config.d_inner, config.n_groups * config.ssm_state
-    x, b, c = jnp.split(xbc, [di, di + gn], axis=-1)
-    lead = xbc.shape[:-1]
-    return (x.reshape(*lead, config.mamba_heads, config.mamba_head_dim),
-            b.reshape(*lead, config.n_groups, config.ssm_state),
-            c.reshape(*lead, config.n_groups, config.ssm_state))
-
-
-def _gate_out(config: NemotronHConfig, layer, y, z, dtype):
-    """``w_norm * group_rmsnorm(y * silu(z))`` then the out projection."""
-    g = y * jax.nn.silu(z.astype(jnp.float32))
-    lead = g.shape[:-1]
-    g = g.reshape(*lead, config.n_groups, -1)
-    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
-                          + config.norm_eps)
-    g = g.reshape(*lead, -1) * layer['gate_norm'].astype(jnp.float32)
-    return jnp.dot(g.astype(dtype), layer['w_out'])
-
-
 def mamba_chunk(config: NemotronHConfig, layer, x, ssm, conv, true_len):
-    """The ``M`` mixer over one prompt chunk of ONE sequence.
-
-    x: ``[C, d]`` (the residual stream); ssm ``[H, P, N]`` float32 and
-    conv ``[k-1, conv_dim]``: the sequence's state before the chunk;
-    true_len: valid tokens. Returns (mixer output ``[C, d]``, ssm',
-    conv') with the state as it stands after token ``true_len - 1``:
-    the padded tail advances neither."""
+    """The ``M`` block's mixer over one prompt chunk of ONE sequence:
+    its norm, then ``mamba_mixer.chunk`` (x ``[C, d]``: the residual
+    stream; the state's contract is there)."""
     h = norms.rms_norm(x, layer['norm'], config.norm_eps)
-    z, xbc, dt = _in_proj(config, layer, h)
-    window = jnp.concatenate([conv.astype(xbc.dtype), xbc], axis=0)
-    xbc_f = mamba2.causal_conv(window, layer['conv_w'], layer['conv_b'])
-    conv = jax.lax.dynamic_slice_in_dim(window, true_len,
-                                        config.conv_kernel - 1, axis=0)
-    valid = jnp.arange(x.shape[0]) < true_len
-    dt = jnp.where(valid[:, None], dt, 0.0)       # a step of 0 holds S
-    xs, b, c = _split_xbc(config, jax.nn.silu(xbc_f))
-    y, ssm = mamba2.ssd_chunk_scan(
-        xs, dt, -jnp.exp(layer['a_log']), b, c, layer['d_skip'], ssm,
-        chunk=config.chunk_size)
-    y = y.reshape(x.shape[0], config.d_inner)
-    return _gate_out(config, layer, y, z, x.dtype), ssm, conv
+    return mamba_mixer.chunk(config, layer, h, ssm, conv, true_len)
 
 
 def mamba_decode(config: NemotronHConfig, layer, x, ssm, conv, active):
-    """The ``M`` mixer for one token of every slot.
-
-    x: ``[slots, d]``; ssm ``[slots, H, P, N]``; conv ``[slots, k-1,
-    conv_dim]``; active ``[slots]`` bool. A slot that is not active
-    keeps its state bit for bit (its output is garbage the engine
-    drops)."""
+    """The ``M`` block's mixer for one token of every slot: its norm,
+    then ``mamba_mixer.decode`` (x ``[slots, d]``)."""
     h = norms.rms_norm(x, layer['norm'], config.norm_eps)
-    z, xbc, dt = _in_proj(config, layer, h)
-    window = jnp.concatenate([conv.astype(xbc.dtype), xbc[:, None]], axis=1)
-    xbc_f = (jnp.einsum('skc,kc->sc', window.astype(jnp.float32),
-                        layer['conv_w']) + layer['conv_b'])
-    xs, b, c = _split_xbc(config, jax.nn.silu(xbc_f))
-    y, new_ssm = mamba2.ssd_decode_step(
-        xs, dt, -jnp.exp(layer['a_log']), b, c, layer['d_skip'], ssm)
-    ssm = jnp.where(active[:, None, None, None], new_ssm, ssm)
-    conv = jnp.where(active[:, None, None], window[:, 1:], conv)
-    y = y.reshape(x.shape[0], config.d_inner)
-    return _gate_out(config, layer, y, z, x.dtype), ssm, conv
+    return mamba_mixer.decode(config, layer, h, ssm, conv, active)
 
 
 def attn_qkv(config: NemotronHConfig, layer, x):

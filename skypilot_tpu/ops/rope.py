@@ -6,15 +6,30 @@ from typing import Tuple
 import jax.numpy as jnp
 
 
+def _inv_freq(head_dim: int, theta: float) -> jnp.ndarray:
+    return 1.0 / (theta ** (
+        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+
+
 def rope_frequencies(head_dim: int, max_seq_len: int,
                      theta: float = 500_000.0) -> Tuple[jnp.ndarray,
                                                         jnp.ndarray]:
     """Precomputed (cos, sin) tables, shape [max_seq_len, head_dim//2],
     fp32 (precision matters at long context)."""
-    inv_freq = 1.0 / (theta ** (
-        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    inv_freq = _inv_freq(head_dim, theta)
     t = jnp.arange(max_seq_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv_freq)
+    return jnp.cos(freqs), jnp.sin(freqs)
+
+
+def rope_at(head_dim: int, theta: float,
+            positions: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(cos, sin) of ``rope_frequencies`` at ``positions [seq]`` alone,
+    ``[seq, head_dim//2]`` fp32, with no table behind them: what
+    ``apply_rope`` takes with ``positions=None``. For a program whose
+    model declares far more positions than a step touches."""
+    inv_freq = _inv_freq(head_dim, theta)
+    freqs = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     return jnp.cos(freqs), jnp.sin(freqs)
 
 

@@ -14,6 +14,7 @@ from benchmark.reference import nemotron_h as ref
 from skypilot_tpu.infer import engine as engine_lib
 from skypilot_tpu.infer import model as model_lib
 from skypilot_tpu.infer import state_cache
+from skypilot_tpu.models import falcon_h1
 from skypilot_tpu.models import nemotron_h
 from skypilot_tpu.ops import mamba2
 from skypilot_tpu.ops import moe_dropless
@@ -272,6 +273,12 @@ def test_chunked_scan_equals_the_recurrence_from_a_carried_state():
     assert np.array_equal(np.asarray(st), np.asarray(at40))
 
 
+# Both families whose slots hold recurrent state (Falcon-H1 keeps it in
+# EVERY block, beside that block's K/V pages) refuse the same switches.
+FAMILIES = [nemotron_h.NemotronHConfig, falcon_h1.FalconH1Config]
+
+
+@pytest.mark.parametrize('family', FAMILIES, ids=lambda f: f.__name__)
 @pytest.mark.parametrize('switch, kw', [
     ('prefix_cache=True', dict(prefix_cache=True)),
     ('spec_k=2', dict(spec_k=2)),
@@ -281,18 +288,19 @@ def test_chunked_scan_equals_the_recurrence_from_a_carried_state():
     ('paged=False', dict(paged=False)),
     ('quantize=True', dict(quantize=True)),
 ])
-def test_the_engine_refuses_what_recurrent_state_breaks(switch, kw):
-    cfg = nemotron_h.NemotronHConfig.tiny()
-    params = nemotron_h.init_params(cfg, jax.random.PRNGKey(0))
-    with pytest.raises(ValueError, match='NemotronHConfig cannot be served'
-                       ) as err:
+def test_the_engine_refuses_what_recurrent_state_breaks(switch, kw, family):
+    cfg = family.tiny()
+    params = cfg.init_params(jax.random.PRNGKey(0))
+    with pytest.raises(ValueError,
+                       match=f'{family.__name__} cannot be served') as err:
         _engine(cfg, params, **kw)
     assert switch in str(err.value)
 
 
-def test_the_engine_refuses_kv_export_and_import():
-    cfg = nemotron_h.NemotronHConfig.tiny()
-    eng = _engine(cfg, nemotron_h.init_params(cfg, jax.random.PRNGKey(0)))
+@pytest.mark.parametrize('family', FAMILIES, ids=lambda f: f.__name__)
+def test_the_engine_refuses_kv_export_and_import(family):
+    cfg = family.tiny()
+    eng = _engine(cfg, cfg.init_params(jax.random.PRNGKey(0)))
     with pytest.raises(ValueError, match='kv_wire'):
         eng.request_kv_export([1, 2, 3])
     with pytest.raises(ValueError, match='kv_wire'):
