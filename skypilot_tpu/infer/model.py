@@ -828,8 +828,10 @@ def hybrid_decode_step(config: Any, params: Any,
     prefill) computes garbage, as in every decode program. Its K/V row
     lands where the next real write covers it; its recurrent state
     must not move at all, because nothing ever overwrites a state:
-    ``mamba_mixer.decode`` keeps it bit for bit. Nor does it reach an
-    expert."""
+    the state kernel under ``mamba_mixer.decode`` moves the active
+    slots' rows and no others, so the step's cost in state bytes is the
+    LIVE slots' (``ssm_slot_steps``), and the mixer's output for such a
+    slot is zeros. Nor does it reach an expert."""
     slots = tokens.shape[0]
     if active is None:
         active = jnp.ones((slots,), bool)

@@ -11,8 +11,10 @@ attention layers (its ``n_layers`` is their count, not the depth).
 ``HybridCache`` is the one object the step programs carry: the pool and
 the state both ride it as donated, in-place-updated arrays. The state
 is a TUPLE of per-layer arrays, not one ``[L, ...]`` array: the stack
-is walked by a Python loop, and a layer's array is read and rewritten
-whole (decode) or in one slot's row (prefill), aliased onto itself.
+is walked by a Python loop, and a layer's array is rewritten in the
+rows of the slots that decode (``ops/mamba2.ssd_decode_live``: a slot
+that is not active is not read either) or in one slot's row (prefill),
+aliased onto itself.
 
 The rule that keeps a slot's state right: **a prefill that starts at
 offset 0 starts from a zero state** (``slot_state``). A fresh request,
@@ -95,7 +97,8 @@ def with_slot_state(cache: HybridCache, layer: int, slot, ssm,
 
 def with_layer_state(cache: HybridCache, layer: int, ssm,
                      conv) -> HybridCache:
-    """The cache with one layer's state rewritten for every slot."""
+    """The cache with one layer's arrays replaced (a decode step's:
+    the same buffers, advanced in place)."""
     return dataclasses.replace(cache, ssm=_with(cache.ssm, layer, ssm),
                                conv=_with(cache.conv, layer, conv))
 

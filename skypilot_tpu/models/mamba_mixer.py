@@ -88,16 +88,18 @@ def decode(config, layer, h, ssm, conv, active, in_mult=None):
 
     h: ``[slots, d]``; ssm ``[slots, H, P, N]``; conv ``[slots, k-1,
     conv_dim]``; active ``[slots]`` bool. A slot that is not active
-    keeps its state bit for bit (its output is garbage the engine
-    drops)."""
+    keeps its state bit for bit: the state kernel
+    (``mamba2.ssd_decode_live``) moves the active slots' rows of
+    ``ssm`` and no others, in place. Its output is garbage the engine
+    drops, and finite: the recurrence gives it zeros."""
     z, xbc, dt = in_proj(config, layer, h, in_mult)
     window = jnp.concatenate([conv.astype(xbc.dtype), xbc[:, None]], axis=1)
     xbc_f = (jnp.einsum('skc,kc->sc', window.astype(jnp.float32),
                         layer['conv_w']) + layer['conv_b'])
     xs, b, c = split_xbc(config, jax.nn.silu(xbc_f))
-    y, new_ssm = mamba2.ssd_decode_step(
-        xs, dt, -jnp.exp(layer['a_log']), b, c, layer['d_skip'], ssm)
-    ssm = jnp.where(active[:, None, None, None], new_ssm, ssm)
+    y, ssm = mamba2.ssd_decode_live(
+        xs, dt, -jnp.exp(layer['a_log']), b, c, layer['d_skip'], ssm,
+        active)
     conv = jnp.where(active[:, None, None], window[:, 1:], conv)
     y = y.reshape(h.shape[0], config.d_inner)
     return gate_out(config, layer, y, z, h.dtype), ssm, conv

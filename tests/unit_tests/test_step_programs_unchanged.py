@@ -11,6 +11,14 @@ None of that may change what the Nemotron-H, dots3 or dense programs
 compute: the digests below were taken on the parent commit's tree with
 this file's own ``digests()``.
 
+PR 34 MEANT to change ``hybrid.decode`` and replaced its digest: the
+step's SSM state update is a Pallas kernel that moves the live slots'
+state only (``ops/mamba2.ssd_decode_live``; here, on the CPU, the text
+holds the kernel as it is interpreted), where the parent's was
+``ssd_decode_step`` under ``jnp.where``. The other five are PR 33's
+parent's still: the prefill-chunk program of the same family among
+them, which reads and writes one slot's row as it did.
+
 A PR that MEANS to change one of these programs replaces its digest
 (``python tests/unit_tests/test_step_programs_unchanged.py`` prints the
 table) and says so; a jax upgrade that rewrites the text replaces all
@@ -38,7 +46,7 @@ AT_PARENT = {
     'hybrid.prefill_chunk':
         '484e6de253a3b90f99d6ab0bc914cef49803b6ad8cfb1053820b90c56f1c0a54',
     'hybrid.decode':
-        'd0ad6a86de421a4bdc4f1839a7924bfd592eacd98d733faecab413e6b48301dc',
+        '4fb7b8777eeeea9a034f003da3573716a40a79d92c5713036eeaa0ad5afacd83',
     'dots3.prefill_chunk':
         '5dc36e338e64e00798582cf51e1805df3a3f1708b85d8eb9b448b47cf1e0c9c8',
     'dots3.decode':
