@@ -428,14 +428,25 @@ def profile_cmd(target: Optional[str], perfetto_path: Optional[str],
                     summ['drain_share'] or 0,
                     summ['readback_share'] or 0,
                     summ['host_share'] or 0))
+            click.echo(
+                'engine thread: on the CPU {:.0%} of the steps\' time; '
+                'waited for work {:.3f} s beside {:.3f} s of steps '
+                '({:.0%}); {} step(s) launched onto an empty '
+                'device'.format(
+                    summ['cpu_share'] or 0, summ['wait_s'],
+                    summ['step_time_s'], summ['wait_share'] or 0,
+                    summ['dev_empty_steps']))
             click.echo(f"step kinds: {summ['step_kinds']}")
-            fmt = '{:>8} {:>8} {:>9} {:>6} {:>7} {:>7} {:>7}'
-            click.echo(fmt.format('STEP', 'KIND', 'DUR_MS', 'BATCH',
-                                  'CHUNK', 'QUEUE', 'FREEPG'))
+            fmt = '{:>8} {:>8} {:>9} {:>9} {:>9} {:>6} {:>7} {:>7} {:>7}'
+            click.echo(fmt.format('STEP', 'KIND', 'DUR_MS', 'CPU_MS',
+                                  'WAIT_MS', 'BATCH', 'CHUNK', 'QUEUE',
+                                  'FREEPG'))
             for rec in snap.get('steps', [])[-max(1, n_steps):]:
                 click.echo(fmt.format(
                     rec.get('idx', 0), rec.get('kind', '?'),
                     f"{rec.get('dur_s', 0) * 1e3:.2f}",
+                    f"{rec.get('cpu_s', 0) * 1e3:.2f}",
+                    f"{rec.get('wait_s', 0) * 1e3:.2f}",
                     rec.get('batch', 0), rec.get('chunk_tokens', 0),
                     rec.get('queue_depth', 0),
                     rec.get('pages_free', -1)))

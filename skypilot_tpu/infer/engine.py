@@ -494,6 +494,11 @@ class InferenceEngine:
         '_kv_transfer_window': '_lock',
         '_kv_index_pub': '_lock',
         '_model_counters': '_lock',  # consume adds vs metrics reads
+        # Step-program launches and how many found the device's queue
+        # empty: metrics() reads the three together.
+        '_launches': '_lock:mut',
+        '_launches_dev_empty': '_lock:mut',
+        '_launches_after_wait': '_lock:mut',
     }
 
     def __init__(self, config: llama.LlamaConfig, params: llama.Params,
@@ -764,9 +769,15 @@ class InferenceEngine:
         # profiler trace. dispatch = device program launches, drain =
         # consume bookkeeping, readback = blocked on the pair's
         # device→host copy, sched = the step's admission section.
+        # Stage `wait` lies between steps: the server loop's block for
+        # work (`wait_stage`).
         self._sl_clock = stepline_lib.StageClock()
         self._stage = self._sl_clock.stage
         self._sl_batch = 0
+        self._sl_dev_empty = 0
+        self._launches = 0
+        self._launches_dev_empty = 0
+        self._launches_after_wait = 0
 
         # ---- compiled programs ------------------------------------------
         # Params are ARGUMENTS, never closure-captured: captured arrays
@@ -1506,6 +1517,7 @@ class InferenceEngine:
         fully cached."""
         self._note_first_dispatch(plan.req)
         with self._stage('dispatch'):
+            self._note_launch()
             if self.allocator is not None:
                 self.cache, self._last_dev = self._prefill_chunk(
                     self.cache, self.params, jnp.int32(plan.slot),
@@ -1846,8 +1858,10 @@ class InferenceEngine:
         (``engine.step`` carries the record's index as ``step_num``):
         a profiler trace shows them beside the device's operations."""
         t0 = time.perf_counter()
+        cpu0 = time.thread_time()   # inside the wall pair: cpu_s <= dur_s
         t_wall = time.time()
         self._sl_batch = 0
+        self._sl_dev_empty = 0
         with self._lock:
             pre = (self._prefill_tokens, self._spec_drafted,
                    self._spec_accepted, self._decode_steps,
@@ -1856,9 +1870,40 @@ class InferenceEngine:
             idx = self._stepline.steps.total
         with self._sl_clock.step(idx):
             worked = self._step_inner()
-        self._sl_record(t_wall, time.perf_counter() - t0, pre)
+        cpu = time.thread_time() - cpu0
+        self._sl_record(t_wall, time.perf_counter() - t0, cpu, pre)
         self._flush_stepline_dumps()
         return worked
+
+    def wait_stage(self) -> Any:
+        """Stage ``wait`` of this engine's clock: the context the step
+        loop blocks for work in (``infer/server.py``). Engine thread."""
+        return self._stage('wait')
+
+    def _note_launch(self) -> None:
+        """At the entry of a step-program launch, before anything is
+        launched: did the device's queue run empty? ``_last_dev`` is
+        the newest result of the previous launch, so it is ready only
+        once all that was launched before it has run (the page frees
+        and copies launched since are microseconds). ``is_ready`` asks
+        the runtime and moves nothing. A result that cannot be asked
+        (deleted: asking one crashes the runtime) counts as no launch
+        at all."""
+        after_wait = self._sl_clock.take_wait('launch') > 0.0
+        prev = self._last_dev
+        try:
+            if prev.is_deleted():
+                return
+            empty = bool(prev.is_ready())
+        except Exception:  # noqa: BLE001 — telemetry must never throw
+            return
+        with self._lock:
+            self._launches += 1
+            if empty:
+                self._launches_dev_empty += 1
+                self._sl_dev_empty = 1
+                if after_wait:
+                    self._launches_after_wait += 1
 
     def _step_inner(self) -> int:
         """The step body (see :meth:`step`).
@@ -2103,6 +2148,7 @@ class InferenceEngine:
         the cache — both device-resident — so it never waits for the
         host to have READ step N."""
         with self._stage('dispatch'):
+            self._note_launch()
             self._refresh_dispatch_state(decoding)
             if self.allocator is not None:
                 pair, self.cache = self._decode(
@@ -2160,6 +2206,7 @@ class InferenceEngine:
         decode — one extra step, zero token-sequence difference
         (greedy outputs are gated bit-identical fused on vs off)."""
         with self._stage('dispatch'):
+            self._note_launch()
             self._refresh_dispatch_state(decoding)
             self._note_first_dispatch(plan.req)
             chunk_key = self._next_key()
@@ -2290,6 +2337,7 @@ class InferenceEngine:
         like a decode pair; consume applies host bookkeeping per
         emitted token and rolls rejected pages back."""
         with self._stage('dispatch'):
+            self._note_launch()
             self._refresh_dispatch_state(decoding)
             drafts_dev = jnp.asarray(draft_mat)
             lens_dev = jnp.asarray(draft_lens)
@@ -2663,7 +2711,7 @@ class InferenceEngine:
 
         stepline_lib.enqueue_dump(_render)
 
-    def _sl_record(self, t_wall: float, dur: float,
+    def _sl_record(self, t_wall: float, dur: float, cpu: float,
                    pre: tuple) -> None:
         """Classify and append this step's record from counter deltas
         (recorder on only; pure observation — no scheduling state is
@@ -2705,6 +2753,9 @@ class InferenceEngine:
                 drain_s=acc['drain'],
                 readback_s=acc['readback'],
                 sched_s=acc['sched'],
+                cpu_s=cpu,
+                wait_s=self._sl_clock.take_wait('record'),
+                dev_empty=self._sl_dev_empty,
                 batch=self._sl_batch,
                 chunk_tokens=d_chunk,
                 prefilling=len(self._prefilling),
@@ -2820,6 +2871,9 @@ class InferenceEngine:
                 kv_bytes=self._kv_transfer_bytes,
                 kv_failures=self._kv_transfer_failures,
                 kv_window=list(self._kv_transfer_window),
+                launches=self._launches,
+                launches_dev_empty=self._launches_dev_empty,
+                launches_after_wait=self._launches_after_wait,
                 model_counters=dict(self._model_counters))
             return (list(self._ttfts), list(self._queue_waits),
                     self._sched.snapshot(), counters,
@@ -2910,6 +2964,15 @@ class InferenceEngine:
             # `sky-tpu profile` may list fewer after a storm).
             'stepline_steps': c['stepline_steps'],
             'stepline_dumps': c['stepline_dumps'],
+            # The engine thread between steps: seconds its loop has
+            # waited for work (an open wait counted so far). Then
+            # step-program launches, those that found the device's
+            # queue empty, and of those the first launch after a
+            # wait, which finds it empty by definition.
+            'engine_wait_s': round(self._sl_clock.waited_s(), 6),
+            'launches': c['launches'],
+            'launches_device_empty': c['launches_dev_empty'],
+            'launches_after_wait': c['launches_after_wait'],
             # Data-integrity plane (docs/robustness.md "Data
             # integrity"): on-device sentinel hits and the one-way
             # corruption verdict ('ok'/'suspect' — a state set in the
@@ -3018,6 +3081,12 @@ class EnginePool:
         # multi-host lockstep still agrees on every id.
         for i, eng in enumerate(self.engines):
             eng._ids = itertools.count(i + 1, len(self.engines))
+        # One thread steps every tier, so the tiers share one clock: a
+        # wait for work goes to the record of whichever tier works
+        # next, and the launch after it is the first on any tier.
+        clock = self.engines[0]._sl_clock
+        for eng in self.engines[1:]:
+            eng._sl_clock, eng._stage = clock, clock.stage
 
     def submit(self, prompt_tokens: Sequence[int],
                max_new_tokens: Optional[int] = None,
@@ -3049,6 +3118,10 @@ class EnginePool:
 
     def step(self) -> int:
         return sum(e.step() for e in self.engines)
+
+    def wait_stage(self) -> Any:
+        """The loop's wait for work, on the clock the tiers share."""
+        return self.engines[0].wait_stage()
 
     # -- fleet KV transfers: one advertised index per replica, so the
     # pool delegates to its first prefix-enabled tier (mixed pools are
@@ -3291,6 +3364,11 @@ class EnginePool:
                                   for t in tiers),
             'stepline_dumps': sum(t.get('stepline_dumps', 0)
                                   for t in tiers),
+            # One clock, so one wait (every tier reads the same).
+            'engine_wait_s': tiers[0]['engine_wait_s'],
+            **{k: sum(t[k] for t in tiers)
+               for k in ('launches', 'launches_device_empty',
+                         'launches_after_wait')},
             # Integrity: one suspect tier poisons the whole pool (the
             # tiers share a chip — corruption is a device property).
             'sdc_events_total': sum(t.get('sdc_events_total', 0)

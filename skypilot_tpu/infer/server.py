@@ -490,8 +490,11 @@ class InferenceServer:
                 if self.engine.step() == 0:
                     # Idle: block until a submit wakes us (the timeout
                     # is a safety net, not a poll cadence — h_generate
-                    # sets the event on every submission).
-                    self._woken.wait(timeout=0.1)
+                    # sets the event on every submission). Timed as
+                    # the engine's stage `wait`: the one state in which
+                    # an idle device is nobody's fault.
+                    with self.engine.wait_stage():
+                        self._woken.wait(timeout=0.1)
                     self._woken.clear()
         except Exception as e:  # noqa: BLE001 — a dead loop must unready
             logger.exception('engine loop died')

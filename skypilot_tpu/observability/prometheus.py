@@ -150,6 +150,15 @@ def replica_exposition() -> Dict[str, Tuple[str, str]]:
             'sky_tpu_engine_stepline_steps', 'counter'),
         'stepline_dumps': (
             'sky_tpu_engine_stepline_dumps', 'counter'),
+        # The engine thread's wait for work, and what its launches
+        # found on the device (docs/observability.md "The stages in a
+        # profiler trace").
+        'engine_wait_s': ('sky_tpu_engine_wait_seconds', 'counter'),
+        'launches': ('sky_tpu_engine_launches', 'counter'),
+        'launches_device_empty': (
+            'sky_tpu_engine_launches_device_empty', 'counter'),
+        'launches_after_wait': (
+            'sky_tpu_engine_launches_after_wait', 'counter'),
         # Data-integrity plane (docs/robustness.md "Data integrity");
         # the string-valued ``integrity`` state renders as a labeled
         # state-set, not a scalar.

@@ -15,8 +15,9 @@ engine's ``_lock``.
 The step loop times its stages through :class:`StageClock`, which
 feeds the step record AND opens ``jax.profiler`` annotations
 (``engine.step`` with its ``step_num``, ``engine.dispatch`` /
-``engine.drain`` / ``engine.readback`` / ``engine.sched``): a profiler
-trace of the replica shows the stages on the device's clock.
+``engine.drain`` / ``engine.readback`` / ``engine.sched``, and between
+steps ``engine.wait``, the loop's block for work): a profiler trace of
+the replica shows the stages on the device's clock.
 
 Three export paths:
 
@@ -50,7 +51,7 @@ import dataclasses
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 # Ring capacities (records, not bytes). A step record is ~15 scalars;
 # 1024 of them cover minutes of steady-state decode — enough context
@@ -113,6 +114,9 @@ class StepRecord:
     queue_depth: int
     tenant_depths: Optional[Dict[str, int]]   # None when single-tenant
     sched_s: float = 0.0     # part of host_s()
+    cpu_s: float = 0.0       # thread CPU time inside dur_s
+    wait_s: float = 0.0      # waited for work before this step
+    dev_empty: int = 0       # 1: a launch found the device's queue empty
 
     def host_s(self) -> float:
         return max(0.0, self.dur_s - self.dispatch_s - self.drain_s
@@ -143,6 +147,23 @@ class _Stage:
         self._ann.__exit__(*exc)
 
 
+class _Wait(_Stage):
+    """Stage ``wait``: the loop's block for work, BETWEEN steps. Its
+    seconds go to the clock's ``wait_state``, which a reader on another
+    thread sees grow while the wait is still open."""
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        super().__enter__()
+        self._clock.wait_state = (self._clock.wait_state[0], self._t0)
+
+    def __exit__(self, *exc: Any) -> None:
+        clock = self._clock
+        dur = time.perf_counter() - self._t0
+        clock.wait_state = (clock.wait_state[0] + dur, 0.0)
+        self._ann.__exit__(*exc)
+
+
 class StageClock:
     """The step loop's one way of timing a stage, on both clocks: the
     seconds land in ``acc`` (what the step's :class:`StepRecord` is
@@ -151,7 +172,16 @@ class StageClock:
     device's operations. With no profiler session an annotation costs
     under a microsecond. Engine thread only (plain floats, never read
     cross-thread); ``dispatch`` / ``drain`` / ``readback`` are disjoint,
-    ``sched`` is a part of the host remainder."""
+    ``sched`` is a part of the host remainder.
+
+    Stage ``wait`` lies between steps (the loop's block for work), so
+    no step's ``acc`` holds it: ``wait_state`` keeps the running total,
+    and each of its readers takes what has closed since it last asked
+    (:meth:`take_wait`): the step record its ``wait_s``, the launch
+    counter whether a wait came before it. ``wait_state`` is the one
+    field another thread reads (``/metrics``, :meth:`waited_s`): a
+    tuple, replaced whole, so a reader never sees a half-closed wait
+    and no lock joins the loop."""
     NAMES = ('dispatch', 'drain', 'readback', 'sched')
 
     def __init__(self) -> None:
@@ -161,6 +191,12 @@ class StageClock:
         self.annotation = profiler.TraceAnnotation
         self._step_annotation = profiler.StepTraceAnnotation
         self.acc: Dict[str, float] = dict.fromkeys(self.NAMES, 0.0)
+        # (seconds of closed waits, perf_counter at which the open
+        # wait began or 0.0)
+        self.wait_state: Tuple[float, float] = (0.0, 0.0)
+        # The closed seconds each reader on the engine thread has
+        # taken so far.
+        self._wait_taken = {'record': 0.0, 'launch': 0.0}
 
     def step(self, idx: int) -> Any:
         """Open one step: the accumulators start from zero, and the
@@ -171,7 +207,23 @@ class StageClock:
         return self._step_annotation('engine.step', step_num=idx)
 
     def stage(self, name: str) -> _Stage:
-        return _Stage(self, name)
+        return (_Wait if name == 'wait' else _Stage)(self, name)
+
+    def take_wait(self, reader: str) -> float:
+        """The seconds of closed waits since ``reader`` last asked:
+        ``'record'`` is the step record (its ``wait_s``: the wait that
+        the step ended), ``'launch'`` the launch counter (above zero
+        at the first launch after a wait). Engine thread."""
+        closed = self.wait_state[0]
+        waited = closed - self._wait_taken[reader]
+        self._wait_taken[reader] = closed
+        return waited
+
+    def waited_s(self) -> float:
+        """Seconds waited for work since the start, an open wait
+        counted as far as it has got. Any thread."""
+        closed, t0 = self.wait_state
+        return closed + (time.perf_counter() - t0 if t0 else 0.0)
 
 
 class Ring:
@@ -286,13 +338,17 @@ def summarize(recs: List[StepRecord]) -> Dict[str, Any]:
     outside any lock."""
     tot = {s: 0.0 for s in STAGES}
     kinds: Dict[str, int] = {}
-    dur = 0.0
+    dur = cpu = wait = 0.0
+    dev_empty = 0
     for r in recs:
         tot['dispatch'] += r.dispatch_s
         tot['drain'] += r.drain_s
         tot['readback'] += r.readback_s
         tot['host'] += r.host_s()
         dur += r.dur_s
+        cpu += r.cpu_s
+        wait += r.wait_s
+        dev_empty += r.dev_empty
         kinds[r.kind] = kinds.get(r.kind, 0) + 1
     out: Dict[str, Any] = {
         'steps': len(recs),
@@ -305,6 +361,15 @@ def summarize(recs: List[StepRecord]) -> Dict[str, Any]:
         out[f'{s}_s'] = round(tot[s], 6)
         out[f'{s}_share'] = (round(tot[s] / dur, 4) if dur
                              else None)
+    # The thread's CPU time as a share of the steps' wall time (the
+    # rest it was off the CPU), and beside the steps the waits for
+    # work between them, as a share of both together.
+    out['cpu_s'] = round(cpu, 6)
+    out['cpu_share'] = round(cpu / dur, 4) if dur else None
+    out['wait_s'] = round(wait, 6)
+    out['wait_share'] = (round(wait / (dur + wait), 4) if dur + wait
+                         else None)
+    out['dev_empty_steps'] = dev_empty
     return out
 
 
@@ -313,7 +378,7 @@ def summarize(recs: List[StepRecord]) -> Dict[str, Any]:
 # (which start at 1), so a merged document never collides.
 _PID_STEPS = 1000
 _PID_REQUESTS = 1001
-_STAGE_TIDS = {s: i + 1 for i, s in enumerate(STAGES)}
+_STAGE_TIDS = {s: i + 1 for i, s in enumerate(STAGES + ('wait',))}
 
 # A request's phases, each between two of its stamps. A stamp is an
 # event's own time, or (``submit.recv_t``) a detail of its ``submit``
@@ -364,6 +429,15 @@ def stepline_events(snapshot: Dict[str, Any]
         # interval: dispatch, drain, readback, then host remainder —
         # an approximation of interleaving, exact in total.
         t = rec['t']
+        wait = rec.get('wait_s', 0.0)
+        if wait > 0.0:
+            # The wait for work that this step ended, drawn up to the
+            # step's start (idle ticks inside it are not told apart).
+            events.append({
+                'name': 'engine.wait', 'ph': 'X',
+                'ts': (t - wait) * 1e6, 'dur': wait * 1e6,
+                'pid': _PID_STEPS, 'tid': _STAGE_TIDS['wait'],
+                'args': {'step': rec['idx'], 'stage': 'wait'}})
         spans = (('dispatch', rec['dispatch_s']),
                  ('drain', rec['drain_s']),
                  ('readback', rec['readback_s']),

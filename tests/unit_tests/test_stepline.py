@@ -31,6 +31,7 @@ import pytest
 pytestmark = pytest.mark.jax
 
 import jax  # noqa: E402
+import numpy as np  # noqa: E402
 
 from skypilot_tpu.infer import engine as engine_lib  # noqa: E402
 from skypilot_tpu.models import llama  # noqa: E402
@@ -211,6 +212,22 @@ def test_step_records_shape(recorded_paged):
     shares = [summ[f'{s}_share'] for s in stepline.STAGES]
     assert all(sh is not None and 0 <= sh <= 1 for sh in shares)
     assert 0.99 <= sum(shares) <= 1.01
+    # The thread's CPU time lies inside the step's wall time; a bare
+    # engine (no server loop) never waits for work.
+    for r in snap['steps']:
+        assert 0.0 <= r['cpu_s'] <= r['dur_s']
+        assert r['wait_s'] == 0.0 and r['dev_empty'] in (0, 1)
+    assert 0 < summ['cpu_share'] <= 1 and summ['wait_s'] == 0.0
+    assert summ['wait_share'] == 0.0
+    m = eng.metrics()
+    # every step that dispatched launched at least one program, and a
+    # launch that found the device empty marked its step's record
+    dispatched = sum(1 for r in snap['steps'] if r['kind'] != 'free')
+    assert m['launches'] >= dispatched > 0
+    assert (summ['dev_empty_steps'] <= m['launches_device_empty']
+            <= m['launches'])
+    assert m['launches_after_wait'] == 0
+    assert m['engine_wait_s'] == 0.0
 
 
 def test_recorder_surfaces_agree_on_what_was_recorded(recorded_paged):
@@ -221,6 +238,135 @@ def test_recorder_surfaces_agree_on_what_was_recorded(recorded_paged):
     m = eng.metrics()
     assert m['stepline_steps'] == snap['steps_total'] > 0
     assert m['stepline_dumps'] == snap['dumps']
+
+
+# ---- the thread's CPU time, and what a launch finds on the device ---------
+
+class _SlowPair:
+    """An in-flight pair whose device→host copy takes ``delay_s``: the
+    readback blocks, and the thread stands meanwhile."""
+
+    def __init__(self, pair, delay_s):
+        self.pair, self.delay_s = pair, delay_s
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(self.delay_s)
+        return np.asarray(self.pair)
+
+
+def test_a_blocked_readback_is_wall_time_but_not_cpu_time(params):
+    eng = engine_lib.InferenceEngine(CFG, params, _tiny_ecfg())
+    eng.generate([[3] * 5], max_new_tokens=3)          # compile
+    eng.submit([5] * 5, max_new_tokens=6)
+    while not eng._queue:
+        eng.step()
+    pair, *rest = eng._queue[0]
+    eng._queue[0] = (_SlowPair(pair, 0.3), *rest)
+    before = eng.stepline_snapshot()['steps_total']
+    eng.step()
+    rec = next(r for r in eng.stepline_snapshot()['steps']
+               if r['idx'] == before)
+    eng.run_until_idle()
+    assert rec['readback_s'] >= 0.3 and rec['dur_s'] >= 0.3
+    # the thread slept through the copy: off the CPU, and the record
+    # says so where dur_s and readback_s alone cannot
+    assert rec['cpu_s'] < rec['dur_s'] - 0.25
+    assert rec['cpu_s'] < 0.1 * rec['dur_s']
+
+
+class _Result:
+    """What a launch left behind, as ready as the test says."""
+
+    def __init__(self, ready):
+        self.ready = ready
+
+    def is_deleted(self):
+        return False
+
+    def is_ready(self):
+        if self.ready is None:
+            raise RuntimeError('no such buffer')
+        return self.ready
+
+
+def _launch_counts(eng):
+    m = eng.metrics()
+    return (m['launches'], m['launches_device_empty'],
+            m['launches_after_wait'])
+
+
+def test_launch_counters_ask_the_previous_result(params):
+    eng = engine_lib.InferenceEngine(CFG, params, _tiny_ecfg())
+    assert _launch_counts(eng) == (0, 0, 0)
+    eng._last_dev = _Result(False)      # the device is still at it
+    eng._note_launch()
+    assert _launch_counts(eng) == (1, 0, 0) and eng._sl_dev_empty == 0
+    eng._last_dev = _Result(True)       # its queue ran empty
+    eng._note_launch()
+    assert _launch_counts(eng) == (2, 1, 0) and eng._sl_dev_empty == 1
+    # the first launch after a wait for work finds it empty by
+    # definition, and only the first
+    with eng.wait_stage():
+        pass
+    eng._note_launch()
+    eng._note_launch()
+    assert _launch_counts(eng) == (4, 3, 1)
+    # busy after a wait (another tier's program): not an empty launch
+    with eng.wait_stage():
+        pass
+    eng._last_dev = _Result(False)
+    eng._note_launch()
+    assert _launch_counts(eng) == (5, 3, 1)
+    # a result that cannot be asked counts as no launch, and the wait
+    # before it marks no later launch
+    with eng.wait_stage():
+        pass
+    eng._last_dev = _Result(None)
+    eng._note_launch()
+    deleted = jax.numpy.zeros((2,), jax.numpy.int32)
+    deleted.delete()
+    eng._last_dev = deleted
+    eng._note_launch()
+    eng._last_dev = _Result(True)
+    eng._note_launch()
+    assert _launch_counts(eng) == (6, 4, 1)
+
+
+def test_engine_pool_sums_the_launch_counters_and_has_one_wait(params):
+    small = engine_lib.InferenceEngine(
+        CFG, params, _tiny_ecfg(max_seq_len=32))
+    large = engine_lib.InferenceEngine(CFG, params, _tiny_ecfg())
+    pool = engine_lib.EnginePool([small, large])
+    # One thread steps every tier, so one clock: whichever tier
+    # launches first after a wait is the launch after it...
+    with pool.wait_stage():
+        time.sleep(0.01)
+    real = (small._last_dev, large._last_dev)
+    for eng, ready in ((large, True), (small, True), (large, False)):
+        eng._last_dev = _Result(ready)
+        eng._note_launch()
+    small._last_dev, large._last_dev = real
+    m = pool.metrics()
+    assert (m['launches'], m['launches_device_empty'],
+            m['launches_after_wait']) == (3, 2, 1)
+    assert [t['launches_after_wait'] for t in m['tiers']] == [0, 1]
+    # ...and the wait is counted once, not once a tier
+    assert 0.01 <= m['engine_wait_s'] < 0.02 * len(pool.engines)
+    assert {t['engine_wait_s'] for t in m['tiers']} == {m['engine_wait_s']}
+    # ...and goes to the record of the tier that works next, so no
+    # later record of another tier spans steps that were not waits
+    before = (small.stepline_snapshot()['steps_total'],
+              large.stepline_snapshot()['steps_total'])
+    with pool.wait_stage():
+        time.sleep(0.01)
+    for prompt in ([5] * 40, [5] * 5):      # the large tier's, the small's
+        pool.submit(prompt, max_new_tokens=2)
+        pool.run_until_idle()
+    s_recs, l_recs = (
+        [r for r in e.stepline_snapshot()['steps'] if r['idx'] >= n]
+        for e, n in zip((small, large), before))
+    assert l_recs[0]['wait_s'] >= 0.01
+    assert s_recs and all(r['wait_s'] == 0.0 for r in s_recs + l_recs[1:])
 
 
 # ---- anomaly-triggered dumps ---------------------------------------------
@@ -448,7 +594,7 @@ def test_perfetto_export_schema_and_tracks(recorded_paged):
     assert {'engine-step', 'requests'} <= meta_names
     stage_names = {e['args']['name'] for e in events
                    if e['ph'] == 'M' and e['name'] == 'thread_name'}
-    assert stage_names == set(stepline.STAGES)
+    assert stage_names == set(stepline.STAGES) | {'wait'}
     req_slices = {e['name'] for e in events
                   if e['ph'] == 'X' and e['pid'] == 1001}
     assert {'req.queue_wait', 'req.prefill', 'req.decode'} <= req_slices

@@ -9,10 +9,15 @@ CPU: every finished request carries ``submit`` (with ``recv_t``, and
 the Perfetto export draws them, and none of it changes a token. Then
 three steps under ``jax.profiler``: the ``engine.*`` annotations are in
 the trace, each stage inside its step, and they add up to what the
-step records hold. A CPU run shows order and counts, never a speed.
+step records hold. And a pause between two requests of a served
+engine: the loop's wait for work is stage ``engine.wait``, between the
+steps in the trace, on the ``/metrics`` counter while still open, and
+on the record of the step that ends it. A CPU run shows order and
+counts, never a speed.
 """
 import asyncio
 import json
+import time
 
 import pytest
 
@@ -239,6 +244,134 @@ def test_engine_pool_routes_the_stamps_to_the_requests_tier(params):
     assert sub['lb_recv_t'] == 10.5 and sub['recv_t'] == 11.0
 
 
+PAUSE_S = 0.5
+
+
+@pytest.fixture(scope='module')
+def paused(params, tmp_path_factory):
+    """One request through the real server, a pause with the engine
+    empty (``/metrics`` read in the middle of it), a second request;
+    all of it under ``jax.profiler`` as the benchmark takes it."""
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from benchmark import trace_reduce
+    from skypilot_tpu.infer import server as server_lib
+
+    log_dir = str(tmp_path_factory.mktemp('wait-trace'))
+
+    async def flow():
+        eng = engine_lib.InferenceEngine(CFG, params, _ecfg())
+        srv = server_lib.InferenceServer(eng)
+        srv._thread.start()
+        client = TestClient(TestServer(srv.make_app()))
+        await client.start_server()
+        out = {}
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        try:
+            await _stream(client, PROMPTS[0], 3)    # compiles outside
+            jax.profiler.start_trace(log_dir, profiler_options=opts)
+            try:
+                out['first'], _ = await _stream(client, PROMPTS[0], 3)
+                out['before'] = await (await client.get('/metrics')).json()
+                await asyncio.sleep(PAUSE_S / 2)
+                out['mid'] = await (await client.get('/metrics')).json()
+                await asyncio.sleep(PAUSE_S / 2)
+                out['second'], _ = await _stream(client, PROMPTS[2], 3)
+                out['after'] = await (await client.get('/metrics')).json()
+            finally:
+                jax.profiler.stop_trace()
+            out['snapshot'] = await (
+                await client.get('/debug/stepline')).json()
+        finally:
+            await client.close()
+            srv._stop.set()
+        return out
+
+    out = asyncio.run(flow())
+    out['rows'] = trace_reduce.load(trace_reduce.find_xplane(log_dir),
+                                    device_only=False)
+    return out
+
+
+def _record_that_ended_the_pause(paused):
+    """The first worked step after the second request's submit."""
+    sub = _first(_by_request(paused['snapshot'])[paused['second']], 'submit')
+    return next(st for st in paused['snapshot']['steps']
+                if st['t'] + st['dur_s'] >= sub['t'])
+
+
+def test_a_pause_between_requests_is_the_next_records_wait(paused):
+    # from the first traced request on: the steps before it compiled
+    t_first = _first(_by_request(paused['snapshot'])[paused['first']],
+                     'submit')['t']
+    steps = [st for st in paused['snapshot']['steps'] if st['t'] >= t_first]
+    rec = _record_that_ended_the_pause(paused)
+    prev = steps[steps.index(rec) - 1]
+    gap = rec['t'] - (prev['t'] + prev['dur_s'])
+    assert gap >= PAUSE_S
+    # the gap between the two records is the wait, to a few ms (the
+    # idle ticks every 0.1 s and the loop itself are the rest)
+    assert rec['wait_s'] == pytest.approx(gap, abs=0.02)
+    # no step's duration covers the pause, and only this one carries it
+    assert all(st['dur_s'] < PAUSE_S / 2 for st in steps)
+    later = [st for st in steps if st['idx'] > rec['idx']]
+    assert later and all(st['wait_s'] < 0.05 for st in later)
+    # the accounting closes over the stretch: steps + waits + a
+    # remainder that is small here
+    first = next(st for st in steps if st['idx'] == prev['idx'])
+    stretch = [st for st in steps if st['idx'] >= first['idx']]
+    span = stretch[-1]['t'] + stretch[-1]['dur_s'] - stretch[0]['t']
+    named = (sum(st['dur_s'] for st in stretch)
+             + sum(st['wait_s'] for st in stretch[1:]))
+    assert 0.0 <= span - named < 0.05
+
+
+def test_the_wait_counter_shows_an_open_wait_and_grows_by_the_pause(paused):
+    before, mid, after = (paused[k] for k in ('before', 'mid', 'after'))
+    rec = _record_that_ended_the_pause(paused)
+    # read in the middle of the wait: part of it is there already
+    assert 0.1 < mid['engine_wait_s'] - before['engine_wait_s'] < PAUSE_S
+    grown = after['engine_wait_s'] - before['engine_wait_s']
+    # the second scrape follows the first request's last step by a
+    # moment the counter had already counted
+    assert grown == pytest.approx(rec['wait_s'], abs=0.05)
+    # the first launch after the wait found the device empty
+    assert (after['launches_after_wait']
+            - before['launches_after_wait']) == 1
+    assert rec['dev_empty'] == 1
+    assert after['launches'] > before['launches']
+    assert (after['launches_after_wait'] <= after['launches_device_empty']
+            <= after['launches'])
+
+
+def test_engine_wait_is_on_the_engine_threads_line_outside_every_step(
+        paused):
+    mine = [r for r in paused['rows'] if r[2].startswith('engine.')]
+    assert {r[0] for r in mine} == {'/host:CPU'}
+    assert len({r[1] for r in mine}) == 1       # the engine's thread
+    waits = [r for r in mine if r[2] == 'engine.wait']
+    steps = [r for r in mine if r[2] == 'engine.step']
+    assert steps and len(waits) >= PAUSE_S / 0.1 - 1
+    for w in waits:
+        assert all(w[3] + w[4] <= s[3] or s[3] + s[4] <= w[3]
+                   for s in steps), w
+    # what the trace holds of waits is what the counter counted
+    total = sum(w[4] for w in waits) / 1e9
+    grown = paused['after']['engine_wait_s'] - paused['before'][
+        'engine_wait_s']
+    assert total >= grown - 0.05
+    # and the Perfetto export draws the wait on its own stage track
+    doc = stepline.to_perfetto(paused['snapshot'])
+    assert stepline.validate_perfetto(doc) == []
+    rec = _record_that_ended_the_pause(paused)
+    drawn = [ev for ev in doc['traceEvents'] if ev['name'] == 'engine.wait']
+    assert any(ev['args']['step'] == rec['idx']
+               and ev['dur'] == pytest.approx(rec['wait_s'] * 1e6)
+               for ev in drawn)
+
+
 def test_stages_are_annotations_in_a_profiler_trace(params, tmp_path):
     """Three steps under ``jax.profiler``: the trace holds
     ``engine.step`` and the four stages on the host plane, each stage
@@ -256,13 +389,17 @@ def test_stages_are_annotations_in_a_profiler_trace(params, tmp_path):
     opts.host_tracer_level = 1      # what benchmark/kinds/_serve.py takes
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
-        for _ in range(3):
+        for i in range(3):
             eng.step()
+            if i == 1:
+                with eng.wait_stage():      # what the server loop does
+                    time.sleep(0.002)
     finally:
         jax.profiler.stop_trace()
     records = [st for st in eng.stepline_snapshot()['steps']
                if st['idx'] >= before]
     assert len(records) == 3
+    assert [st['wait_s'] >= 0.002 for st in records] == [False, False, True]
     eng.run_until_idle()
 
     rows = trace_reduce.load(trace_reduce.find_xplane(str(tmp_path)),
@@ -272,7 +409,7 @@ def test_stages_are_annotations_in_a_profiler_trace(params, tmp_path):
     assert len({r[1] for r in mine}) == 1       # the engine's thread
     names = {r[2] for r in mine}
     assert names >= {'engine.step', 'engine.dispatch', 'engine.readback',
-                     'engine.drain', 'engine.sched'}
+                     'engine.drain', 'engine.sched', 'engine.wait'}
     steps = sorted((r for r in mine if r[2] == 'engine.step'),
                    key=lambda r: r[3])
     assert len(steps) == 3
@@ -286,10 +423,16 @@ def test_stages_are_annotations_in_a_profiler_trace(params, tmp_path):
             assert total == pytest.approx(rec[f'{stage}_s'], abs=1e-3), (
                 stage, total, rec)
         assert step[4] / 1e9 == pytest.approx(rec['dur_s'], abs=2e-3)
-    # every stage lies inside one of the steps
-    stages = [r for r in mine if r[2] != 'engine.step']
+    # every stage lies inside one of the steps, but the wait for work,
+    # which lies between the second and the third
+    stages = [r for r in mine
+              if r[2] not in ('engine.step', 'engine.wait')]
     assert all(any(s[3] <= r[3] and r[3] + r[4] <= s[3] + s[4]
                    for s in steps) for r in stages)
+    (wait,) = [r for r in mine if r[2] == 'engine.wait']
+    assert steps[1][3] + steps[1][4] <= wait[3]
+    assert wait[3] + wait[4] <= steps[2][3]
+    assert wait[4] / 1e9 == pytest.approx(records[2]['wait_s'], abs=1e-3)
     # and the step carries the index of the record it wrote
     from jax.profiler import ProfileData
     nums = []
@@ -315,6 +458,25 @@ def test_stage_clock_adds_up_and_starts_each_step_from_zero():
     assert clock.acc['drain'] == 0 and clock.acc['readback'] == 0
     with clock.step(8):
         assert set(clock.acc.values()) == {0.0}
+    # the wait for work lies between steps: no step's share holds it,
+    # a step's start does not lose it, and it is taken once
+    with clock.stage('wait'):
+        time.sleep(0.001)
+        open_s = clock.waited_s()
+        assert 0.0 < open_s and clock.wait_state[1] > 0
+        # an open wait is nobody's yet
+        assert clock.take_wait('record') == 0.0
+    assert clock.wait_state[1] == 0.0
+    with clock.step(9):
+        assert set(clock.acc.values()) == {0.0}
+    closed_s = clock.waited_s()
+    assert closed_s >= max(open_s, 0.001)
+    # each reader takes it once, whichever asks first
+    assert clock.take_wait('record') == closed_s
+    assert clock.take_wait('record') == 0.0
+    assert clock.take_wait('launch') == closed_s
+    assert clock.take_wait('launch') == 0.0
+    assert clock.waited_s() == closed_s
     with pytest.raises(KeyError):
         with clock.stage('lunch'):
             pass
