@@ -19,7 +19,11 @@ TPU-first structure:
   one step behind the device, and per-token operands (temps, active
   mask, block table) live on device behind dirty flags instead of
   being re-uploaded every token (docs/serving.md, "The decode
-  pipeline").
+  pipeline"). A prompt's FIRST token does not wait for a pair: the
+  chunk that ends the prompt returns it, and its record is read as
+  soon as that chunk has ended, ahead of the decode dispatched behind
+  it (``infer/inflight.py``: the in-flight records and the consume
+  ladder).
 - Token delivery is event-driven: every consumed token fires the
   request's condition/listeners (``Request.wait_progress``), so the
   server streams without sleep-polling.
@@ -44,6 +48,7 @@ import numpy as np
 
 from skypilot_tpu.infer import cache as cache_lib
 from skypilot_tpu.infer import drafter as drafter_lib
+from skypilot_tpu.infer import inflight
 from skypilot_tpu.infer import kv_wire
 from skypilot_tpu.infer import model as model_lib
 from skypilot_tpu.infer import paged_cache as paged_cache_lib
@@ -423,7 +428,7 @@ class _KVJob:
         return self._done.wait(timeout)
 
 
-class InferenceEngine:
+class InferenceEngine(inflight.ConsumeLadder):
     """Slot-based continuous batching over one model replica."""
 
     # Concurrency contract, enforced statically by `sky-tpu lint`
@@ -499,6 +504,10 @@ class InferenceEngine:
         '_launches': '_lock:mut',
         '_launches_dev_empty': '_lock:mut',
         '_launches_after_wait': '_lock:mut',
+        # First tokens stamped, and those read early (by their chunk's
+        # own record, not a step pair's row 0).
+        '_first_tokens': '_lock:mut',
+        '_first_tokens_early': '_lock:mut',
     }
 
     def __init__(self, config: llama.LlamaConfig, params: llama.Params,
@@ -674,14 +683,16 @@ class InferenceEngine:
         self._slot_len = np.zeros((self.ecfg.n_slots,), np.int64)
         self._temps = np.zeros((self.ecfg.n_slots,), np.float32)
         # ---- overlapped decode pipeline state ---------------------------
-        # Dispatched-but-unread decode steps (≤ _depth of them). Each
-        # record pins the [2, slots] pair (async host copy in flight)
+        # Dispatched-but-unread results (infer/inflight.py): step
+        # pairs (≤ _depth of them) and, ahead of the decode dispatched
+        # behind a prompt's last chunk, that chunk's first token. Each
+        # record pins the device result (async host copy in flight)
         # plus the slot→request assignment AT DISPATCH TIME, so consume
         # can apply the stale-by-one rule: a token whose slot no longer
         # holds the same request (finished / preempted meanwhile) is
         # dropped.
         self._depth = max(0, int(self.ecfg.pipeline_depth))
-        self._queue: collections.deque = collections.deque()
+        self._queue = inflight.Queue()
         # Per-slot count of tokens in flight (page accounting must cover
         # positions the device will have written before the host reads).
         self._inflight_tok = [0] * self.ecfg.n_slots
@@ -708,13 +719,6 @@ class InferenceEngine:
         self._prefill_tokens = 0
         self._fused_steps = 0
         self._stall_steps = 0
-        # Slots whose prompt finished prefilling WITHOUT joining a
-        # decode dispatch yet (fused-mode edge: the decode batch
-        # evaporated under page pressure, so the completing chunk went
-        # out standalone): their first token sits in _last_dev and
-        # surfaces via the NEXT dispatch's pair row 0. Engine thread
-        # only.
-        self._pending_first: Dict[int, Request] = {}
         # Zero-downtime-serving counters: queued requests dropped
         # because the client vanished, requests cut by their deadline,
         # active requests cancelled by a client disconnect.
@@ -778,6 +782,8 @@ class InferenceEngine:
         self._launches = 0
         self._launches_dev_empty = 0
         self._launches_after_wait = 0
+        self._first_tokens = 0
+        self._first_tokens_early = 0
 
         # ---- compiled programs ------------------------------------------
         # Params are ARGUMENTS, never closure-captured: captured arrays
@@ -802,6 +808,17 @@ class InferenceEngine:
             axes = tuple(range(1, logits.ndim))
             return jnp.all(jnp.isfinite(logits),
                            axis=axes).astype(jnp.int32)
+
+        def _first_out(tok, logits):
+            # What a prompt's last chunk hands the early read
+            # (inflight.FirstToken): the token it sampled and, sentinel
+            # on, whether the ONE row of logits it sampled from is all
+            # finite. A [vocab] reduce a chunk, not a step.
+            rows = [tok.astype(jnp.int32)]
+            if self._sentinel:
+                rows.append(jnp.all(jnp.isfinite(logits)).astype(
+                    jnp.int32))
+            return jnp.stack(rows)
 
         def _accept(tokens, logits, drafts, draft_len, key, temps,
                     active, lengths):
@@ -840,8 +857,8 @@ class InferenceEngine:
                     offset, true_len)
                 tok = sampling_lib.sample(logits[None], key, temp[None],
                                           top_k=self.ecfg.top_k)[0]
-                return new_cache, last.at[slot].set(
-                    tok.astype(last.dtype))
+                return (new_cache, last.at[slot].set(
+                    tok.astype(last.dtype)), _first_out(tok, logits))
             self._prefill_chunk = _jit(_prefill_chunk_paged,
                                        donate=(0, 9))
 
@@ -933,19 +950,21 @@ class InferenceEngine:
                 # First-token sampling AND the last-token vector update
                 # are FUSED: separate programs would cost extra
                 # dispatches (and a sample sync) per prompt. The
-                # sampled token is only meaningful on the final chunk;
-                # earlier chunks' updates are overwritten before the
-                # slot ever decodes.
+                # sampled token is only meaningful on the final chunk
+                # (whose third result the host reads early); earlier
+                # chunks' updates are overwritten before the slot ever
+                # decodes.
                 new_cache, logits = model_lib.prefill_chunk(
                     config, params, kv_cache, slot, tokens, offset,
                     true_len)
                 tok = sampling_lib.sample(logits[None], key, temp[None],
                                           top_k=self.ecfg.top_k)[0]
-                return new_cache, last.at[slot].set(
-                    tok.astype(last.dtype))
+                return (new_cache, last.at[slot].set(
+                    tok.astype(last.dtype)), _first_out(tok, logits))
             self._prefill_chunk = _jit(
                 _prefill_chunk, donate=(0, 8),
-                out=(self._cache_sharding, self._rep_sharding))
+                out=(self._cache_sharding, self._rep_sharding,
+                     self._rep_sharding))
 
             def _decode(kv_cache, params, tokens, key, temps, active):
                 logits, new_cache = model_lib.decode_step(
@@ -953,10 +972,8 @@ class InferenceEngine:
                 sampled = sampling_lib.sample(logits, key, temps,
                                               top_k=self.ecfg.top_k)
                 toks_out = jnp.where(active, sampled, tokens)
-                # [2, slots]: row 0 echoes the inputs (= the first
-                # sampled token of any slot that finished prefill this
-                # step), row 1 the new tokens — ONE host read serves
-                # both.
+                # [2, slots]: row 0 echoes the inputs, row 1 the new
+                # tokens — ONE host read serves both.
                 rows = [tokens, toks_out]
                 if self._sentinel:
                     rows.append(_finite_row(logits))
@@ -1396,11 +1413,12 @@ class InferenceEngine:
 
     def _do_chunk(self, slot: int) -> Optional[bool]:
         """Advance one prefilling slot by ONE chunk — NO host sync
-        (the sampled first token stays on device; the step's single
-        decode read surfaces it). Returns True when the prompt is fully
-        cached (slot joins this step's decode), False on progress, None
-        when the page pool cannot cover the chunk right now (deferred;
-        decode continues and finishing slots free pages)."""
+        (a completing chunk's first token is read from its in-flight
+        record once the chunk has ended). Returns True when the prompt
+        is fully cached (slot joins this step's decode), False on
+        progress, None when the page pool cannot cover the chunk right
+        now (deferred; decode continues and finishing slots free
+        pages)."""
         plan = self._prepare_chunk(slot)
         if plan is None:
             return None
@@ -1514,19 +1532,21 @@ class InferenceEngine:
     def _dispatch_chunk_plan(self, plan: _ChunkPlan) -> bool:
         """Standalone dispatch of a prepared chunk via the prefill
         program (no host sync). Returns True when the prompt is now
-        fully cached."""
+        fully cached: the chunk's own ``[token, finite]`` then starts
+        its copy to the host at once and is queued as a first-token
+        record, AHEAD of the decode this step dispatches behind it."""
         self._note_first_dispatch(plan.req)
         with self._stage('dispatch'):
             self._note_launch()
             if self.allocator is not None:
-                self.cache, self._last_dev = self._prefill_chunk(
+                self.cache, self._last_dev, first = self._prefill_chunk(
                     self.cache, self.params, jnp.int32(plan.slot),
                     plan.table_row, jnp.asarray(plan.padded),
                     jnp.int32(plan.off), jnp.int32(plan.tl),
                     self._next_key(),
                     jnp.float32(plan.req.temperature), self._last_dev)
             else:
-                self.cache, self._last_dev = self._prefill_chunk(
+                self.cache, self._last_dev, first = self._prefill_chunk(
                     self.cache, self.params, jnp.int32(plan.slot),
                     jnp.asarray(plan.padded), jnp.int32(plan.off),
                     jnp.int32(plan.tl), self._next_key(),
@@ -1534,14 +1554,20 @@ class InferenceEngine:
         with self._lock:
             self._prefill_tokens += plan.tl
             self._note_prefill_dispatched(plan)
-        return self._note_chunk_dispatched(plan)
+        done = self._note_chunk_dispatched(plan)
+        if done:
+            first.copy_to_host_async()
+            self._queue.append(
+                inflight.FirstToken(first, plan.slot, plan.req))
+        return done
 
     def _note_prefill_dispatched(self,  # holds: _lock
                                  plan: _ChunkPlan) -> None:
         """Timeline event: the chunk just dispatched was the LAST of
-        the request's prompt. From here to ``first_token`` lie that
-        step's device time and the dispatch-ahead pipeline's
-        stale-by-one consume."""
+        the request's prompt. From here to ``first_token`` lie what
+        the device still had ahead of the chunk (at depth 1 the decode
+        in flight) and the chunk's own device time; the fused mixed
+        step's first token waits for its whole pair besides."""
         if plan.off + plan.tl >= plan.total:
             self._stepline.note_event(
                 plan.req.request_id, plan.req.tenant,
@@ -2006,13 +2032,14 @@ class InferenceEngine:
                 self._prefilling.pop(keep, None)
                 self._finish(keep, req)
         # Decode phase: every fully-prefilled slot — including the ones
-        # that JUST finished prefill (their first token is in _last_dev;
-        # they decode their second token in this same step). The step
-        # reads back ONE [2, slots] pair: row 0 carries first tokens,
-        # row 1 everyone's new token — but at pipeline_depth > 0 the
-        # pair read is the PREVIOUS step's, consumed only after this
-        # step's decode is already dispatched, so the device never
-        # waits on host bookkeeping.
+        # that JUST finished prefill (their first token is in _last_dev
+        # and in their first-token record; they decode their second
+        # token in this same step). The step dispatches ONE [2, slots]
+        # pair: row 1 everyone's new token — but at pipeline_depth > 0
+        # the pair read is the PREVIOUS step's, consumed only after
+        # this step's decode is already dispatched, so the device never
+        # waits on host bookkeeping. The first-token records are read
+        # on the way to it, each as soon as its chunk has ended.
         if plan is not None:
             # A chunk is riding this step's dispatch: the fused mixed
             # program has no draft lanes, so speculation stands down
@@ -2087,29 +2114,24 @@ class InferenceEngine:
             else:
                 # The decode batch evaporated (page-pressure drains
                 # finished every decoder): the prepared chunk goes out
-                # standalone; a completed prompt's first token parks
-                # in _last_dev and surfaces via the NEXT dispatch's
-                # pair row 0 (_pending_first).
-                if self._dispatch_chunk_plan(plan):
-                    self._pending_first[plan.slot] = plan.req
+                # standalone, and a completed prompt's first token is
+                # read by its own record in this step's drain below.
+                self._dispatch_chunk_plan(plan)
         elif decoding:
             drafts = (self._build_drafts(decoding, just_prefilled,
                                          spec_k) if spec_k else None)
             if drafts is not None:
-                self._dispatch_verify(decoding, just_prefilled,
-                                      *drafts)
+                self._dispatch_verify(decoding, *drafts)
             else:
                 # No drafts this step (spec off, sampled slots, or no
                 # n-gram matched): the plain decode program is the
                 # cheaper dispatch — a draftless verify would pay
                 # spec_k wasted lanes per slot.
-                self._dispatch_decode(decoding, just_prefilled)
+                self._dispatch_decode(decoding)
         # Keep at most _depth steps in flight; with nothing newly
         # dispatched there is no overlap left to win — drain fully so
         # finished requests surface and idle() can flip.
-        allowed = self._depth if decoding else 0
-        while len(self._queue) > allowed:
-            self._consume_one()
+        self._consume_to(self._depth if decoding else 0)
         with self._lock:
             self._decode_time += time.perf_counter() - t0
         return len(decoding) + len(self._prefilling)
@@ -2140,13 +2162,14 @@ class InferenceEngine:
                         self.window_alloc.table()))
                 self._table_version = version
 
-    def _dispatch_decode(self, decoding: List[int],
-                         just_prefilled: List[int]) -> None:
+    def _dispatch_decode(self, decoding: List[int]) -> None:
         """Dispatch one decode step (no host sync) and start its pair's
         device→host copy; the result is consumed by a later
         ``_consume_one``. Decode N+1 depends only on ``_last_dev`` and
         the cache — both device-resident — so it never waits for the
-        host to have READ step N."""
+        host to have READ step N. A slot that just finished prefill
+        rides as any other lane: the pair carries its SECOND token, its
+        first is in the record queued ahead of this one."""
         with self._stage('dispatch'):
             self._note_launch()
             self._refresh_dispatch_state(decoding)
@@ -2174,25 +2197,8 @@ class InferenceEngine:
             self._decode_steps += 1
             for s in decoding:
                 self._inflight_tok[s] += 1
-        self._queue.append((
-            pair,
-            [(s, self._slots[s]) for s in decoding],
-            self._take_pending_first()
-            + [(s, self._slots[s]) for s in just_prefilled],
-            None))   # no verify payload: consume takes the decode path
-
-    def _take_pending_first(self) -> List[tuple]:
-        """Drain the fused-mode pending-first-token slots into this
-        dispatch's pair record (their first token is already in
-        ``_last_dev``, so pair row 0 will echo it). Identity-checked:
-        a slot preempted or refilled since simply re-prefills and
-        re-samples. Engine thread only."""
-        if not self._pending_first:
-            return []
-        out = [(s, r) for s, r in self._pending_first.items()
-               if self._slots[s] is r]
-        self._pending_first.clear()
-        return out
+        self._queue.append(inflight.StepPair(
+            pair, [(s, self._slots[s]) for s in decoding]))
 
     def _dispatch_mixed(self, decoding: List[int],
                         plan: _ChunkPlan) -> None:
@@ -2202,9 +2208,12 @@ class InferenceEngine:
         dispatch sits between decode dispatches. The [2, slots] pair
         rides the in-flight queue exactly like a decode pair; a chunk
         that completes its prompt surfaces its first token through
-        pair row 0 (the prefilled list) and joins the NEXT step's
-        decode — one extra step, zero token-sequence difference
-        (greedy outputs are gated bit-identical fused on vs off)."""
+        pair row 0 (the record's ``prefilled``: the token and the
+        decode are one program's result, so there is nothing earlier
+        to read, and this is the one path that still reads row 0) and
+        joins the NEXT step's decode — one extra step, zero
+        token-sequence difference (greedy outputs are gated
+        bit-identical fused on vs off)."""
         with self._stage('dispatch'):
             self._note_launch()
             self._refresh_dispatch_state(decoding)
@@ -2237,23 +2246,18 @@ class InferenceEngine:
             for s in decoding:
                 self._inflight_tok[s] += 1
         completes = self._note_chunk_dispatched(plan)
-        prefilled = self._take_pending_first()
-        if completes:
-            prefilled.append((plan.slot, plan.req))
-        self._queue.append((
-            pair,
-            [(s, self._slots[s]) for s in decoding],
-            prefilled,
-            None))
+        self._queue.append(inflight.StepPair(
+            pair, [(s, self._slots[s]) for s in decoding],
+            prefilled=[(plan.slot, plan.req)] if completes else ()))
 
     def _spec_eligible(self, s: int, fresh: set) -> bool:
         """May slot ``s`` draft this step? Greedy, opted in, fully
         prefilled, and not one of this step's fresh prefills (their
-        first token is still device-side, so the host cannot continue
-        the sequence). ONE definition, shared by step()'s skip-the-
-        drain gate and ``_build_drafts`` — an eligibility change must
-        reach both or speculation silently diverges from the gate.
-        Engine thread only."""
+        first token's record is not read yet, so the host cannot
+        continue the sequence). ONE definition, shared by step()'s
+        skip-the-drain gate and ``_build_drafts`` — an eligibility
+        change must reach both or speculation silently diverges from
+        the gate. Engine thread only."""
         r = self._slots[s]
         return (r is not None and s not in self._prefilling
                 and s not in fresh and r.temperature == 0 and r.spec)
@@ -2268,8 +2272,8 @@ class InferenceEngine:
         the verify program honors), or None when nobody drafted — the
         caller then dispatches the plain decode program. A slot drafts
         only when it is greedy, opted in, NOT just-prefilled (its
-        first token is still device-side, so the host cannot continue
-        the sequence), within the scheduler's per-step budget
+        first token's record is not read yet, so the host cannot
+        continue the sequence), within the scheduler's per-step budget
         (wfq caps over-share tenants), short enough of the cache end
         that every drafted position fits, and — paged — coverable
         without evicting cached prefixes or preempting anyone
@@ -2325,7 +2329,6 @@ class InferenceEngine:
         return (mat, lens) if any_draft else None
 
     def _dispatch_verify(self, decoding: List[int],
-                         just_prefilled: List[int],
                          draft_mat: 'np.ndarray',
                          draft_lens: 'np.ndarray') -> None:
         """Dispatch one fused verify step (no host sync): the draft
@@ -2359,171 +2362,11 @@ class InferenceEngine:
             self._spec_steps += 1
             for s in decoding:
                 self._inflight_tok[s] += int(draft_lens[s]) + 1
-        self._queue.append((
+        self._queue.append(inflight.StepPair(
             pair,
             [(s, self._slots[s], int(draft_lens[s]))
              for s in decoding],
-            self._take_pending_first()
-            + [(s, self._slots[s]) for s in just_prefilled],
-            draft_mat.shape[1] + 1))
-
-    def _consume_one(self) -> None:
-        """Read back the OLDEST in-flight pair and apply its host-side
-        bookkeeping (token appends, TTFT stamps, finish detection, slot
-        frees). Stale-by-one rule: a slot that no longer holds the
-        request it held at dispatch time (finished or preempted since)
-        drops its token — for greedy decoding the resume path recomputes
-        the identical token, so outputs are depth-invariant."""
-        pair, decoded, prefilled, spec_r = self._queue.popleft()
-        # Readback = blocked on the device→host copy; everything after
-        # is drain (host bookkeeping catching up). Both accumulate
-        # into the current step's record.
-        with self._stage('readback'):
-            pair_host = np.asarray(pair)   # sync point (copy async)
-        with self._stage('drain'):
-            self._apply_pair(pair_host, decoded, prefilled, spec_r)
-
-    def _apply_pair(self, pair_host: 'np.ndarray', decoded: List[tuple],
-                    prefilled: List[tuple],
-                    spec_r: Optional[int]) -> None:
-        """The host bookkeeping of one consumed pair (the drain stage
-        of :meth:`_consume_one`)."""
-        now = time.time()
-        bad: set = set()
-        if self._sentinel:
-            # Sentinel row (appended LAST — all token-row indices are
-            # unchanged): flag 0 = this step produced non-finite
-            # logits for that slot. The failpoint simulates a device
-            # NaN on hosts without a corruptible chip.
-            flags = pair_host[pair_host.shape[0] - 1]
-            try:
-                failpoints.hit('infer.engine.sdc_nan')
-            except failpoints.FailpointError:
-                flags = np.zeros_like(flags)
-            bad = {s for s in range(flags.shape[0]) if not flags[s]}
-        touched: List[Request] = []
-        with self._lock:
-            # The family's step counts: rows 2.. of a decode pair, the
-            # same value in every column (``_decode_paged``).
-            for j, name in enumerate(self._step_stats):
-                self._model_counters[name] += int(pair_host[2 + j, 0])
-            for slot, req in prefilled:
-                if req is None or req.done or self._slots[slot] is not req:
-                    continue   # finished/preempted since dispatch
-                if slot in bad:
-                    self._sdc_hit(slot, req)
-                    continue
-                first = int(pair_host[0, slot])
-                if req.first_token_at is None:
-                    req.first_token_at = now
-                    self._ttfts.append(now - req.submitted_at)
-                    self._sched.note_first_token(
-                        req, now - req.submitted_at)
-                    self._sl_first_token(req, now - req.submitted_at)
-                req.output_tokens.append(first)
-                self._decode_tokens += 1
-                self._sched.note_tokens(req)
-                touched.append(req)
-                if self._finished(req, slot, first):
-                    # First token already ends the request; the second
-                    # token decoded the same step dies with the slot.
-                    self._finish(slot, req)
-            if spec_r is None:
-                for slot, req in decoded:
-                    self._inflight_tok[slot] = max(
-                        0, self._inflight_tok[slot] - 1)
-                    if (req is None or req.done
-                            or self._slots[slot] is not req):
-                        continue   # stale-by-one: post-finish dropped
-                    if slot in bad:
-                        # Drop the garbage token; tear the slot down.
-                        self._sdc_hit(slot, req)
-                        continue
-                    token = int(pair_host[1, slot])
-                    req.output_tokens.append(token)
-                    self._slot_len[slot] += 1
-                    self._decode_tokens += 1
-                    self._sched.note_tokens(req)
-                    touched.append(req)
-                    if self._finished(req, slot, token):
-                        self._finish(slot, req)
-            else:
-                self._consume_verify(pair_host, decoded, spec_r,
-                                     touched, bad)
-        for req in touched:
-            if not req.done:       # _finish already notified
-                req._notify()
-
-    def _consume_verify(self, pair_host, decoded, spec_r,
-                        touched, bad=()) -> None:  # holds: _lock
-        """Verify-pair bookkeeping: emit the accepted run plus the
-        corrected token ONE token at a time through the exact same
-        finish ladder as plain decode — eos / max_tokens / cache_full
-        fire mid-run and drop the tail, which is precisely what
-        spec-off would have produced — then roll pages extended for
-        rejected draft positions back to the pool. ``decoded`` rows
-        are (slot, request-at-dispatch, draft_len); ``spec_r`` =
-        spec_k+1 (the accepted count sits in pair row spec_r+1)."""
-        for slot, req, dl in decoded:
-            self._inflight_tok[slot] = max(
-                0, self._inflight_tok[slot] - (dl + 1))
-            if req is None or req.done or self._slots[slot] is not req:
-                continue   # stale-by-one: post-finish tokens dropped
-            if slot in bad:
-                self._sdc_hit(slot, req)
-                continue
-            accepted = min(int(pair_host[spec_r + 1, slot]), dl)
-            if dl > 0:
-                # Only DRAFTING lanes feed the speculation gauges: a
-                # draft_len=0 slot co-riding this dispatch (sampled /
-                # opted-out / just-prefilled) emits exactly one token
-                # like plain decode, and counting it would dilute
-                # accepted_len_mean toward 1.0 under mixed traffic —
-                # the operator tuning spec_k would read the wrong
-                # signal.
-                self._spec_slot_steps += 1
-                self._spec_drafted += dl
-                self._spec_accepted += accepted
-                req.spec_steps += 1
-            for i in range(accepted + 1):
-                token = int(pair_host[1 + i, slot])
-                req.output_tokens.append(token)
-                self._slot_len[slot] += 1
-                self._decode_tokens += 1
-                if dl > 0:
-                    self._spec_emitted += 1
-                    req.spec_emitted += 1
-                self._sched.note_tokens(req)
-                if self._finished(req, slot, token):
-                    self._finish(slot, req)
-                    break
-            if req.done:
-                continue
-            touched.append(req)
-            if self.allocator is not None:
-                # Rejected-draft rollback: pages extended past the new
-                # frontier (the next token's write page is kept)
-                # return to the pool NOW, not at finish — rejected
-                # pages are freed, never leaked (the PR 4 refcount
-                # discipline applies, so a somehow-shared page merely
-                # loses this slot's reference).
-                self.allocator.shrink(slot,
-                                      int(self._slot_len[slot]) + 1)
-
-    def _sdc_hit(self, slot: int, req: Request) -> None:  # holds: _lock
-        """Non-finite logits observed for a live slot: the garbage
-        token is never appended; the request finishes with reason
-        'sdc'; the engine flips integrity_suspect (ONE-WAY — the
-        server's /health turns 503 "corrupt", admission sheds with the
-        quarantined marker, and the control plane's golden-probe loop
-        quarantines and replaces the replica). An 'sdc' anomaly dump
-        snapshots the flight recorder around the hit."""
-        self._sdc_events += 1
-        self._integrity_suspect = True
-        self._note_anomaly('sdc', {
-            'slot': slot, 'request_id': req.request_id,
-            'tenant': req.tenant})
-        self._finish_early(slot, req, 'sdc')
+            spec_r=draft_mat.shape[1] + 1))
 
     def integrity_suspect(self) -> bool:
         """One-way corruption verdict (the /health + admission read).
@@ -2549,20 +2392,12 @@ class InferenceEngine:
                              f'{",".join(map(str, req.output_tokens))}')
         return zlib.crc32(';'.join(parts).encode())
 
-    def _drain_inflight(self) -> None:
-        """Consume every in-flight step (host state catches up to the
-        device). Called before page-pressure decisions and by
-        ``set_pipeline_depth``."""
-        while self._queue:
-            self._consume_one()
-
     def set_pipeline_depth(self, depth: int) -> None:
         """Change the dispatch-ahead depth at runtime. The multihost
         lockstep driver pins 0: its tick protocol requires every host
         to observe identical request state after each tick."""
         self._depth = max(0, int(depth))
-        while len(self._queue) > self._depth:
-            self._consume_one()
+        self._consume_to(self._depth)
 
     def set_wallclock_cancel(self, enabled: bool) -> None:
         """Enable/disable the deadline + client-cancel sweeps. The
@@ -2632,12 +2467,15 @@ class InferenceEngine:
 
     # ---- flight recorder -------------------------------------------------
     def _sl_first_token(self, req: Request,  # holds: _lock
-                        ttft: float) -> None:
+                        ttft: float, early: bool = False) -> None:
         """Timeline event + the TTFT-SLO anomaly trigger, at the one
-        moment TTFT becomes known."""
+        moment TTFT becomes known. ``early``: read from the chunk's
+        own record (``inflight.FirstToken``), not with a step pair."""
+        self._first_tokens += 1
+        self._first_tokens_early += early
         self._stepline.note_event(
             req.request_id, req.tenant, 'first_token',
-            req.first_token_at, ttft_s=round(ttft, 6))
+            req.first_token_at, ttft_s=round(ttft, 6), early=int(early))
         slo = self.ecfg.ttft_slo_s
         if slo is not None and ttft > slo:
             self._note_anomaly('ttft_slo', {
@@ -2874,6 +2712,8 @@ class InferenceEngine:
                 launches=self._launches,
                 launches_dev_empty=self._launches_dev_empty,
                 launches_after_wait=self._launches_after_wait,
+                first_tokens=self._first_tokens,
+                first_tokens_early=self._first_tokens_early,
                 model_counters=dict(self._model_counters))
             return (list(self._ttfts), list(self._queue_waits),
                     self._sched.snapshot(), counters,
@@ -2973,6 +2813,11 @@ class InferenceEngine:
             'launches': c['launches'],
             'launches_device_empty': c['launches_dev_empty'],
             'launches_after_wait': c['launches_after_wait'],
+            # First tokens stamped, and of those the ones read as soon
+            # as the prompt's last chunk had ended (every one but the
+            # fused mixed step's, and a request finished before any).
+            'first_token_total': c['first_tokens'],
+            'first_token_early_total': c['first_tokens_early'],
             # Data-integrity plane (docs/robustness.md "Data
             # integrity"): on-device sentinel hits and the one-way
             # corruption verdict ('ok'/'suspect' — a state set in the
@@ -3368,7 +3213,8 @@ class EnginePool:
             'engine_wait_s': tiers[0]['engine_wait_s'],
             **{k: sum(t[k] for t in tiers)
                for k in ('launches', 'launches_device_empty',
-                         'launches_after_wait')},
+                         'launches_after_wait', 'first_token_total',
+                         'first_token_early_total')},
             # Integrity: one suspect tier poisons the whole pool (the
             # tiers share a chip — corruption is a device property).
             'sdc_events_total': sum(t.get('sdc_events_total', 0)

@@ -159,6 +159,12 @@ def replica_exposition() -> Dict[str, Tuple[str, str]]:
             'sky_tpu_engine_launches_device_empty', 'counter'),
         'launches_after_wait': (
             'sky_tpu_engine_launches_after_wait', 'counter'),
+        # How first tokens were read (docs/serving.md "The decode
+        # pipeline").
+        'first_token_total': (
+            'sky_tpu_engine_first_token_total', 'counter'),
+        'first_token_early_total': (
+            'sky_tpu_engine_first_token_early_total', 'counter'),
         # Data-integrity plane (docs/robustness.md "Data integrity");
         # the string-valued ``integrity`` state renders as a labeled
         # state-set, not a scalar.

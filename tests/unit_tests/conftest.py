@@ -121,6 +121,21 @@ def _greedy_oracle(params, prompts, max_new_tokens):
             for i, p in enumerate(prompts)]
 
 
+def tiny_state_model():
+    """``(config, params)`` of the one state family the engine tests
+    run beside the dense block: Falcon-H1's tiny preset in float32 (a
+    Mamba-2 state beside K/V pages in every block)."""
+    import dataclasses
+
+    import jax
+
+    from skypilot_tpu.models import falcon_h1
+    from skypilot_tpu.models import interface
+    config = dataclasses.replace(falcon_h1.FalconH1Config.tiny(),
+                                 dtype='float32')
+    return config, interface.init_params(config, jax.random.PRNGKey(0))
+
+
 @pytest.fixture(scope='session')
 def tiny_params():
     return tiny_llama_params()

@@ -14,16 +14,32 @@ contracts that staleness must never break:
   recompiles.
 - Token delivery is event-driven: waiters wake on append/finish, not
   on a poll cadence.
+- A prompt's FIRST token is read by its chunk's own in-flight record
+  (``infer/inflight.py``), ahead of the decode pair dispatched behind
+  that chunk: one token on the first notify, the same tokens at every
+  depth, a stale record dropped, the sentinel's flag beside the token,
+  and both kinds of record drained before the engine reads idle. Over
+  the dense cache, the paged one and one state family (Falcon-H1).
 """
+import dataclasses
+import hashlib
 import threading
+import time
 
 import pytest
 
 pytestmark = pytest.mark.jax
 
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
 from skypilot_tpu.infer import engine as engine_lib  # noqa: E402
+from skypilot_tpu.infer import inflight  # noqa: E402
 from skypilot_tpu.infer import server as server_lib  # noqa: E402
 from skypilot_tpu.models import llama  # noqa: E402
+from skypilot_tpu.utils import failpoints  # noqa: E402
+
+from tests.unit_tests import conftest  # noqa: E402
 
 CFG = llama.LlamaConfig.tiny()
 
@@ -297,6 +313,308 @@ def test_set_pipeline_depth_drains(params):
     assert req.done and len(req.output_tokens) == 8
 
 
+# ---- the first token's early read (infer/inflight.py) ---------------------
+
+FAMILIES = ('dense', 'paged', 'state')
+_BUSY = [11] * 40          # two chunks; decoding while the next arrives
+_LATE = [5, 17, 101, 7]    # one chunk: its first dispatch ends its prompt
+# What the PARENT commit (82a9fff) generated for ``_BUSY`` / ``_LATE``
+# (8 / 5 tokens) through the same engines; dense and paged are also the
+# no-cache float32 oracle's (``greedy_oracle``).
+_AT_PARENT = {
+    'dense': None, 'paged': None,   # the oracle's: checked against it
+    'state': [[186, 418, 69, 505, 264, 504, 209, 438],
+              [405, 423, 459, 171, 481]],
+}
+# sha256 of ``eng._decode.lower(...).as_text()`` at the parent commit:
+# the decode programs did not change, only the chunk programs gained a
+# result (``python tests/unit_tests/test_infer_pipeline.py`` prints
+# the table; a PR that MEANS to change a decode program replaces it).
+_DECODE_AT_PARENT = {
+    'dense':
+        '7d739f5b16a991492764a5b271a2d0bacece0faaf75533d9246504bc2e6eeb84',
+    'paged':
+        'd7ab86ffdd3892ef89d16e768e956279d1c4bf8e8834a7244766c6085007adc6',
+    'state':
+        '26df6c7efefe5d01b2245432e980308af1e5c1655d5884e11e2fa2d503e2953b',
+}
+
+
+def _family_engine(family, tiny_params):
+    kw = dict(n_slots=3, max_seq_len=128, prefill_buckets=(16, 32),
+              prefill_chunk=32, pipeline_depth=1)
+    if family == 'dense':
+        return engine_lib.InferenceEngine(
+            CFG, tiny_params, engine_lib.EngineConfig(**kw))
+    kw.update(paged=True, page_size=16, n_pages=40)
+    if family == 'paged':
+        return engine_lib.InferenceEngine(
+            CFG, tiny_params, engine_lib.EngineConfig(**kw))
+    return engine_lib.InferenceEngine(
+        *conftest.tiny_state_model(),
+        engine_lib.EngineConfig(cache_dtype='float32', **kw))
+
+
+@pytest.fixture(scope='module')
+def first_engines(params):
+    """One depth-1 engine a family, built on first use and shared:
+    every test leaves it idle, at depth 1 and clean of verdicts."""
+    built = {}
+
+    def get(family):
+        if family not in built:
+            built[family] = _family_engine(family, params)
+        eng = built[family]
+        assert eng.idle() and eng._depth == 1
+        return eng
+    return get
+
+
+def _decode_digest(eng):
+    args = [eng.cache, eng.params]
+    if eng.allocator is not None:
+        tables = jnp.asarray(eng.allocator.table())
+        if eng.window_alloc is not None:
+            tables = (tables, jnp.asarray(eng.window_alloc.table()))
+        args.append(tables)
+    args += [eng._last_dev, jax.random.PRNGKey(0),
+             jnp.zeros((eng.ecfg.n_slots,), jnp.float32),
+             jnp.zeros((eng.ecfg.n_slots,), jnp.bool_)]
+    text = eng._decode.lower(*args).as_text()
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def _kinds(eng):
+    return [type(rec).__name__ for rec in eng._queue]
+
+
+def _spy(eng, monkeypatch):
+    """Log every record as the consume ladder applies it."""
+    log = []
+    first, pair = eng._apply_first, eng._apply_pair
+
+    def apply_first(host, rec):
+        log.append(('first', rec, time.time()))
+        first(host, rec)
+
+    def apply_pair(host, rec):
+        log.append(('pair', rec, time.time()))
+        pair(host, rec)
+    monkeypatch.setattr(eng, '_apply_first', apply_first)
+    monkeypatch.setattr(eng, '_apply_pair', apply_pair)
+    return log
+
+
+def _event(eng, req, name):
+    return [ev for ev in eng.stepline_snapshot()['events']
+            if ev['request_id'] == req.request_id and ev['event'] == name]
+
+
+def _decoding(eng):
+    """A request far enough in to be decoding, so that whatever
+    arrives next finds a decode in flight."""
+    busy = eng.submit(_BUSY, max_new_tokens=12)
+    while len(busy.output_tokens) < 2:
+        eng.step()
+    return busy
+
+
+@pytest.mark.parametrize('family', FAMILIES)
+def test_first_token_is_read_ahead_of_the_pair_behind_its_chunk(
+        first_engines, family, monkeypatch):
+    eng = first_engines(family)
+    log = _spy(eng, monkeypatch)
+    before = eng.metrics()
+    busy = _decoding(eng)
+    late = eng.submit(_LATE, max_new_tokens=5)
+    seen = []
+    late.add_listener(lambda: seen.append(len(late.output_tokens)))
+    eng.step()      # the late prompt's one chunk, and the decode behind
+    recs = list(eng._queue)
+    (i,) = [k for k, rec in enumerate(recs)
+            if isinstance(rec, inflight.FirstToken) and rec.req is late]
+    behind = recs[i + 1]
+    assert isinstance(behind, inflight.StepPair)
+    assert sorted(req.request_id for _, req in behind.decoded) == [
+        busy.request_id, late.request_id]
+    assert not behind.prefilled and not late.output_tokens
+    # depth 1 counts pairs: the first-token record is not one of them
+    assert eng._queue.pairs() <= 1 < len(eng._queue)
+    eng.run_until_idle()
+    order = [(kind, rec) for kind, rec, _ in log]
+    k_first = order.index(('first', recs[i]))
+    k_pair = order.index(('pair', behind))
+    assert k_first < k_pair
+    # the first notify carries ONE token, and the pair behind the
+    # chunk only the second
+    assert seen[0] == 1 and seen[1] == 2
+    (ev,) = _event(eng, late, 'first_token')
+    assert ev['early'] == 1 and ev['t'] <= log[k_pair][2]
+    assert late.first_token_at == ev['t']
+    m = eng.metrics()
+    assert m['first_token_total'] - before['first_token_total'] == 2
+    assert (m['first_token_early_total']
+            - before['first_token_early_total']) == 2
+    assert len(late.output_tokens) == 5 and len(busy.output_tokens) == 12
+
+
+@pytest.mark.parametrize('family', FAMILIES)
+def test_greedy_tokens_are_the_parents_at_depth_0_and_1(
+        first_engines, family, greedy_oracle):
+    eng = first_engines(family)
+    prompts, n = [_BUSY, _LATE], [8, 5]
+
+    def run():
+        reqs = [eng.submit(p, max_new_tokens=k)
+                for p, k in zip(prompts, n)]
+        eng.run_until_idle()
+        return [r.output_tokens for r in reqs]
+    out1 = run()
+    eng.set_pipeline_depth(0)
+    try:
+        out0 = run()
+    finally:
+        eng.set_pipeline_depth(1)
+    assert out0 == out1
+    want = _AT_PARENT[family] or [
+        greedy_oracle([p], k)[0] for p, k in zip(prompts, n)]
+    assert out1 == want
+
+
+@pytest.mark.parametrize('how', ['max_tokens', 'eos'])
+@pytest.mark.parametrize('family', FAMILIES)
+def test_a_request_its_first_token_ends_finishes_at_the_first_record(
+        first_engines, family, how, monkeypatch):
+    eng = first_engines(family)
+    [probe] = eng.generate([_LATE], max_new_tokens=2)
+    first = probe.output_tokens[0]
+    if how == 'eos':
+        monkeypatch.setattr(eng, 'ecfg', dataclasses.replace(
+            eng.ecfg, eos_id=first))
+    log = _spy(eng, monkeypatch)
+    busy = _decoding(eng)
+    req = eng.submit(_LATE, max_new_tokens=1 if how == 'max_tokens' else 9)
+    seen = []
+
+    def pairs_read():
+        return sum(1 for kind, rec, _ in log if kind == 'pair'
+                   and any(r is req for _, r in rec.decoded))
+    req.add_listener(lambda: seen.append(
+        (len(req.output_tokens), req.done, pairs_read())))
+    while not req.done:
+        eng.step()
+    # ONE notify: done with one token, inside the first-token record's
+    # apply, the pair behind the chunk still unread (its lane for this
+    # slot is dropped when it is)
+    assert seen == [(1, True, 0)]
+    assert req.finish_reason == how
+    assert req.output_tokens == [first]
+    (ev,) = _event(eng, req, 'first_token')
+    (done,) = _event(eng, req, 'done')
+    assert ev['early'] == 1 and done['tokens'] == 1
+    eng.run_until_idle()
+    assert req.output_tokens == [first] and len(busy.output_tokens) == 12
+    assert eng.metrics()['tokens_in_flight'] == 0
+
+
+@pytest.mark.parametrize('family', FAMILIES)
+def test_a_slot_preempted_before_the_read_emits_nothing_and_reprefills(
+        first_engines, family):
+    eng = first_engines(family)
+    [want] = eng.generate([_LATE], max_new_tokens=5)
+    preempted = eng.metrics().get('preemptions', 0)
+    busy = _decoding(eng)
+    req = eng.submit(_LATE, max_new_tokens=5)
+    seen = []
+    req.add_listener(lambda: seen.append(len(req.output_tokens)))
+    eng.step()
+    (slot,) = [rec.slot for rec in eng._queue
+               if isinstance(rec, inflight.FirstToken) and rec.req is req]
+    eng._preempt(slot)          # between the chunk's dispatch and the read
+    eng._drain_inflight()
+    assert req.output_tokens == [] and seen == []
+    assert not _event(eng, req, 'first_token')
+    eng.run_until_idle()
+    assert req.output_tokens == want.output_tokens
+    assert len(_event(eng, req, 'first_token')) == 1
+    assert _event(eng, req, 'resume')
+    assert len(busy.output_tokens) == 12
+    if eng.allocator is not None:
+        assert eng.metrics()['preemptions'] == preempted + 1
+        assert eng.allocator.free_pages == eng.allocator.n_pages - 1
+
+
+@pytest.mark.parametrize('family', FAMILIES)
+def test_both_kinds_of_record_are_drained(first_engines, family):
+    eng = first_engines(family)
+    req = eng.submit(_LATE, max_new_tokens=6)
+    eng.step()
+    assert _kinds(eng) == ['FirstToken', 'StepPair']
+    assert not eng.idle() and not req.output_tokens
+    eng._consume_one()                       # the first-token record
+    assert _kinds(eng) == ['StepPair'] and len(req.output_tokens) == 1
+    assert not eng.idle()
+    eng.run_until_idle()
+    req = eng.submit(_LATE, max_new_tokens=6)
+    eng.step()
+    assert _kinds(eng) == ['FirstToken', 'StepPair']
+    eng.set_pipeline_depth(0)
+    try:
+        assert not eng._queue, 'depth 0 leaves neither kind in flight'
+        assert len(req.output_tokens) == 2
+        assert eng.metrics()['tokens_in_flight'] == 0
+    finally:
+        eng.set_pipeline_depth(1)
+    eng.step()
+    assert eng._queue.pairs() == 1
+    eng._drain_inflight()
+    assert not eng._queue
+    eng.run_until_idle()
+    assert eng.idle() and len(req.output_tokens) == 6
+
+
+@pytest.mark.parametrize('family', FAMILIES)
+def test_sentinel_stops_a_first_token_its_chunk_logits_spoiled(
+        first_engines, family, monkeypatch):
+    """The failpoint stands for a device NaN in the ONE row of logits
+    the chunk samples from: the flag rides beside the token, so the
+    token is never appended, notified or counted."""
+    eng = first_engines(family)
+    busy = _decoding(eng)
+    before = eng.metrics()
+    req = eng.submit(_LATE, max_new_tokens=5)
+    seen = []
+    req.add_listener(lambda: seen.append(len(req.output_tokens)))
+    eng.step()
+    assert _kinds(eng)[-2:] == ['FirstToken', 'StepPair']
+    while not isinstance(eng._queue[0], inflight.FirstToken):
+        eng._consume_one()
+    failpoints._reset_for_tests()
+    monkeypatch.setenv('SKY_TPU_FAILPOINTS', 'infer.engine.sdc_nan=error@1')
+    try:
+        eng._consume_one()
+        assert failpoints.fired('infer.engine.sdc_nan') == 1
+        assert req.done and req.finish_reason == 'sdc'
+        assert req.output_tokens == [] and seen == [0]   # the finish only
+        assert not _event(eng, req, 'first_token')
+        assert eng.integrity_suspect()
+        m = eng.metrics()
+        assert m['sdc_events_total'] == before['sdc_events_total'] + 1
+        assert m['first_token_total'] == before['first_token_total']
+        eng.run_until_idle()
+        assert len(busy.output_tokens) == 12 and busy.finish_reason != 'sdc'
+    finally:
+        monkeypatch.delenv('SKY_TPU_FAILPOINTS')
+        failpoints._reset_for_tests()
+        eng._integrity_suspect = False      # the fixture is shared
+
+
+@pytest.mark.parametrize('family', FAMILIES)
+def test_decode_program_lowers_to_the_parents_stablehlo(first_engines,
+                                                        family):
+    assert _decode_digest(first_engines(family)) == _DECODE_AT_PARENT[family]
+
+
 class _CountingTokenizer(server_lib.Tokenizer):
     """Byte tokenizer that counts token positions decoded — the O(n)
     evidence for the incremental streaming detokenizer."""
@@ -384,3 +702,18 @@ def test_incremental_decoder_matches_cumulative_on_byte_soup():
         emitted += dec.feed(tokens[:min(n, len(tokens))])
     emitted += dec.flush(tokens)
     assert emitted == tok.decode(tokens)
+
+
+if __name__ == '__main__':
+    import json
+
+    weights = conftest.tiny_llama_params()
+    table = {}
+    for fam in FAMILIES:
+        eng = _family_engine(fam, weights)
+        reqs = [eng.submit(_BUSY, max_new_tokens=8),
+                eng.submit(_LATE, max_new_tokens=5)]
+        eng.run_until_idle()
+        table[fam] = {'decode': _decode_digest(eng),
+                      'tokens': [r.output_tokens for r in reqs]}
+    print(json.dumps(table, indent=1))

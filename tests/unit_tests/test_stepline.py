@@ -260,8 +260,8 @@ def test_a_blocked_readback_is_wall_time_but_not_cpu_time(params):
     eng.submit([5] * 5, max_new_tokens=6)
     while not eng._queue:
         eng.step()
-    pair, *rest = eng._queue[0]
-    eng._queue[0] = (_SlowPair(pair, 0.3), *rest)
+    rec = eng._queue[0]
+    rec.out = _SlowPair(rec.out, 0.3)
     before = eng.stepline_snapshot()['steps_total']
     eng.step()
     rec = next(r for r in eng.stepline_snapshot()['steps']

@@ -104,6 +104,7 @@ def served(params):
                 via_lb, PROMPTS[0], 2,
                 headers={common_lib.LB_RECV_HEADER: '12.5'})
             snap = await (await direct.get('/debug/stepline')).json()
+            out['metrics'] = await (await direct.get('/metrics')).json()
         finally:
             await via_lb.close()
             await lb._session.close()
@@ -149,6 +150,98 @@ def test_a_one_token_answer_is_done_at_its_first_token(served):
     assert first and done and first['t'] <= done['t']
     flush = _first(evs, 'first_flush')
     assert flush and flush['t'] >= first['t']
+
+
+def test_every_first_token_was_read_early_and_counted(served):
+    """No request of the fixture rode a fused step, so each first token
+    came from its chunk's own record: the event says so, and the two
+    ``/metrics`` counters agree with the count of requests."""
+    by = _by_request(served['snapshot'])
+    rids = (list(served['direct']) + list(served['lb'])
+            + [served[k] for k in ('one_token', 'bad_header', 'planted')])
+    for rid in rids:
+        assert _first(by[rid], 'first_token')['early'] == 1, rid
+    m = served['metrics']
+    # request 1 was the server's own warm-up
+    assert min(rids) == 2 and m['first_token_total'] == len(rids) + 1
+    assert m['first_token_early_total'] == m['first_token_total']
+
+
+FAMILIES = ('dense', 'paged', 'state')
+
+
+def _family_engine(family, params):
+    if family == 'state':
+        from tests.unit_tests import conftest
+        return engine_lib.InferenceEngine(
+            *conftest.tiny_state_model(), _ecfg(cache_dtype='float32'))
+    if family == 'dense':
+        return engine_lib.InferenceEngine(
+            CFG, params, engine_lib.EngineConfig(
+                n_slots=3, max_seq_len=128, prefill_buckets=(16, 32),
+                prefill_chunk=CHUNK, pipeline_depth=1))
+    return engine_lib.InferenceEngine(CFG, params, _ecfg())
+
+
+@pytest.mark.parametrize('family', FAMILIES)
+def test_the_first_streamed_line_holds_one_token(params, family):
+    """A request that arrives while another decodes: its first token
+    leaves the server in a line of its own, stamped before the engine
+    consumed the decode pair dispatched behind its last chunk (the
+    pair's consume is slowed here, so the handler is never raced)."""
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from skypilot_tpu.infer import inflight
+    from skypilot_tpu.infer import server as server_lib
+
+    eng = _family_engine(family, params)
+    eng.generate([PROMPTS[0]], max_new_tokens=2)       # compile outside
+    pairs = []          # (wall time, lanes) of each pair's consume
+    apply_pair = eng._apply_pair
+
+    def slow_pair(host, rec):
+        time.sleep(0.03)
+        pairs.append((time.time(), [req.request_id
+                                    for _, req, *_ in rec.decoded]))
+        apply_pair(host, rec)
+    eng._apply_pair = slow_pair
+
+    async def lines_of(client, tokens, max_new):
+        r = await client.post('/generate', json={
+            'tokens': tokens, 'max_new_tokens': max_new, 'stream': True})
+        assert r.status == 200, await r.text()
+        return [json.loads(ln) for ln in (await r.text()).splitlines()
+                if ln]
+
+    async def flow():
+        srv = server_lib.InferenceServer(eng)
+        srv._thread.start()
+        client = TestClient(TestServer(srv.make_app()))
+        await client.start_server()
+        try:
+            busy = asyncio.ensure_future(lines_of(client, PROMPTS[1], 24))
+            while eng.metrics()['decode_tokens'] < 4:   # it is decoding
+                await asyncio.sleep(0.005)
+            late = await lines_of(client, PROMPTS[0], 4)
+            await busy
+            snap = await (await client.get('/debug/stepline')).json()
+        finally:
+            await client.close()
+            srv._stop.set()
+        return late, snap
+
+    late, snap = asyncio.run(flow())
+    rid = late[-1]['request_id']
+    assert late[-1]['done'] and len(late[0]['tokens']) == 1
+    assert sum(len(ln.get('tokens', [])) for ln in late) == 4
+    evs = _by_request(snap)[rid]
+    first = _first(evs, 'first_token')
+    assert first['early'] == 1
+    # the first pair that carried this request was consumed after its
+    # first token was stamped: that pair brought the SECOND token
+    behind = next(t for t, lanes in pairs if rid in lanes)
+    assert first['t'] < behind
+    assert isinstance(eng._queue, inflight.Queue) and eng.idle()
 
 
 def test_the_lb_header_starts_the_timeline(served):
