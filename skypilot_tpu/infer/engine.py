@@ -508,6 +508,8 @@ class InferenceEngine(inflight.ConsumeLadder):
         # own record, not a step pair's row 0).
         '_first_tokens': '_lock:mut',
         '_first_tokens_early': '_lock:mut',
+        # K/V rows the decode and verify steps hand the page writer.
+        '_kv_rows_written': '_lock:mut',
     }
 
     def __init__(self, config: llama.LlamaConfig, params: llama.Params,
@@ -522,6 +524,10 @@ class InferenceEngine(inflight.ConsumeLadder):
         spec = interface.cache_spec(config)
         steps = model_lib.paged_steps(config)
         self._state_spec = spec.state
+        # Layers whose decode and verify steps write K/V rows into the
+        # page pool (ops/paged_attention.paged_row_write): a step
+        # writes this many rows for each live slot and run position.
+        self._row_layers = spec.kv_layers if self.ecfg.paged else 0
         # Counts the decode program returns beside its logits (a hybrid
         # model's expert and state counters), carried out on the step's
         # pair and summed here; /metrics shows them.
@@ -784,6 +790,7 @@ class InferenceEngine(inflight.ConsumeLadder):
         self._launches_after_wait = 0
         self._first_tokens = 0
         self._first_tokens_early = 0
+        self._kv_rows_written = 0
 
         # ---- compiled programs ------------------------------------------
         # Params are ARGUMENTS, never closure-captured: captured arrays
@@ -890,7 +897,7 @@ class InferenceEngine(inflight.ConsumeLadder):
                 tokens = jnp.concatenate([last[:, None], drafts],
                                          axis=1)
                 logits, new_cache = steps.verify(
-                    config, params, kv_cache, tables, tokens)
+                    config, params, kv_cache, tables, tokens, active)
                 pair, new_last, lengths = _accept(
                     tokens, logits, drafts, draft_len, key, temps,
                     active, new_cache.lengths)
@@ -2195,6 +2202,7 @@ class InferenceEngine(inflight.ConsumeLadder):
             # engine thread), and tokens_per_step never divides by a
             # step count the token counter hasn't caught up with.
             self._decode_steps += 1
+            self._kv_rows_written += len(decoding) * self._row_layers
             for s in decoding:
                 self._inflight_tok[s] += 1
         self._queue.append(inflight.StepPair(
@@ -2240,6 +2248,7 @@ class InferenceEngine(inflight.ConsumeLadder):
         self._sl_batch = len(decoding)
         with self._lock:
             self._decode_steps += 1
+            self._kv_rows_written += len(decoding) * self._row_layers
             self._fused_steps += 1
             self._prefill_tokens += plan.tl
             self._note_prefill_dispatched(plan)
@@ -2359,6 +2368,8 @@ class InferenceEngine(inflight.ConsumeLadder):
         self._sl_batch = len(decoding)
         with self._lock:
             self._decode_steps += 1
+            self._kv_rows_written += (len(decoding) * self._row_layers
+                                      * (draft_mat.shape[1] + 1))
             self._spec_steps += 1
             for s in decoding:
                 self._inflight_tok[s] += int(draft_lens[s]) + 1
@@ -2714,6 +2725,7 @@ class InferenceEngine(inflight.ConsumeLadder):
                 launches_after_wait=self._launches_after_wait,
                 first_tokens=self._first_tokens,
                 first_tokens_early=self._first_tokens_early,
+                kv_rows_written=self._kv_rows_written,
                 model_counters=dict(self._model_counters))
             return (list(self._ttfts), list(self._queue_waits),
                     self._sched.snapshot(), counters,
@@ -2818,6 +2830,12 @@ class InferenceEngine(inflight.ConsumeLadder):
             # fused mixed step's, and a request finished before any).
             'first_token_total': c['first_tokens'],
             'first_token_early_total': c['first_tokens_early'],
+            # K/V rows the decode, mixed and verify steps wrote into
+            # the page pool: live slots x paging layers x rows a slot
+            # (1, or spec_k + 1 in a verify step); beside
+            # decode_steps, how much of the pool's slot rows the row
+            # writer touches.
+            'kv_rows_written_total': c['kv_rows_written'],
             # Data-integrity plane (docs/robustness.md "Data
             # integrity"): on-device sentinel hits and the one-way
             # corruption verdict ('ok'/'suspect' — a state set in the
@@ -3214,7 +3232,8 @@ class EnginePool:
             **{k: sum(t[k] for t in tiers)
                for k in ('launches', 'launches_device_empty',
                          'launches_after_wait', 'first_token_total',
-                         'first_token_early_total')},
+                         'first_token_early_total',
+                         'kv_rows_written_total')},
             # Integrity: one suspect tier poisons the whole pool (the
             # tiers share a chip — corruption is a device property).
             'sdc_events_total': sum(t.get('sdc_events_total', 0)

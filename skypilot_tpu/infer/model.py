@@ -16,9 +16,11 @@ cache. The paged programs keep that promise all the way down: the page
 pool (every layer's pages folded into one page axis,
 infer/paged_cache.py) is a loop CARRY of the layer scan
 (``_scan_layers``), never per-layer ``xs``/``ys``. A layer writes only
-its new rows into the carried pool (``dynamic_update_slice``s, in
-place) and its attention kernel reads the layer's pages out of the same
-buffer through a block table that carries the layer's offset. So the
+its new rows into the carried pool, in place (a prefill chunk's pages
+by ``dynamic_update_slice``s, a decode or verify step's rows by the
+``paged_row_write`` kernel, aliased onto its result) and its attention
+kernel reads the layer's pages out of the same buffer through a block
+table that carries the layer's offset. So the
 donated input pool IS the output pool: no second pool is allocated and
 no layer's slab is sliced out, stacked back or relaid.
 
@@ -295,8 +297,9 @@ def paged_decode_step(config: llama.LlamaConfig, params: llama.Params,
     the pages that the ACTIVE slots own, sum(ceil(len_i/page)) of
     them, and nothing for a slot that is not active (`_attended` hands
     it over with length 0): its row of the logits is computed from a
-    zero attention output and ignored by the engine, like its K/V row,
-    which still lands at its frontier.
+    zero attention output and ignored by the engine, and the row writer
+    (``paged_row_write``) writes no K/V row of it (on the int8 flavor
+    its garbage row still lands at its frontier).
 
     The engine guarantees every active slot's table covers position
     lengths[slot] (the incoming token's write target).
@@ -352,7 +355,8 @@ def _paged_decode_layer(config, x, layer, cos, sin, pkv, block_tables,
     with jax.named_scope('kv_write'):
         pkv = _with_pages(pkv, paged_attn.append_token_pages(
             pkv.k_pages, pkv.v_pages, k[:, 0], v[:, 0], block_tables,
-            positions, pkv.k_scales, pkv.v_scales, sink_page=sink_page))
+            positions, pkv.k_scales, pkv.v_scales, sink_page=sink_page,
+            live=attend > 0))
     with jax.named_scope('attn'):
         qg = q[:, 0].reshape(slots, hkv, group, hd)
         att = paged_attn.paged_decode_attention(
@@ -441,7 +445,8 @@ def _verify_layer(config, x, layer, cos, sin, k_cache, v_cache,
 
 def paged_verify_step(config: llama.LlamaConfig, params: llama.Params,
                       pkv: paged_cache_lib.PagedKVCache,
-                      block_tables: jnp.ndarray, tokens: jnp.ndarray
+                      block_tables: jnp.ndarray, tokens: jnp.ndarray,
+                      active: Optional[jnp.ndarray] = None
                       ) -> Tuple[jnp.ndarray,
                                  paged_cache_lib.PagedKVCache]:
     """verify_step over the paged cache: the run's K/V land in the
@@ -449,7 +454,9 @@ def paged_verify_step(config: llama.LlamaConfig, params: llama.Params,
     the sink page) and all R queries stream each owned page ONCE via
     the verify kernel — the bandwidth bill of a single decode step for
     up to R tokens of progress. ``lengths`` is not advanced (the
-    engine bumps by accepted+1)."""
+    engine bumps by accepted+1). A slot that is not ``active`` writes
+    no row (None: every slot writes); its logits are garbage the
+    engine throws away."""
     slots, R = tokens.shape
     positions = pkv.lengths[:, None] + jnp.arange(
         R, dtype=jnp.int32)[None, :]                  # [slots, R]
@@ -464,7 +471,7 @@ def paged_verify_step(config: llama.LlamaConfig, params: llama.Params,
     def layer_fn(h, layer, kv, physical):
         return _paged_verify_layer(
             config, h, layer, cos, sin, kv, physical(block_tables),
-            positions, lengths, physical(0))
+            positions, lengths, physical(0), active)
 
     x, pkv = _scan_layers(params, pkv, x, layer_fn)
     with jax.named_scope('head'):
@@ -475,10 +482,11 @@ def paged_verify_step(config: llama.LlamaConfig, params: llama.Params,
 
 
 def _paged_verify_layer(config, x, layer, cos, sin, pkv, block_tables,
-                        positions, lengths, sink_page):
+                        positions, lengths, sink_page, active):
     """One layer of the paged verify step. pkv: the whole folded pool;
     block_tables / sink_page: this layer's PHYSICAL page ids (the sink
-    is the layer's own page 0); x: [slots, R, d]."""
+    is the layer's own page 0); x: [slots, R, d]; active: the slots
+    whose rows are written (None: all)."""
     slots, R, d = x.shape
     hq, hkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     group = hq // hkv
@@ -491,7 +499,8 @@ def _paged_verify_layer(config, x, layer, cos, sin, pkv, block_tables,
     with jax.named_scope('kv_write'):
         pkv = _with_pages(pkv, paged_attn.append_run_pages(
             pkv.k_pages, pkv.v_pages, k, v, block_tables, lengths,
-            pkv.k_scales, pkv.v_scales, sink_page=sink_page))
+            pkv.k_scales, pkv.v_scales, sink_page=sink_page,
+            live=active))
     with jax.named_scope('attn'):
         qg = q.reshape(slots, R, hkv, group, hd)
         att = paged_attn.paged_verify_attention(
@@ -733,7 +742,7 @@ def _state_attn_decode(q, k, v, kv, block_tables, positions, attend,
     with jax.named_scope('kv_write'):
         kv = _with_pages(kv, paged_attn.append_token_pages(
             kv.k_pages, kv.v_pages, k, v, block_tables, positions, None,
-            None, sink_page=sink_page))
+            None, sink_page=sink_page, live=attend > 0))
     with jax.named_scope('attn'):
         att = paged_attn.paged_decode_attention(
             q, kv.k_pages, kv.v_pages, block_tables, attend)
@@ -826,7 +835,7 @@ def hybrid_decode_step(config: Any, params: Any,
 
     A slot that is not ``active`` (free, or mid-way through a chunked
     prefill) computes garbage, as in every decode program. Its K/V row
-    lands where the next real write covers it; its recurrent state
+    is not written (``paged_row_write``); its recurrent state
     must not move at all, because nothing ever overwrites a state:
     the state kernel under ``mamba_mixer.decode`` moves the active
     slots' rows and no others, so the step's cost in state bytes is the

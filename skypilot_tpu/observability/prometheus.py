@@ -165,6 +165,9 @@ def replica_exposition() -> Dict[str, Tuple[str, str]]:
             'sky_tpu_engine_first_token_total', 'counter'),
         'first_token_early_total': (
             'sky_tpu_engine_first_token_early_total', 'counter'),
+        # Rows the page writer wrote (docs/observability.md).
+        'kv_rows_written_total': (
+            'sky_tpu_engine_kv_rows_written_total', 'counter'),
         # Data-integrity plane (docs/robustness.md "Data integrity");
         # the string-valued ``integrity`` state renders as a labeled
         # state-set, not a scalar.

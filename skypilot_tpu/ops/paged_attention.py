@@ -926,14 +926,171 @@ def paged_verify_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
+# Row writer: a decode or verify step's new K/V rows into their pages
+# ---------------------------------------------------------------------------
+def _row_tile(page_size: int, itemsize: int) -> int:
+    """Rows of a page that one of the writer's copies moves: a whole
+    tile of the pool's layout (8 sublanes of 32-bit words: 8 float32
+    rows, 16 bfloat16 rows), or the page where it is shorter. Mosaic
+    copies a slice of a page only along whole tiles, and a bfloat16 row
+    shares its words with its neighbour."""
+    return min(page_size, 8 * max(1, 4 // itemsize))
+
+
+def _row_write_kernel(tables_ref, horizon_ref, sink_ref, k_ref, v_ref,
+                      k_in, v_in, k_out, v_out, k_buf, v_buf, sems, *,
+                      page_size: int, max_pages: int, tile: int):
+    """Every live slot's R new rows into their pages, in ONE grid step:
+    the pools stay in HBM, aliased onto the results, and the kernel
+    walks the live slots itself, so a dead slot (horizon 0) costs a
+    scalar compare and writes nothing.
+
+    Row i of slot b lies at position horizon_ref[b] - 1 + i, in the
+    page the table names (``sink_ref[0]`` past the table's end). A row
+    goes in with the tile around it (``_row_tile``): the tile is copied
+    into VMEM, the row put in its place, the tile copied back. Rows of
+    one run index are worked together, every tile in flight at once,
+    since live slots write distinct pages; the rows that land in the
+    sink page, which slots share, go one at a time in slot order, after
+    the others. So the pools come out as a chain of row writes, in run
+    order and then slot order, would leave them."""
+    del k_in, v_in
+    slots, runs, hkv, hd = k_ref.shape
+    pools = ((k_ref, k_out, k_buf), (v_ref, v_out, v_buf))
+
+    def next_live(b):
+        return jax.lax.while_loop(
+            lambda b: jnp.logical_and(
+                b < slots, horizon_ref[jnp.minimum(b, slots - 1)] <= 0),
+            lambda b: b + 1, b)
+
+    def each_live(body, cond):
+        """body(b) for every live slot b for which cond(b) holds, in
+        slot order."""
+        def step(b):
+            pl.when(cond(b))(lambda: body(b))
+            return next_live(b + 1)
+        jax.lax.while_loop(lambda b: b < slots, step, next_live(0))
+
+    def target(b, i):
+        """(page, the tile's first row, the row's place in the tile)."""
+        pos = horizon_ref[b] - 1 + i
+        col = pos // page_size
+        pid = jnp.where(col < max_pages,
+                        tables_ref[b, jnp.minimum(col, max_pages - 1)],
+                        sink_ref[0])
+        at = pos % page_size
+        return pid, pl.multiple_of(at - at % tile, tile), at % tile
+
+    def copies(b, i, towards_page):
+        pid, first, _ = target(b, i)
+        out = []
+        for n, (_, hbm, buf) in enumerate(pools):
+            rows = hbm.at[:, pid, pl.ds(first, tile)]
+            src, dst = (buf.at[b], rows) if towards_page else (
+                rows, buf.at[b])
+            out.append(pltpu.make_async_copy(
+                src, dst, sems.at[int(towards_page), n]))
+        return out
+
+    def read(b, i):
+        for copy in copies(b, i, False):
+            copy.start()
+
+    def landed(b, i):
+        for copy in copies(b, i, False):
+            copy.wait()
+
+    def put_back(b, i):
+        """The row into its tile as read, the tile on its way back."""
+        mine = jax.lax.broadcasted_iota(
+            jnp.int32, (hkv, tile, hd), 1) == target(b, i)[2]
+        for new, _, buf in pools:
+            row = new[b, i][:, None, :].astype(buf.dtype)
+            buf[b] = jnp.where(mine, jnp.broadcast_to(row, mine.shape),
+                               buf[b])
+        for copy in copies(b, i, True):
+            copy.start()
+
+    def settle(b, i):
+        for copy in copies(b, i, True):
+            copy.wait()
+
+    def alone(b, i):
+        for act in (read, landed, put_back, settle):
+            act(b, i)
+
+    for i in range(runs):
+        def shared(b, i=i):
+            return target(b, i)[0] == sink_ref[0]
+
+        def apart(b, i=i):
+            return jnp.logical_not(shared(b))
+        # Rows in pages of their own: every tile in flight at once. The
+        # copies of one direction share a semaphore, so a wait says only
+        # that one more tile's bytes are in, not whose: every read is
+        # waited before any tile is touched.
+        for act in (read, landed, put_back, settle):
+            each_live(functools.partial(act, i=i), apart)
+        # Rows in the sink, which slots share: one at a time.
+        each_live(functools.partial(alone, i=i), shared)
+
+
+def paged_row_write(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
+                    k_rows: jnp.ndarray, v_rows: jnp.ndarray,
+                    block_tables: jnp.ndarray, horizon: jnp.ndarray,
+                    sink_page, *, interpret: Optional[bool] = None
+                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """k_rows / v_rows [slots, R, hkv, hd] into the pools [hkv, P, page,
+    hd] (a layer's pages, or a whole folded pool whose tables carry the
+    layer's offset), as ONE Pallas call (``_row_write_kernel``).
+
+    horizon [slots]: the position of the slot's first row plus one, 0
+    for a slot that writes nothing: what the decode kernel attends to.
+    Row i goes to position ``horizon - 1 + i``; past the table's end,
+    to ``sink_page``. Each pool is aliased onto its result, so inside a
+    layer scan the pool stays one buffer and a write costs its rows'
+    tiles. Compiled on a TPU, interpreted elsewhere."""
+    slots, runs, hkv, hd = k_rows.shape
+    page_size = k_pages.shape[2]
+    tile = _row_tile(page_size, k_pages.dtype.itemsize)
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((slots, hkv, tile, hd), k_pages.dtype)
+    kernel = functools.partial(
+        _row_write_kernel, page_size=page_size,
+        max_pages=block_tables.shape[1], tile=tile)
+    return tuple(pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[whole, whole, in_hbm, in_hbm],
+            out_specs=[in_hbm, in_hbm],
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+                   jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
+        input_output_aliases={5: 0, 6: 1},
+        interpret=_interpret_default(interpret),
+        name='paged_row_write',
+    )(block_tables, horizon.astype(jnp.int32),
+      jnp.reshape(jnp.asarray(sink_page, jnp.int32), (1,)),
+      k_rows.astype(k_pages.dtype), v_rows.astype(v_pages.dtype),
+      k_pages, v_pages))
+
+
+# ---------------------------------------------------------------------------
 # Paged cache writes (pure JAX: dynamic_update_slices of the written rows)
 # ---------------------------------------------------------------------------
-# Every writer is a chain of ``dynamic_update_slice``s whose update is
-# the rows written and nothing more. ``k_pages`` may be a whole folded
-# pool (every layer's pages on the page axis, infer/paged_cache.py)
-# carried through a layer scan: XLA applies such an update in place, so
-# a write costs its rows. A scatter (``.at[:, pids, rows].set``) would
-# not do: the TPU compiler relays the whole operand around it.
+# Every writer here is a chain of ``dynamic_update_slice``s whose update
+# is the rows written and nothing more: the prefill chunk's whole pages,
+# and the int8 flavor's decode and verify rows. ``k_pages`` may be a
+# whole folded pool (every layer's pages on the page axis,
+# infer/paged_cache.py) carried through a layer scan: XLA applies such
+# an update in place, so a write costs its rows. A scatter
+# (``.at[:, pids, rows].set``) would not do: the TPU compiler relays the
+# whole operand around it.
 def _put(pages: jnp.ndarray, update: jnp.ndarray, pid, row
          ) -> jnp.ndarray:
     """pages[:, pid, row:row+n] = update ([hkv, n, ...]); n rows of one
@@ -1003,25 +1160,37 @@ def append_run_pages(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                      block_tables: jnp.ndarray, lengths: jnp.ndarray,
                      k_scales: Optional[jnp.ndarray] = None,
                      v_scales: Optional[jnp.ndarray] = None, *,
-                     sink_page=0):
+                     sink_page=0, live: Optional[jnp.ndarray] = None):
     """Append a RUN of R tokens' K/V per slot at positions
     ``lengths[slot] + i`` — the speculative-verify write (input token
     plus padded draft candidates in one step).
 
-    k_new/v_new: [slots, R, hkv, hd]. One row write per slot and run
-    position, chained in run order. Positions past the slot's
-    block-table coverage (padded drafts of a slot the engine capped,
-    inactive slots' garbage lanes) redirect to ``sink_page`` (page 0; a
-    layer's own page 0 when the tables address a folded pool) — the
-    table lookup is clamped and overridden, never allowed to alias a
-    live page the way a clamped index would. With scales (int8 flavor)
-    each run row is quantized on write and returns a 4-tuple.
+    k_new/v_new: [slots, R, hkv, hd]. Positions past the slot's
+    block-table coverage (padded drafts of a slot the engine capped)
+    redirect to ``sink_page`` (page 0; a layer's own page 0 when the
+    tables address a folded pool) — the table lookup is clamped and
+    overridden, never allowed to alias a live page the way a clamped
+    index would. live: [slots] bool, the slots whose rows are written
+    (None: all of them).
+
+    The plain pools go through ``paged_row_write``: one kernel, and a
+    slot that is not live writes nothing. With scales (int8 flavor)
+    each run row is quantized on write, every slot's rows go in by a
+    chain of row writes in run order, the slots that are not live
+    included, and a 4-tuple comes back: a scale row is one float a
+    head, which the kernel's tiles would gain nothing on.
     """
+    if k_scales is None:
+        horizon = lengths + 1
+        if live is not None:
+            horizon = jnp.where(live, horizon, 0)
+        return paged_row_write(k_pages, v_pages, k_new, v_new,
+                               block_tables, horizon, sink_page)
     page = k_pages.shape[2]
     slots, maxp = block_tables.shape
     R = k_new.shape[1]
-    rows = _kv_rows(k_new, v_new, k_pages.dtype, k_scales is not None)
-    pools = _pools(k_pages, v_pages, k_scales, v_scales)
+    rows = _kv_rows(k_new, v_new, k_pages.dtype, True)
+    pools = (k_pages, v_pages, k_scales, v_scales)
     for i in range(R):
         pos = lengths + i
         col = pos // page
@@ -1041,17 +1210,18 @@ def append_token_pages(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                        block_tables: jnp.ndarray, lengths: jnp.ndarray,
                        k_scales: Optional[jnp.ndarray] = None,
                        v_scales: Optional[jnp.ndarray] = None, *,
-                       sink_page=0):
+                       sink_page=0, live: Optional[jnp.ndarray] = None):
     """Append one token's K/V per slot at position lengths[slot]: a run
     of one (``append_run_pages``).
 
     k_new/v_new: [slots, hkv, hd]. Slot i's row lands in page
-    table[i, len//page] at row len%page: one ``[hkv, 1, 1, hd]`` update
-    per slot. Active slots own distinct pages; inactive slots' garbage
-    rows may share the sink page, where the last one written stays.
-    With scales (int8 flavor) the row quantizes on write and returns a
+    table[i, len//page] at row len%page. Live slots own distinct pages;
+    on the int8 flavor the garbage rows of the slots that are not live
+    may share the sink page, where the last one written stays. With
+    scales (int8 flavor) the row quantizes on write and returns a
     4-tuple.
     """
     return append_run_pages(k_pages, v_pages, k_new[:, None],
                             v_new[:, None], block_tables, lengths,
-                            k_scales, v_scales, sink_page=sink_page)
+                            k_scales, v_scales, sink_page=sink_page,
+                            live=live)

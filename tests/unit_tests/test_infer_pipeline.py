@@ -330,13 +330,16 @@ _AT_PARENT = {
 # the decode programs did not change, only the chunk programs gained a
 # result (``python tests/unit_tests/test_infer_pipeline.py`` prints
 # the table; a PR that MEANS to change a decode program replaces it).
+# Since replaced on purpose: the paged and state programs write their
+# K/V rows through the row writer's kernel
+# (``ops/paged_attention.paged_row_write``), with the same tokens out.
 _DECODE_AT_PARENT = {
     'dense':
         '7d739f5b16a991492764a5b271a2d0bacece0faaf75533d9246504bc2e6eeb84',
     'paged':
-        'd7ab86ffdd3892ef89d16e768e956279d1c4bf8e8834a7244766c6085007adc6',
+        '54e8df5c5989742fa5278c45cf6df4abcf45c21ef94dc19ebc547c87a5357877',
     'state':
-        '26df6c7efefe5d01b2245432e980308af1e5c1655d5884e11e2fa2d503e2953b',
+        '0bc225d9691970c1836d6a0f80216b1b57f4b3249ae43bd7c849faa871b0cb15',
 }
 
 

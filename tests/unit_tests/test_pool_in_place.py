@@ -15,8 +15,11 @@ KV flavors (plain pages; int8 pages with row scales):
 - values: logits and the whole pool, read back through
   ``gather_pages`` as ``[L, hkv, P, page, hd]``, against a plain
   reference that loops over explicitly sliced layers.
-- the sink: writes past a slot's coverage and inactive slots' garbage
-  rows land in their OWN layer's page 0.
+- the sink: writes past a slot's coverage land in their OWN layer's
+  page 0; a slot that is not active writes nothing on the plain flavor,
+  whose decode and verify rows go through the ``paged_row_write``
+  kernel, and its garbage row into that sink on the int8 flavor, whose
+  rows go through a chain of ``dynamic_update_slice``s.
 
 Compiled here means XLA's CPU backend. It upcasts a bfloat16
 ``dynamic-update-slice`` to float32 (a pool-sized convert the TPU
@@ -24,7 +27,8 @@ compiler does not make), so the plain flavor is float32 here; and it
 runs a Pallas kernel in interpret mode inside a loop that carries, and
 so copies, every operand, which says nothing of the TPU program: the
 structural tests put a stand-in that reads one page through the table
-in the kernels' place. The value tests run the real kernels.
+in the attention kernels' place, and one that writes one row through
+the table in the row writer's. The value tests run the real kernels.
 """
 import dataclasses
 import re
@@ -141,6 +145,17 @@ def _stand_in(q, k_pages, v_pages, tables, *_, k_scales=None,
     return jnp.zeros(q.shape, jnp.float32) + jnp.sum(got)
 
 
+def _writer_stand_in(k_pages, v_pages, k_rows, v_rows, tables, horizon,
+                     sink_page, **_):
+    """In the row writer's place (module docstring): slot 0's first row
+    into each pool through the table, as a ``dynamic_update_slice``."""
+    pid = jnp.reshape(tables, (-1,))[0]
+    at = (0, pid, jnp.maximum(horizon[0] - 1, 0) % k_pages.shape[2], 0)
+    return tuple(jax.lax.dynamic_update_slice(
+        pool, rows[0, 0][:, None, None].astype(pool.dtype), at)
+        for pool, rows in ((k_pages, k_rows), (v_pages, v_rows)))
+
+
 _BYTES = {'f32': 4, 's32': 4, 'u32': 4, 'bf16': 2, 'f16': 2, 's8': 1,
           'u8': 1, 'pred': 1, 's64': 8, 'f64': 8}
 _INSTR = re.compile(
@@ -171,6 +186,7 @@ def test_compiled_program_holds_the_pool_in_place(name, flavor, params,
     for kernel in ('paged_decode_attention', 'paged_prefill_attention',
                    'paged_verify_attention'):
         monkeypatch.setattr(pa, kernel, _stand_in)
+    monkeypatch.setattr(pa, 'paged_row_write', _writer_stand_in)
     fn, args = _program(name)
     cache = _cache(flavor)
     compiled = jax.jit(fn, donate_argnums=0).lower(
@@ -238,8 +254,9 @@ def test_layer_scan_carries_the_pool(name, flavor, params):
     """The program as traced, real kernels and all, whatever backend
     compiles it: the pool arrays are CARRIES of the layer scan (not
     ``xs`` sliced a layer at a time, not ``ys`` stacked back), nothing
-    scatters into or gathers from them, and every
-    ``dynamic_update_slice`` of them updates a page or less."""
+    scatters into or gathers from them, every ``dynamic_update_slice``
+    of them updates a page or less, and the row writer's kernel hands
+    back every pool it is given, aliased onto its result."""
     fn, args = _program(name)
     cache = _cache(flavor)
     pools = [a for a in (cache.k_pages, cache.v_pages, cache.k_scales,
@@ -268,9 +285,16 @@ def test_layer_scan_carries_the_pool(name, flavor, params):
     updates = 0
     for eqn, inside in _walk(jaxpr):
         prim = eqn.primitive.name
-        touched = [v for v in eqn.invars
+        touched = [i for i, v in enumerate(eqn.invars)
                    if hasattr(v, 'aval') and hasattr(v.aval, 'shape')
                    and is_pool(v)]
+        if (touched and prim == 'pallas_call'
+                and eqn.params['name'] == 'paged_row_write'):
+            assert 'scan' in inside, inside
+            assert set(touched) <= set(dict(
+                eqn.params['input_output_aliases'])), eqn.params
+            updates += 1
+            continue
         if not touched or prim in ('pallas_call', 'scan', 'pjit', 'jit',
                                    'closed_call', 'custom_jvp_call'):
             continue
@@ -306,8 +330,9 @@ def _reference(params, cache, runs):
     causally through ``paged_verify_attention_reference``. A decode
     step is R = 1, a prefill chunk one row with R = C; a run may name
     as a fourth entry the rows that are ``active``: the decode programs
-    attend nothing in the others (their K/V row is still written, their
-    attention output is zeros). Returns the logits of every run
+    attend nothing in the others (their attention output is zeros) and
+    write no K/V row of them, but on the int8 flavor, whose chain of
+    row writes writes every slot. Returns the logits of every run
     ([n, R, vocab]) and the logical pool."""
     k_all, v_all, ks_all, vs_all = _logical(cache)
     quantized = ks_all is not None
@@ -334,6 +359,8 @@ def _reference(params, cache, runs):
                 k, v = np.asarray(k), np.asarray(v)
             for i in range(R):
                 for s in range(n):
+                    if active and not active[0][s] and not quantized:
+                        continue
                     col, row = divmod(int(positions[s, i]), PAGE)
                     pid = int(tables[s, col]) if col < MAXP else 0
                     k_all[layer_idx][:, pid, row] = k[s, i]
@@ -433,13 +460,17 @@ def test_logits_and_pool_match_the_per_layer_reference(name, flavor,
 @pytest.mark.parametrize('flavor', FLAVORS)
 @pytest.mark.parametrize('name', ['decode', 'verify'])
 def test_sink_writes_stay_in_their_own_layer(name, flavor, params):
-    """Slot 1's padded draft runs past its table's coverage and slot 2
-    is inactive with a zeroed row: both write into page 0, the sink,
-    of the layer that computed the row, and into no other page that the
-    step does not own. With the layer folded into the page axis a
-    clamp applied AFTER the layer's offset would send them to layer
-    0's page 0, or to another layer's live page."""
+    """Slot 1's padded draft runs past its table's coverage: that row
+    goes into page 0, the sink, of the layer that computed it, and into
+    no other page that the step does not own. With the layer folded
+    into the page axis a clamp applied AFTER the layer's offset would
+    send it to layer 0's page 0, or to another layer's live page. Slot
+    2 is not active (both programs are handed ``ACTIVE``): on the plain
+    flavor it writes nothing at all; on the int8 flavor its garbage
+    rows, through its zeroed table row, land in the same sink."""
     fn, args = _program(name)
+    if name == 'verify':
+        args = (*args, jnp.asarray(ACTIVE))
     cache = _cache(flavor)
     zero = jax.tree.map(jnp.zeros_like, cache)
     zero = dataclasses.replace(zero, lengths=cache.lengths)
@@ -452,7 +483,9 @@ def test_sink_writes_stay_in_their_own_layer(name, flavor, params):
              for s in (0, 1) for p in range(LENGTHS[s], LENGTHS[s] + run)
              if p // PAGE < MAXP}
     sink_rows = {p % PAGE for p in range(LENGTHS[1], LENGTHS[1] + run)
-                 if p // PAGE >= MAXP} | set(range(run))   # slot 2
+                 if p // PAGE >= MAXP}
+    if flavor == 'int8':
+        sink_rows |= set(range(run))                         # slot 2
     for layer_idx in range(CFG.n_layers):
         written = k[layer_idx].astype(np.float32).any(axis=(0, 3))
         where = {(int(p), int(r)) for p, r in zip(*np.nonzero(written))}
@@ -462,4 +495,5 @@ def test_sink_writes_stay_in_their_own_layer(name, flavor, params):
     sinks = [k[i][:, 0].astype(np.float32) for i in range(CFG.n_layers)]
     for a in range(CFG.n_layers):
         for b in range(a + 1, CFG.n_layers):
-            assert np.abs(sinks[a] - sinks[b]).max() > 0
+            assert (np.abs(sinks[a] - sinks[b]).max() > 0) == bool(
+                sink_rows)

@@ -15,9 +15,17 @@ PR 34 MEANT to change ``hybrid.decode`` and replaced its digest: the
 step's SSM state update is a Pallas kernel that moves the live slots'
 state only (``ops/mamba2.ssd_decode_live``; here, on the CPU, the text
 holds the kernel as it is interpreted), where the parent's was
-``ssd_decode_step`` under ``jnp.where``. The other five are PR 33's
-parent's still: the prefill-chunk program of the same family among
-them, which reads and writes one slot's row as it did.
+``ssd_decode_step`` under ``jnp.where``.
+
+The row writer MEANT to change ``dense.decode`` and ``hybrid.decode``
+and replaced both digests: a decode step's new K/V rows go into the
+page pool through one Pallas call a layer
+(``ops/paged_attention.paged_row_write``, interpreted here), which
+writes the live slots' rows only, where the parent's was a chain of
+one ``dynamic_update_slice`` a slot and pool, every slot. The other
+four are PR 33's parent's still: both families' prefill-chunk
+programs, which write whole pages as they did, and both dots3
+programs, which keep their own latent writers.
 
 A PR that MEANS to change one of these programs replaces its digest
 (``python tests/unit_tests/test_step_programs_unchanged.py`` prints the
@@ -42,11 +50,11 @@ AT_PARENT = {
     'dense.prefill_chunk':
         '7f8a443c5340b3bcf8627c7cfa84cc88eaae2fe20897f9c8a18c7e859de43c8f',
     'dense.decode':
-        '18caf9dedd1dd4237ab6d0c7e49c4f09fcfc46638b92c6912496c4e7dc5d9cf3',
+        'f507777a0f706e7251a8b6717e603f24166ab22776f31d6190c27b90cc068b2a',
     'hybrid.prefill_chunk':
         '484e6de253a3b90f99d6ab0bc914cef49803b6ad8cfb1053820b90c56f1c0a54',
     'hybrid.decode':
-        '4fb7b8777eeeea9a034f003da3573716a40a79d92c5713036eeaa0ad5afacd83',
+        'e89527f0694c190df209ea6e2eeacb9cbc78d654a5f286867cad8b1f91a4710a',
     'dots3.prefill_chunk':
         '5dc36e338e64e00798582cf51e1805df3a3f1708b85d8eb9b448b47cf1e0c9c8',
     'dots3.decode':

@@ -169,6 +169,55 @@ def _qkv_as_it_was(config, h, layer, cos, sin, positions):
     return q, k, v
 
 
+def _row_writes_as_they_were(k_pages, v_pages, k_rows, v_rows, tables,
+                             horizon, sink_page, **_):
+    """What every decode step's plain pools took before the row writer,
+    kept here so that the test shows what it guards: one
+    ``dynamic_update_slice`` of a row a slot and pool, every slot."""
+    page = k_pages.shape[2]
+    pos = jnp.maximum(horizon - 1, 0)
+    pids = jnp.take_along_axis(tables, (pos // page)[:, None], axis=1)[:, 0]
+    for s in range(k_rows.shape[0]):
+        at = (0, pids[s], pos[s] % page, 0)
+        k_pages = jax.lax.dynamic_update_slice(
+            k_pages, k_rows[s, 0][:, None, None].astype(k_pages.dtype), at)
+        v_pages = jax.lax.dynamic_update_slice(
+            v_pages, v_rows[s, 0][:, None, None].astype(v_pages.dtype), at)
+    return k_pages, v_pages
+
+
+def _pool_writes(compiled):
+    """(calls of the row writer's kernel, ``dynamic-update-slice``s
+    into a pool) in the layer loop's body."""
+    pool = f'bf16[{HKV},{DENSE_LAYERS * N_PAGES},{PAGE},{HD}]'
+    body = _loop_body(compiled.as_text())
+    writers = [line for line in body if ' custom-call(' in line
+               and 'tpu_custom_call' in line and 'paged_row_write' in line]
+    updates = [line for line in body
+               if re.search(rf'= {re.escape(pool)}\S* dynamic-update-slice\(',
+                            line)]
+    return len(writers), len(updates)
+
+
+@pytest.mark.parametrize('writer', ['kernel', 'as_it_was'])
+def test_dense_decode_writes_its_rows_through_one_kernel(
+        one_chip, no_cache, monkeypatch, writer):
+    """``paged_decode_step`` at the Mistral cell's shapes (int8 weights,
+    bfloat16 pools): the layer loop's body writes the step's K/V rows
+    by ONE call of ``paged_row_write`` and by no ``dynamic-update-slice``
+    into a pool; with the chain it replaced put back, the same reading
+    finds 2 x slots of them, one a slot and pool, so it can fail. (The
+    pools' aliasing and the temporaries are held by the test above, on
+    the same program.)"""
+    from skypilot_tpu.ops import paged_attention as pa
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    if writer == 'as_it_was':
+        monkeypatch.setattr(pa, 'paged_row_write', _row_writes_as_they_were)
+    compiled = _compile_dense_step('decode', one_chip, True)
+    want = (1, 0) if writer == 'kernel' else (0, 2 * SLOTS)
+    assert _pool_writes(compiled) == want
+
+
 @pytest.mark.parametrize('quantized', [True, False], ids=['int8', 'plain'])
 @pytest.mark.parametrize('program', ['decode', 'chunk'])
 @pytest.mark.parametrize('formulation', ['helper', 'as_it_was'])
@@ -197,7 +246,10 @@ def test_dense_step_reads_its_projection_weights_in_place(
         assert m.alias_size_in_bytes >= 2 * DENSE_LAYERS * slab
         assert m.temp_size_in_bytes < slab, m.temp_size_in_bytes
     else:
-        # wq and wk in a decode step, wv too in a chunk: each sliced
-        # into fast memory and then copied transposed.
-        assert len(relaid) == (4 if program == 'decode' else 6), relaid
+        # wq, wk and wv, each sliced into fast memory and then copied
+        # transposed. (A decode step's wv was read in place while its
+        # rows went into the pool by a chain of row updates; the row
+        # writer's kernel takes the step's v rows heads-major, as a
+        # chunk's page write does.)
+        assert len(relaid) == 6, relaid
         assert any(' copy(' in line for line in relaid)

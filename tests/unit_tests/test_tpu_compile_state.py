@@ -9,9 +9,11 @@ What this guards, beside "it compiles and fits": the Mamba-2 state
 kernel (``ops/mamba2.ssd_decode_live``, PR 34) lowers at both state
 shapes, ``[64, 32, 128, 256]`` and ``[64, 64, 64, 128]`` float32, once
 a state layer; every state array rides the program IN PLACE, aliased
-onto its result; and no operation yields a state-sized array at all: no
+onto its result; no operation yields a state-sized array at all: no
 copy, no relay, and no fusion over all 64 slots, which is what the
-``jax.numpy`` update under ``jnp.where`` was.
+``jax.numpy`` update under ``jnp.where`` was; and the K/V rows go into
+the pool through the row writer's kernel, once a block that pages K/V,
+and through no ``dynamic-update-slice``.
 
 These cases live here and not in ``tests/benchmark/``, which is the
 benchmark's (``tests/benchmark/test_tpu_compile_falcon_h1.py`` compiles
@@ -127,6 +129,24 @@ def test_the_state_kernel_lowers_once_a_state_layer(decode_program):
                        r'custom_call_target="tpu_custom_call"[^\n]*'
                        r'ssd_decode_state', compiled.as_text())
     assert len(calls) == len(cache.ssm)
+
+
+def test_the_rows_go_in_through_the_row_writer(decode_program):
+    """Every block that pages K/V writes its rows by one call of
+    ``paged_row_write`` (the pools aliased onto its results: the test
+    below), and no ``dynamic-update-slice`` goes into a pool, which is
+    what the chain it replaced was, one a slot and pool
+    (tests/unit_tests/test_tpu_compile_dense.py shows that reading
+    fail on the chain)."""
+    compiled, cache = decode_program
+    text = compiled.as_text()
+    calls = re.findall(r'= \([^=]*\) custom-call\([^\n]*'
+                       r'custom_call_target="tpu_custom_call"[^\n]*'
+                       r'paged_row_write', text)
+    assert len(calls) == cache.kv.n_layers
+    pool = ','.join(map(str, cache.kv.k_pages.shape))
+    assert not re.search(rf'= bf16\[{pool}\]\S* dynamic-update-slice\(',
+                         text)
 
 
 def test_no_operation_yields_a_state_sized_array(decode_program):
